@@ -47,7 +47,7 @@ from repro import obs
 from repro.serve.bucketing import BucketLadder
 from repro.serve.engine import ARCHS, Engine, EngineStats
 from repro.serve.router import DeviceRouter
-from repro.serve.workload import lidar_stream
+from repro.serve.workload import lidar_stream, stream_bound
 
 
 def _ms(v) -> str:
@@ -361,12 +361,14 @@ def run_fleet(tiny: bool = False, hosts: int = 2):
         ladder = BucketLadder((512, 1024, 2048), max_batch=4)
         reps = 3
     channels = ARCHS[arch].in_channels_of(ARCHS[arch].default_config)
-    scenes, bound = lidar_stream(0, count, channels, n_range=n_range)
-    cfg = ServiceConfig.from_ladder(ladder, spatial_bound=bound)
-    eng = Engine(arch, config=cfg)
-    rt = DeviceRouter(arch, devices=jax.device_count(), config=cfg)
+    cfg = ServiceConfig.from_ladder(ladder, spatial_bound=stream_bound())
+    # the fleet first: its workers spawn before this process starts a jax
+    # backend (the in-process tiers and lidar_stream below use jax)
     fl = FleetFrontend(arch, hosts=hosts, config=cfg)
     try:
+        scenes, _ = lidar_stream(0, count, channels, n_range=n_range)
+        eng = Engine(arch, config=cfg)
+        rt = DeviceRouter(arch, devices=jax.device_count(), config=cfg)
         warm = {}
         for tag, svc in (("engine", eng), ("router", rt), ("fleet", fl)):
             svc.warmup()

@@ -68,6 +68,8 @@ def main(argv=None) -> None:
     ap.add_argument("--hosts", type=int, default=2,
                     help="localhost worker count for the fleet_serving suite")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (bench_generator, bench_graph, bench_hybrid,
                             bench_inference, bench_kmap, bench_serving,
@@ -87,7 +89,10 @@ def main(argv=None) -> None:
         ("fig8_generator", bench_generator.run),
     ]
     # Opt-in suites: spawn subprocesses (localhost fleet workers), so they
-    # run only when explicitly named in --only, never by default.
+    # run only when explicitly named in --only, never by default — and
+    # alone: the workers must claim the chip before this process starts a
+    # jax backend, and on a TPU host the front end then stays on the CPU,
+    # where any other suite would run too.
     opt_in = [
         ("fleet_serving", bench_serving.run_fleet),
     ]
@@ -96,11 +101,14 @@ def main(argv=None) -> None:
         unknown = keep - {name for name, _ in suites + opt_in}
         if unknown:
             raise SystemExit(f"unknown suites: {sorted(unknown)}")
+        if len(keep) > 1 and keep & {name for name, _ in opt_in}:
+            raise SystemExit(f"{sorted(keep)}: an opt-in suite spawns "
+                             f"workers and runs alone")
         suites = [(n, f) for n, f in suites + opt_in if n in keep]
 
     print("name,us_per_call,derived")
     failures = []
-    report = {"meta": _metadata(args.tiny), "suites": {}}
+    report = {"suites": {}}
     for name, fn in suites:
         start = len(common.RECORDS)
         t0 = time.perf_counter()
@@ -126,6 +134,7 @@ def main(argv=None) -> None:
             "rows": rows,
         }
     report["failures"] = failures
+    report["meta"] = _metadata(args.tiny)
 
     if args.out:
         with open(args.out, "w") as f:

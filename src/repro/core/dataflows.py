@@ -108,17 +108,12 @@ class DataflowConfig:
 DEFAULT_CONFIG = DataflowConfig()
 
 
-def default_serving_space(include_pallas: Optional[bool] = None) -> Tuple[DataflowConfig, ...]:
+def default_serving_space(include_pallas: bool = True) -> Tuple[DataflowConfig, ...]:
     """The serving tuner's default search space: all three dataflows on the
-    XLA backend plus — when the installed jax can run them (interpret mode
-    on CPU, native on TPU) — the same three on the Pallas backend.
-
-    include_pallas: force the Pallas axis on/off; None probes
-    ``kernels.common.pallas_supported()``.
-    """
-    if include_pallas is None:
-        from repro.kernels.common import pallas_supported
-        include_pallas = pallas_supported()
+    XLA backend plus (``include_pallas``) the same three on the Pallas
+    backend — compiled on TPU, interpret mode elsewhere.  The worklist
+    variant is not searched: the served executor is jitted, and the
+    worklist kernel runs eagerly only."""
     space = [DataflowConfig("gather_scatter"),
              DataflowConfig("fetch_on_demand"),
              DataflowConfig("implicit_gemm", n_splits=1)]
@@ -129,12 +124,8 @@ def default_serving_space(include_pallas: Optional[bool] = None) -> Tuple[Datafl
         # tuner's dataflow ranking — is tile-independent); real TPUs keep
         # the MXU-shaped defaults.
         tm, tn = (16, 128) if default_interpret() else (128, 128)
-        pallas = [dataclasses.replace(cfg, backend="pallas", tile_m=tm,
+        space += [dataclasses.replace(cfg, backend="pallas", tile_m=tm,
                                       tile_n=tn) for cfg in space]
-        pallas.append(DataflowConfig("implicit_gemm", n_splits=1, tile_m=tm,
-                                     tile_n=tn, backend="pallas",
-                                     worklist=True))
-        space += pallas
     return tuple(space)
 
 

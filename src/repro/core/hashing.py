@@ -347,8 +347,7 @@ def radix_argsort_padded(vals: jax.Array, nbits: int) -> jax.Array:
 
 
 def radix_argsort_keys(keys: jax.Array, spec: KeySpec) -> jax.Array:
-    """O(N·bits) stable radix argsort of packed keys (XLA twin of the
-    Pallas kernel in ``repro.kernels.radix_sort``).  Requires a bounded
+    """O(N·bits) stable radix argsort of packed keys.  Requires a bounded
     spec; two-word keys chain lo-word then hi-word passes (stable LSD).
     The permutation is bit-identical to ``sort_keys``'s argsort, pads and
     MISS sentinels included."""

@@ -15,10 +15,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # Sentinel for padded coordinate rows.  Chosen so that shifted/strided variants
-# of a padded coordinate also never collide with a real voxel key.
-INVALID_COORD = jnp.int32(0x3FFFFFF)
+# of a padded coordinate also never collide with a real voxel key.  A numpy
+# scalar: a jnp one would start a jax backend (and claim a TPU) on import.
+INVALID_COORD = np.int32(0x3FFFFFF)
 
 
 @jax.tree_util.register_dataclass

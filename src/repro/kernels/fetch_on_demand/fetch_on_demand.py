@@ -5,15 +5,15 @@ fetched on demand into on-chip memory, partial sums are scattered straight to
 the output without a DRAM scatter buffer.  PCEngine's "block fusion" (the
 host δ-loop becoming a parallel dimension) maps to the leading grid axis.
 
-TPU adaptation (DESIGN.md §2): the paper needs atomics because CUDA thread
-blocks race on output rows.  A Pallas TPU grid runs *sequentially* on a core,
-so the read-modify-write scatter (DMA out-row → VMEM, add, DMA back) is
-race-free by construction; the cost — Σ_δ |M_δ| output-row writes, 4-10× the
-output size — is exactly the write-amplification the paper attributes to this
+TPU adaptation: the paper needs atomics because CUDA thread blocks race on
+output rows.  A Pallas TPU grid runs *sequentially* on a core, so the
+read-modify-write scatter (DMA out-row → VMEM, add, DMA back) is race-free
+by construction; the cost — Σ_δ |M_δ| output-row writes, 4-10× the output
+size — is exactly the write-amplification the paper attributes to this
 dataflow, and is what the Autotuner trades off against implicit GEMM.
 
-The output is accumulated in place via ``input_output_aliases`` (caller
-passes the zero-initialized buffer).
+The output is accumulated in place, in float32, via
+``input_output_aliases``.
 """
 from __future__ import annotations
 
@@ -28,104 +28,81 @@ from repro.kernels import common
 
 
 def _kernel(wsin_ref, wsout_ref, x_ref, w_ref, acc_in_ref, o_ref,
-            scratch, obuf, ybuf, sems, osems, *, tile_r: int, cin: int):
+            xbuf, obuf, sems, osems, *, tile_r: int):
     del acc_in_ref  # aliased with o_ref
+    # Fetch this tile's input rows and current output rows, all in flight.
+    # The read-modify-write scatter is race-free: a TPU Pallas grid runs
+    # sequentially on a core, and within one δ every output row appears at
+    # most once, so tile-internal rows never collide either.
+    common.start_gather(wsout_ref, o_ref, obuf, osems, n=tile_r)
+    common.gather_rows(wsin_ref, x_ref, xbuf, sems, n=tile_r)
+    common.wait_gather(wsout_ref, o_ref, obuf, osems, n=tile_r)
 
-    # 1) gather input rows for this tile of (in, out) pairs
-    for r in range(tile_r):
-        idx = wsin_ref[0, r]
+    y = common.chunked_dot(xbuf, w_ref, w_ref.dtype)
+    for j in range(obuf.shape[0]):
+        obuf[j] += y[:, j * common.LANES:(j + 1) * common.LANES]
 
-        @pl.when(idx >= 0)
-        def _start():
-            pltpu.make_async_copy(x_ref.at[idx], scratch.at[r], sems.at[r]).start()
+    # scatter the partial sums straight back to the output rows
+    def write_back(r):
+        return common.row_copies(o_ref, wsout_ref[0, r], obuf, r,
+                                 osems.at[r], scatter=True)
 
-        @pl.when(idx < 0)
-        def _zero():
-            scratch[r, :] = jnp.zeros((cin,), scratch.dtype)
-
-    # 2) fetch current output rows (read-modify-write scatter; race-free
-    #    because a TPU Pallas grid executes sequentially on a core).  Within
-    #    one δ every output row appears at most once, so tile-internal rows
-    #    never collide either.
-    for r in range(tile_r):
-        odx = wsout_ref[0, r]
-
-        @pl.when(odx >= 0)
-        def _ostart():
-            pltpu.make_async_copy(o_ref.at[odx], obuf.at[r], osems.at[r]).start()
-
-    for r in range(tile_r):
-        idx = wsin_ref[0, r]
-
-        @pl.when(idx >= 0)
-        def _wait():
-            pltpu.make_async_copy(x_ref.at[idx], scratch.at[r], sems.at[r]).wait()
-
-    # 3) on-chip MMA
-    ybuf[...] = jnp.dot(scratch[...], w_ref[0],
-                        preferred_element_type=jnp.float32)
-
-    # 4) scatter partial sums straight back to the output rows
-    for r in range(tile_r):
-        odx = wsout_ref[0, r]
-
-        @pl.when(odx >= 0)
-        def _owait():
-            pltpu.make_async_copy(o_ref.at[odx], obuf.at[r], osems.at[r]).wait()
-
-    obuf[...] = (obuf[...].astype(jnp.float32) + ybuf[...]).astype(obuf.dtype)
-
-    for r in range(tile_r):
-        odx = wsout_ref[0, r]
-
-        @pl.when(odx >= 0)
+    def start(r, carry):
+        @pl.when(wsout_ref[0, r] >= 0)
         def _wb():
-            pltpu.make_async_copy(obuf.at[r], o_ref.at[odx], osems.at[r]).start()
+            for c in write_back(r):
+                c.start()
+        return carry
 
-    for r in range(tile_r):
-        odx = wsout_ref[0, r]
-
-        @pl.when(odx >= 0)
+    def wait(r, carry):
+        @pl.when(wsout_ref[0, r] >= 0)
         def _wb_wait():
-            pltpu.make_async_copy(obuf.at[r], o_ref.at[odx], osems.at[r]).wait()
+            for c in write_back(r):
+                c.wait()
+        return carry
+
+    jax.lax.fori_loop(0, tile_r, start, 0)
+    jax.lax.fori_loop(0, tile_r, wait, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_r", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_out", "tile_r", "interpret"))
 def fetch_on_demand_pallas(ws_in: jax.Array, ws_out: jax.Array, x: jax.Array,
-                           w: jax.Array, out0: jax.Array, *, tile_r: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           w: jax.Array, *, n_out: int, tile_r: int,
+                           interpret: bool) -> jax.Array:
     """ws_in/ws_out: (KD, cap) int32 pair lists (-1 pad, compacted to front);
-    x: (N_in, Cin); w: (KD, Cin, Cout); out0: zero-init (N_out, Cout).
-    Returns out0 + sparse_conv(x, w)."""
+    x: (N_in, Cin); w: (KD, Cin, Cout).  Returns sparse_conv(x, w) as
+    (n_out, Cout) in x.dtype, accumulated in float32."""
     kd, cap = ws_in.shape
-    _, cin = x.shape
-    cout = w.shape[-1]
+    out_dtype, cout = x.dtype, w.shape[-1]
     assert cap % tile_r == 0
-    grid = (kd, cap // tile_r)
-
-    kernel = functools.partial(_kernel, tile_r=tile_r, cin=cin)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    nci, nco = common.chunks(x.shape[1]), common.chunks(cout)
+    x = common.gather_operand(x)
+    w = common.pad_lanes(common.pad_lanes(w, 1), 2)
+    sq = pl.squeezed
+    pairs = pl.BlockSpec((sq, sq, 1, tile_r), lambda k, r: (k, r, 0, 0),
+                         memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
+        functools.partial(_kernel, tile_r=tile_r),
+        grid=(kd, cap // tile_r),
         in_specs=[
-            pl.BlockSpec((1, tile_r), lambda k, r: (k, r), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, tile_r), lambda k, r: (k, r), memory_space=pltpu.SMEM),
+            pairs, pairs,
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, cin, cout), lambda k, r: (k, 0, 0)),
+            pl.BlockSpec((sq,) + w.shape[1:], lambda k, r: (k, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),   # aliased accumulator
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct(out0.shape, out0.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_out * nco, common.LANES),
+                                       jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((tile_r, cin), x.dtype),
-            pltpu.VMEM((tile_r, cout), out0.dtype),
-            pltpu.VMEM((tile_r, cout), jnp.float32),
+            pltpu.VMEM((nci, tile_r, common.LANES), x.dtype),
+            pltpu.VMEM((nco, tile_r, common.LANES), jnp.float32),
             pltpu.SemaphoreType.DMA((tile_r,)),
             pltpu.SemaphoreType.DMA((tile_r,)),
         ],
         input_output_aliases={4: 0},
         interpret=interpret,
-        compiler_params=common.tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            interpret=interpret),
-    )(ws_in, ws_out, x, w, out0)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+    )(common.pair_blocks(ws_in, tile_r), common.pair_blocks(ws_out, tile_r),
+      x, w, jnp.zeros((n_out * nco, common.LANES), jnp.float32))
+    return out.reshape(n_out, -1)[:, :cout].astype(out_dtype)

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.kmap import KernelMap
-from repro.kernels.common import default_interpret, pad_rows
+from repro.kernels.common import default_interpret
 from repro.kernels.fetch_on_demand.fetch_on_demand import fetch_on_demand_pallas
 
 
@@ -18,6 +18,5 @@ def fetch_on_demand(x: jax.Array, w: jax.Array, kmap: KernelMap, *,
     pad = (-cap) % tile_r
     ws_in = jnp.pad(kmap.ws_in, ((0, 0), (0, pad)), constant_values=-1)
     ws_out = jnp.pad(kmap.ws_out, ((0, 0), (0, pad)), constant_values=-1)
-    out0 = jnp.zeros((kmap.capacity, w.shape[-1]), x.dtype)
-    return fetch_on_demand_pallas(ws_in, ws_out, x, w, out0, tile_r=tile_r,
-                                  interpret=interpret)
+    return fetch_on_demand_pallas(ws_in, ws_out, x, w, n_out=kmap.capacity,
+                                  tile_r=tile_r, interpret=interpret)

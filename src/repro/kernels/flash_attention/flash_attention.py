@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
 
 NEG_INF = -1e30
 
@@ -71,7 +70,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, block_q: int = 128,
-                           block_k: int = 128, interpret: bool = True) -> jax.Array:
+                           block_k: int = 128, interpret: bool) -> jax.Array:
     """q: (BH, S, D); k/v: (BH, T, D) — heads pre-flattened/broadcast."""
     bh, s, d = q.shape
     t = k.shape[1]
@@ -96,7 +95,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=common.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            interpret=interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(q, k, v)

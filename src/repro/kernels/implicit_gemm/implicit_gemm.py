@@ -7,7 +7,8 @@ loads go through one level of indirection (the kernel map).  TPU adaptation
 * the kernel map tile lives in **SMEM** (BlockSpec memory_space=SMEM) — the
   structural equivalent of the paper's hoisted, register-resident addressing;
 * operand A rows are fetched **HBM→VMEM by per-row async DMA**
-  (`pltpu.make_async_copy`), all `tile_m` copies in flight before the MXU
+  (`pltpu.make_async_copy`, one per 128-lane chunk of the row — see
+  ``common.gather_operand``), all `tile_m` rows in flight before the MXU
   consumes them — this is the "sparse DRAM→L1 iterator" with overlapped
   memory access and compute (paper Fig. 3d);
 * per-(tile, δ) **occupancy scalars** gate the whole gather+matmul with
@@ -39,11 +40,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
-from repro.kernels.common import cdiv
 
 
 def _kernel(midx_ref, occ_ref, x_ref, w_ref, o_ref, scratch, acc, sems, *,
-            tile_m: int, cin: int):
+            tile_m: int):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -52,28 +52,8 @@ def _kernel(midx_ref, occ_ref, x_ref, w_ref, o_ref, scratch, acc, sems, *,
 
     @pl.when(occ_ref[0, 0] == 1)
     def _compute():
-        # Issue all row gathers (double buffering degenerates to "all in
-        # flight": one DMA + semaphore per row).
-        for r in range(tile_m):
-            idx = midx_ref[r, 0]
-
-            @pl.when(idx >= 0)
-            def _start():
-                pltpu.make_async_copy(x_ref.at[idx], scratch.at[r], sems.at[r]).start()
-
-            @pl.when(idx < 0)
-            def _zero_row():
-                scratch[r, :] = jnp.zeros((cin,), scratch.dtype)
-
-        for r in range(tile_m):
-            idx = midx_ref[r, 0]
-
-            @pl.when(idx >= 0)
-            def _wait():
-                pltpu.make_async_copy(x_ref.at[idx], scratch.at[r], sems.at[r]).wait()
-
-        acc[...] += jnp.dot(scratch[...], w_ref[0],
-                            preferred_element_type=jnp.float32)
+        common.gather_rows(midx_ref, x_ref, scratch, sems, n=tile_m)
+        acc[...] += common.chunked_dot(scratch, w_ref, w_ref.dtype)
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _flush():
@@ -82,8 +62,8 @@ def _kernel(midx_ref, occ_ref, x_ref, w_ref, o_ref, scratch, acc, sems, *,
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "tile_n", "interpret"))
 def implicit_gemm_pallas(midx: jax.Array, occ: jax.Array, x: jax.Array,
-                         w: jax.Array, *, tile_m: int = 128, tile_n: int = 128,
-                         interpret: bool = True) -> jax.Array:
+                         w: jax.Array, *, tile_m: int, tile_n: int,
+                         interpret: bool) -> jax.Array:
     """One split of sorted/unsorted implicit GEMM.
 
     midx: (N_out_pad, KD) int32 — (already row-permuted) kernel map slice.
@@ -93,34 +73,43 @@ def implicit_gemm_pallas(midx: jax.Array, occ: jax.Array, x: jax.Array,
     Returns (N_out_pad, Cout) partial sums in x.dtype.
     """
     n_out, kd = midx.shape
-    _, cin = x.shape
-    cout = w.shape[-1]
+    out_dtype = x.dtype
+    nc, cout = common.chunks(x.shape[1]), w.shape[-1]
+    x, w = common.gather_operand(x), common.pad_lanes(w, 1)
     assert n_out % tile_m == 0, "pad map rows to tile_m (paper §3.2)"
     assert cout % tile_n == 0, f"Cout {cout} must be a multiple of tile_n {tile_n}"
-    grid = (n_out // tile_m, cout // tile_n, kd)
+    n_tiles = n_out // tile_m
+    grid = (n_tiles, cout // tile_n, kd)
+    # Index blocks whose last two dims span the whole array, as Mosaic
+    # requires of blocks that are not (8, 128)-aligned: one (1, tile_m) row
+    # of map entries and one (1, 1) occupancy scalar per (tile, δ) step.
+    midx_t = midx.reshape(n_tiles, tile_m, kd).transpose(0, 2, 1)[:, :, None, :]
+    occ_t = occ.reshape(n_tiles, kd, 1, 1)
+    sq = pl.squeezed
 
-    kernel = functools.partial(_kernel, tile_m=tile_m, cin=cin)
+    kernel = functools.partial(_kernel, tile_m=tile_m)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_m, 1), lambda i, j, k: (i, k), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, k), memory_space=pltpu.SMEM),
+            pl.BlockSpec((sq, sq, 1, tile_m), lambda i, j, k: (i, k, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((sq, sq, 1, 1), lambda i, j, k: (i, k, 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, cin, tile_n), lambda i, j, k: (k, 0, j)),
+            pl.BlockSpec((sq, w.shape[1], tile_n), lambda i, j, k: (k, 0, j)),
         ],
         out_specs=pl.BlockSpec((tile_m, tile_n), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n_out, cout), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_out, cout), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((tile_m, cin), x.dtype),
+            pltpu.VMEM((nc, tile_m, common.LANES), x.dtype),
             pltpu.VMEM((tile_m, tile_n), jnp.float32),
             pltpu.SemaphoreType.DMA((tile_m,)),
         ],
         interpret=interpret,
-        compiler_params=common.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            interpret=interpret),
-    )(midx, occ, x, w)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+    )(midx_t, occ_t, x, w)
 
 
 # ------------------------------------------------------- tile skipping
@@ -132,7 +121,7 @@ WL_VALID = 4   # real entry: gather + accumulate (middle entries are
 
 
 def _wl_kernel(wl_tile_ref, wl_delta_ref, wl_flags_ref, midx_ref, x_ref,
-               w_ref, o_ref, scratch, acc, sems, *, tile_m: int, cin: int):
+               w_ref, o_ref, scratch, acc, sems, *, tile_m: int):
     del wl_tile_ref, wl_delta_ref   # consumed by the index maps
     i = pl.program_id(1)
     fl = wl_flags_ref[i]
@@ -143,26 +132,8 @@ def _wl_kernel(wl_tile_ref, wl_delta_ref, wl_flags_ref, midx_ref, x_ref,
 
     @pl.when((fl & WL_VALID) != 0)
     def _compute():
-        for r in range(tile_m):
-            idx = midx_ref[0, r]
-
-            @pl.when(idx >= 0)
-            def _start():
-                pltpu.make_async_copy(x_ref.at[idx], scratch.at[r], sems.at[r]).start()
-
-            @pl.when(idx < 0)
-            def _zero_row():
-                scratch[r, :] = jnp.zeros((cin,), scratch.dtype)
-
-        for r in range(tile_m):
-            idx = midx_ref[0, r]
-
-            @pl.when(idx >= 0)
-            def _wait():
-                pltpu.make_async_copy(x_ref.at[idx], scratch.at[r], sems.at[r]).wait()
-
-        acc[...] += jnp.dot(scratch[...], w_ref[0],
-                            preferred_element_type=jnp.float32)
+        common.gather_rows(midx_ref, x_ref, scratch, sems, n=tile_m)
+        acc[...] += common.chunked_dot(scratch, w_ref, w_ref.dtype)
 
     @pl.when((fl & WL_LAST) != 0)
     def _flush():
@@ -175,9 +146,8 @@ def _wl_kernel(wl_tile_ref, wl_delta_ref, wl_flags_ref, midx_ref, x_ref,
 def implicit_gemm_worklist_pallas(wl_tile: jax.Array, wl_delta: jax.Array,
                                   wl_flags: jax.Array, wl_midx: jax.Array,
                                   x: jax.Array, w: jax.Array, *,
-                                  n_tiles_m: int, tile_m: int = 128,
-                                  tile_n: int = 128,
-                                  interpret: bool = True) -> jax.Array:
+                                  n_tiles_m: int, tile_m: int, tile_n: int,
+                                  interpret: bool) -> jax.Array:
     """One split of tile-skipping implicit GEMM over a compacted worklist.
 
     wl_tile:  (W,) int32 — output m-tile of each entry, sorted ascending
@@ -193,26 +163,30 @@ def implicit_gemm_worklist_pallas(wl_tile: jax.Array, wl_delta: jax.Array,
     entry hold uninitialized garbage — callers must mask them to zero
     (the wrapper does).
     """
-    wn, cin = wl_midx.shape[0], x.shape[1]
-    cout = w.shape[-1]
+    wn = wl_midx.shape[0]
+    out_dtype = x.dtype
+    nc, cout = common.chunks(x.shape[1]), w.shape[-1]
+    x, w = common.gather_operand(x), common.pad_lanes(w, 1)
     assert cout % tile_n == 0, f"Cout {cout} must be a multiple of tile_n {tile_n}"
     grid = (cout // tile_n, wn)   # worklist innermost: same-tile steps stay
     #                               resident in the output block / acc
+    sq = pl.squeezed
 
-    kernel = functools.partial(_wl_kernel, tile_m=tile_m, cin=cin)
+    kernel = functools.partial(_wl_kernel, tile_m=tile_m)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tile_m), lambda j, i, wt, wd, wf: (i, 0),
+            pl.BlockSpec((sq, 1, tile_m), lambda j, i, wt, wd, wf: (i, 0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, cin, tile_n), lambda j, i, wt, wd, wf: (wd[i], 0, j)),
+            pl.BlockSpec((sq, w.shape[1], tile_n),
+                         lambda j, i, wt, wd, wf: (wd[i], 0, j)),
         ],
         out_specs=pl.BlockSpec((tile_m, tile_n),
                                lambda j, i, wt, wd, wf: (wt[i], j)),
         scratch_shapes=[
-            pltpu.VMEM((tile_m, cin), x.dtype),
+            pltpu.VMEM((nc, tile_m, common.LANES), x.dtype),
             pltpu.VMEM((tile_m, tile_n), jnp.float32),
             pltpu.SemaphoreType.DMA((tile_m,)),
         ],
@@ -220,9 +194,8 @@ def implicit_gemm_worklist_pallas(wl_tile: jax.Array, wl_delta: jax.Array,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles_m * tile_m, cout), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_tiles_m * tile_m, cout), out_dtype),
         interpret=interpret,
-        compiler_params=common.tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"),
-            interpret=interpret),
-    )(wl_tile, wl_delta, wl_flags, wl_midx, x, w)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(wl_tile, wl_delta, wl_flags, wl_midx[:, None, :], x, w)

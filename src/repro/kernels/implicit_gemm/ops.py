@@ -12,13 +12,14 @@ Two launch geometries:
 * worklist (``worklist=True``) — the occupied (m_tile, δ) pairs are
   compacted host-side from the ``SplitPlan`` occupancy (fused into
   ``make_split_plan(tile_m=...)``) and the grid runs over *only* those —
-  Spira-style structure-exploiting tile skipping.  Needs concrete
-  occupancy to size the grid, so under ``jit`` tracing it falls back to
-  the dense grid (bit-identical math; the tuner stamps what ran).
+  Spira-style structure-exploiting tile skipping.  It needs concrete
+  occupancy to size the grid, so it runs eagerly only: under ``jit`` it
+  raises rather than quietly running the dense grid.
 
-Requested tiles are clamped to divisors of the actual shapes
-(``gcd(tile, dim)``) so any tuner-proposed config runs on any layer —
-small-channel layers get narrower tiles instead of an assertion.
+Requested tiles are clamped to what the shapes allow so any tuner-proposed
+config runs on any layer: ``tile_m`` to ``gcd(tile_m, capacity)``, and
+``tile_n`` to the whole of Cout where it does not divide Cout (a lane block
+is either a divisor of Cout or all of it).
 """
 from __future__ import annotations
 
@@ -69,15 +70,18 @@ def implicit_gemm(x: jax.Array, w: jax.Array, kmap: KernelMap, plan: SplitPlan,
     cap = kmap.capacity
     cout = w.shape[-1]
     tile_m = math.gcd(tile_m, cap)
-    tile_n = math.gcd(tile_n, cout)
+    tile_n = tile_n if cout % tile_n == 0 else cout
     n_tiles = cap // tile_m
     out = jnp.zeros((cap, cout), x.dtype)
     for s, (a, b) in enumerate(plan.ranges):
         order = plan.order[s]
         midx = kmap.m_out[order][:, a:b]
         occ3 = (midx.reshape(n_tiles, tile_m, b - a) >= 0).any(axis=1)
-        use_wl = worklist and not isinstance(occ3, jax.core.Tracer)
-        if use_wl:
+        if worklist and isinstance(occ3, jax.core.Tracer):
+            raise ValueError(
+                "the worklist implicit GEMM sizes its grid from concrete "
+                "occupancy and cannot run under jit; use worklist=False")
+        if worklist:
             if plan.occupancy is not None and plan.tile_m == tile_m \
                     and not isinstance(plan.occupancy, jax.core.Tracer):
                 occ_np = np.asarray(plan.occupancy[s][:, a:b]) != 0

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
-
 
 def _kernel(a_ref, x_ref, b_ref, c_ref, y_ref, hfin_ref, state, *, chunk: int):
     ci = pl.program_id(1)
@@ -64,7 +62,7 @@ def _kernel(a_ref, x_ref, b_ref, c_ref, y_ref, hfin_ref, state, *, chunk: int):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_chunk_pallas(a: jax.Array, xdt: jax.Array, b: jax.Array, c: jax.Array,
-                     *, chunk: int = 128, interpret: bool = True):
+                     *, chunk: int = 128, interpret: bool):
     """a: (BH, S) log-decays; xdt: (BH, S, P); b/c: (BH, S, N), S % chunk == 0.
 
     Returns (y (BH, S, P) f32, h_final (BH, N, P) f32)."""
@@ -93,7 +91,6 @@ def ssd_chunk_pallas(a: jax.Array, xdt: jax.Array, b: jax.Array, c: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-        compiler_params=common.tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"),
-            interpret=interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(a, xdt, b, c)

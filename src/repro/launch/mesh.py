@@ -2,11 +2,6 @@
 
 Functions, not module-level constants — importing this module never touches
 jax device state (the dry-run must set XLA_FLAGS before any jax init).
-
-Capability note: ``jax.sharding.AxisType`` (and ``jax.make_mesh``'s
-``axis_types=`` kwarg) only exist in newer jax releases; on older runtimes
-(e.g. the 0.4.37 CI environment) meshes are built without explicit axis
-types, which is the same ``Auto`` default those releases used implicitly.
 """
 from __future__ import annotations
 
@@ -14,28 +9,25 @@ import os
 from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 from repro.models.lm_common import ShardCtx
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where the running jax supports it, else {}."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    return (AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests, elastic restore)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=_auto(len(axes)))
 
 
 def make_ctx(mesh, fsdp: bool = False) -> ShardCtx:
@@ -82,7 +74,7 @@ def make_serving_mesh(n: Optional[int] = None):
     the ``serve.DeviceRouter`` shards its bucket-ladder workers across."""
     devs = serving_devices(n)
     return jax.make_mesh((len(devs),), ("serve",), devices=devs,
-                         **_axis_type_kwargs(1))
+                         axis_types=_auto(1))
 
 
 # TPU v5e hardware constants for the roofline (per chip).

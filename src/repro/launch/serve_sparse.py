@@ -20,14 +20,16 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import time
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import ARCHS, Engine
 from repro.serve.fleet import FleetFrontend
 from repro.serve.plans import PlanRegistry
 from repro.serve.router import DeviceRouter
 from repro.serve.service import ServiceConfig
-from repro.serve.workload import lidar_stream
+from repro.serve.workload import lidar_stream, stream_bound
 
 
 def build_service(arch: str, buckets, max_batch: int, spatial_bound: int,
@@ -104,13 +106,13 @@ def main(argv=None):
                     help="write a trace of the serving run: Chrome "
                          "trace-event JSON (open in Perfetto) or a flat "
                          "event log when OUT ends in .jsonl; also captures "
-                         "an XLA-level profile to OUT.xprof/ when the jax "
-                         "profiler is available")
+                         "an XLA-level profile to OUT.xprof/")
     ap.add_argument("--max-wait-ms", type=float, default=None,
                     help="latency deadline: flush when the oldest queued "
                          "scene exceeds this age; doubles as the per-request "
                          "SLO reported in summary()['slo']")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.tiny:
         args.scenes, args.buckets = 6, "256,512"
@@ -119,14 +121,17 @@ def main(argv=None):
 
     binding = ARCHS[args.arch]
     channels = binding.in_channels_of(binding.default_config)
-    scenes, bound = lidar_stream(args.seed, args.scenes, channels,
-                                 n_range=(args.min_points, args.max_points))
-    engine = build_service(args.arch, buckets, args.max_batch, bound,
+    # the service before the stream: a fleet front end spawns its workers
+    # before this process starts a jax backend (lidar_stream uses jax), so
+    # a spawned worker can claim the chip
+    engine = build_service(args.arch, buckets, args.max_batch, stream_bound(),
                            plans_path=args.plans, seed=args.seed,
                            map_strategy=args.map_strategy,
                            devices=args.devices, hosts=args.hosts,
                            max_wait_ms=args.max_wait_ms,
                            replication=args.replication)
+    scenes, _ = lidar_stream(args.seed, args.scenes, channels,
+                             n_range=(args.min_points, args.max_points))
     sharded = isinstance(engine, DeviceRouter)
     fleet = isinstance(engine, FleetFrontend)
     if args.trace:
@@ -144,14 +149,15 @@ def main(argv=None):
     elif not (sharded or fleet) and engine.assignment:
         print(f"loaded {len(engine.assignment)} tuned groups from {args.plans}")
 
+    t0 = time.perf_counter()
     engine.warmup()
+    warm_s = time.perf_counter() - t0
     warm = engine.stats.summary()
     # --trace also brackets the serve epochs with the XLA-level profiler
-    # (TensorBoard/XProf artifact next to our own Chrome trace) when the
-    # running jax exposes one
+    # (TensorBoard/XProf artifact next to our own Chrome trace)
     profiler = (obs.jax_profile(args.trace + ".xprof")
-                if args.trace else contextlib.nullcontext(False))
-    with profiler as profiling:
+                if args.trace else contextlib.nullcontext())
+    with profiler:
         for _ in range(max(1, args.epochs)):
             results = engine.serve(scenes, flush_every=args.flush_every)
 
@@ -165,7 +171,8 @@ def main(argv=None):
     print(f"jit: {sum(s['recompiles'].values())} executor + "
           f"{sum(s['map_compiles'].values())} map-builder compiles "
           f"across {len(buckets)} buckets "
-          f"({sum(warm['recompiles'].values())} during warmup)")
+          f"({sum(warm['recompiles'].values())} during a {warm_s:.1f} s "
+          f"warmup)")
     print(f"map cache: {s['map_cache']['hits']} hits / "
           f"{s['map_cache']['misses']} misses")
     sc = s["scene_tables"]
@@ -211,8 +218,7 @@ def main(argv=None):
         path = obs.export(obs.get_tracer(), args.trace)
         tr = obs.get_tracer().snapshot()
         print(f"trace: {tr['spans']} spans + {tr['events']} events -> {path}"
-              + (f" (+ XLA profile in {args.trace}.xprof/)"
-                 if profiling else ""))
+              + f" (+ XLA profile in {args.trace}.xprof/)")
     if fleet:
         engine.close()
 

@@ -53,8 +53,7 @@ def main():
     ap.add_argument("--trace", default=None, metavar="OUT",
                     help="trace the training loop: Chrome trace-event JSON "
                          "(or .jsonl event log) with per-step/checkpoint "
-                         "spans, plus an XLA profile in OUT.xprof/ when the "
-                         "jax profiler is available")
+                         "spans, plus an XLA profile in OUT.xprof/")
     args = ap.parse_args()
 
     cfg = cfgbase.get_arch(args.arch)
@@ -98,8 +97,8 @@ def main():
     if args.trace:
         obs.enable()
     profiler = (obs.jax_profile(args.trace + ".xprof")
-                if args.trace else contextlib.nullcontext(False))
-    with profiler as profiling:
+                if args.trace else contextlib.nullcontext())
+    with profiler:
         params, state, report = train_loop(step, params, state, wrap(data),
                                            lcfg)
     print(f"done: {report.steps_run} steps, final metrics {report.last_metrics}, "
@@ -109,8 +108,7 @@ def main():
         path = obs.export(obs.get_tracer(), args.trace)
         snap = obs.get_tracer().snapshot()
         print(f"trace: {snap['spans']} spans -> {path}"
-              + (f" (+ XLA profile in {args.trace}.xprof/)"
-                 if profiling else ""))
+              + f" (+ XLA profile in {args.trace}.xprof/)")
 
 
 if __name__ == "__main__":

@@ -1,49 +1,23 @@
-"""Optional XLA-level profiling behind the same ``--trace`` flag.
+"""XLA-level profiling behind the same ``--trace`` flag.
 
 ``jax_profile(logdir)`` brackets a region with
-``jax.profiler.start_trace``/``stop_trace`` when the running jax has them
-(capability-probed like the pallas skips in tests/conftest.py), writing a
-TensorBoard/XProf trace next to the repo's own Chrome trace — on TPU that
-is the free XLA-level view of the same run.  On runtimes without the API,
-or when the profiler itself fails (some CPU builds), the context manager
-degrades to a no-op rather than taking down serving.
+``jax.profiler.start_trace``/``stop_trace``, writing a TensorBoard/XProf
+trace next to the repo's own Chrome trace — on TPU that is the device-side
+view of the same run.
 
 jax is imported lazily so ``repro.obs`` itself stays dependency-free.
 """
 from __future__ import annotations
 
 import contextlib
-import warnings
-
-
-def has_jax_profiler() -> bool:
-    """True iff the running jax exposes start_trace/stop_trace."""
-    try:
-        import jax.profiler
-    except Exception:  # pragma: no cover - jax missing entirely
-        return False
-    return (hasattr(jax.profiler, "start_trace")
-            and hasattr(jax.profiler, "stop_trace"))
 
 
 @contextlib.contextmanager
 def jax_profile(logdir: str):
-    """Bracket a region with the jax profiler when available; yields True
-    when a trace is actually being captured, False on the no-op path."""
-    if not has_jax_profiler():
-        yield False
-        return
+    """Capture a jax profiler trace of the enclosed region into ``logdir``."""
     import jax.profiler
+    jax.profiler.start_trace(logdir)
     try:
-        jax.profiler.start_trace(logdir)
-    except Exception as e:  # pragma: no cover - backend-dependent
-        warnings.warn(f"jax profiler unavailable ({e}); continuing untraced")
-        yield False
-        return
-    try:
-        yield True
+        yield
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:  # pragma: no cover - backend-dependent
-            warnings.warn(f"jax profiler stop failed ({e})")
+        jax.profiler.stop_trace()

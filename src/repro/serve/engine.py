@@ -141,6 +141,14 @@ LATENCY_WINDOW = 8192
 PHASE_WINDOW = 4096
 
 
+def _spec_of(leaf):
+    """Shape/dtype/placement of a jit argument leaf, without its data."""
+    if isinstance(leaf, jax.Array):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=leaf.sharding)
+    return leaf
+
+
 def percentiles_ms(values) -> Tuple[Optional[float], Optional[float]]:
     """(p50, p95) of a latency window — ``(None, None)`` when nothing was
     recorded, so an idle worker is distinguishable from an infinitely fast
@@ -416,6 +424,8 @@ class Engine:
         #: (kind, rung) marks queued by trace-time side effects, drained by
         #: the jit wrappers into structured ``compile`` trace events
         self._compile_marks: List[tuple] = []
+        #: (kind, rung) → (jitted stage, arg shapes) of its last compile
+        self._compiled: Dict[tuple, tuple] = {}
         # per-scene builds jit once per rung of a small capacity ladder
         # (scene sizes vary request to request; exact-size eager builds
         # would recompile every op per distinct size)
@@ -471,9 +481,20 @@ class Engine:
                     obs.event("compile", kind=k, rung=c,
                               device=self.device_name,
                               wall_ms=round(wall_ms, 3))
+                self._compiled[(kind, cap)] = (jfn, jax.tree.map(_spec_of,
+                                                                 args))
             return out
 
         return wrapper
+
+    def compiled_text(self, kind: str, cap: int) -> str:
+        """Optimized HLO of stage ``kind`` ("executor", "map_builder",
+        "plan_builder", "scene_builder", …) as last compiled for rung
+        ``cap`` — e.g. to check that a Pallas plan really lowered to
+        ``tpu_custom_call`` kernels.  Hits jax's caches: no retrace, and no
+        compile counter moves."""
+        jfn, specs = self._compiled[(kind, cap)]
+        return jfn.lower(*specs).compile().as_text()
 
     # ------------------------------------------------------------------ jit
     def _builder_for(self, cap: int) -> Callable:

@@ -238,6 +238,8 @@ def worker_main(argv=None) -> None:
     ap.add_argument("--plans", default=None)
     ap.add_argument("--devices", type=int, default=1)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg = ServiceConfig.from_dict(json.loads(args.config))
     FleetWorker(args.arch, cfg, plans=args.plans,
                 devices=args.devices).serve_forever(args.port)
@@ -410,12 +412,51 @@ def _src_pythonpath() -> str:
     return src + (os.pathsep + current if current else "")
 
 
+def _local_chips() -> int:
+    """TPU chips a locally spawned worker would claim, read off the PCI bus
+    without starting a jax backend — 0 where ``JAX_PLATFORMS`` keeps the
+    workers off the TPU."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def _leave_chips_to_workers(hosts: int) -> None:
+    """One process per chip.  A TPU chip belongs to the first process that
+    starts a jax backend on it; a second one fails or hangs.  On a host with
+    chips, refuse more than one local worker, refuse when this process
+    already holds the TPU, and otherwise keep this front end — which only
+    packs and unpacks — on the CPU backend, leaving the chips to the one
+    spawned worker."""
+    chips = _local_chips()
+    if not chips:
+        return
+    if hosts > 1:
+        raise RuntimeError(
+            f"{hosts} local fleet workers on a host with {chips} TPU chips "
+            f"would all claim the same chips; spawn one worker (it routes "
+            f"over the host's chips with devices_per_host) or start workers "
+            f"on separate hosts and pass their addresses")
+    import jax
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() and "tpu" in xla_bridge.backends():
+        raise RuntimeError(
+            "this process already holds the TPU, so a spawned fleet worker "
+            "cannot claim it; create the FleetFrontend before any jax "
+            "computation")
+    jax.config.update("jax_platforms", "cpu")
+
+
 class FleetFrontend:
     """Host-level ``SparseService``: route scene groups to worker hosts.
 
     arch: model architecture, as for ``Engine``.
     hosts: an int N — spawn N localhost worker processes — or a list of
-        ``(host, port)`` addresses of already-running workers.
+        ``(host, port)`` addresses of already-running workers.  On a host
+        with TPU chips only one local worker may be spawned (see
+        ``_leave_chips_to_workers``).
     config: the ``ServiceConfig`` every worker serves with (shipped to
         spawned workers as JSON; remote workers must be started with the
         same config or bit-identity is forfeit).
@@ -467,6 +508,7 @@ class FleetFrontend:
         self._closed = False
         if isinstance(hosts, int):
             assert hosts >= 1, hosts
+            _leave_chips_to_workers(hosts)
             procs = [self._spawn_worker() for _ in range(hosts)]
             for proc in procs:
                 self._attach(self._handshake(proc))

@@ -31,20 +31,11 @@ def _dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:
     return q.astype(jnp.float32) * scale
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static size of a named mapped axis.  ``jax.lax.axis_size`` only
-    exists in newer jax; older runtimes (0.4.37 CI) read the axis frame."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)   # int on 0.4.x, frame later
-    return frame if isinstance(frame, int) else frame.size
-
-
 def compressed_all_reduce_mean(x: jax.Array, axis_name: str) -> jax.Array:
     """Int8 two-phase all-reduce along ``axis_name`` (call inside shard_map).
 
     x: any shape; flattened internally; returns mean over the axis."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     flat = x.reshape(-1).astype(jnp.float32)
     pad = (-flat.shape[0]) % n
     flat = jnp.pad(flat, (0, pad))

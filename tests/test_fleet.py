@@ -355,6 +355,34 @@ def test_fleet_route_skips_dead_hosts():
         fl._route(64)
 
 
+# ----------------------------------------------------- one process per chip
+
+@pytest.fixture
+def chip_host(monkeypatch):
+    """Pretend this host has TPU chips; spawning a worker fails the test."""
+    from repro.serve import fleet as fleet_mod
+
+    def spawn(self):
+        raise AssertionError("spawned a worker on a refused chip host")
+    monkeypatch.setattr(fleet_mod, "_local_chips", lambda: 4)
+    monkeypatch.setattr(FleetFrontend, "_spawn_worker", spawn)
+
+
+def test_fleet_refuses_local_workers_sharing_chips(chip_host):
+    with pytest.raises(RuntimeError, match="would all claim the same chips"):
+        FleetFrontend("minkunet_kitti", hosts=2,
+                      config=service_mod.ServiceConfig(buckets=(256,)))
+
+
+def test_fleet_refuses_when_front_end_holds_the_tpu(chip_host, monkeypatch):
+    from jax._src import xla_bridge
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: True)
+    monkeypatch.setattr(xla_bridge, "backends", lambda: {"tpu": None})
+    with pytest.raises(RuntimeError, match="already holds the TPU"):
+        FleetFrontend("minkunet_kitti", hosts=1,
+                      config=service_mod.ServiceConfig(buckets=(256,)))
+
+
 # --------------------------------------------------- worker ops (in-process)
 
 def test_fleet_worker_handle_ops(engine_ref):

@@ -85,8 +85,8 @@ def test_implicit_gemm_worklist_bit_identical_to_dense(splits, sort):
     """Tile skipping changes the launch geometry, not the math: the
     worklist kernel visits the occupied (tile, δ) pairs in the same order
     the dense grid's gated steps run them, so the two are *bit*-identical
-    (same float add sequence) — with ad-hoc occupancy, with the occupancy
-    fused into the split plan, and through the traced-occupancy fallback."""
+    (same float add sequence) — with ad-hoc occupancy and with the
+    occupancy fused into the split plan.  Under jit it refuses."""
     stx = random_tensor(11, n=90, cap=128, channels=8, extent=7)
     kmap = km.build_kmap(stx, 3, 1)
     w = jax.random.normal(jax.random.PRNGKey(1), (27, 8, 16)) * 0.3
@@ -98,12 +98,13 @@ def test_implicit_gemm_worklist_bit_identical_to_dense(splits, sort):
         wl = implicit_gemm(stx.feats, w, kmap, p, tile_m=16, tile_n=8,
                            worklist=True, interpret=True)
         assert jnp.array_equal(dense, wl)
-    # under jit the occupancy is a tracer: no concrete worklist to compact,
-    # so the wrapper falls back to the dense grid — still identical
+    # under jit the occupancy is a tracer: there is no concrete worklist to
+    # compact, and the wrapper refuses rather than run the dense grid
     jitted = jax.jit(lambda x, w_: implicit_gemm(
         x, w_, kmap, plan, tile_m=16, tile_n=8, worklist=True,
         interpret=True))
-    assert jnp.array_equal(dense, jitted(stx.feats, w))
+    with pytest.raises(ValueError, match="worklist"):
+        jitted(stx.feats, w)
 
 
 # ---------------------------------------------------------------------------

@@ -47,7 +47,7 @@ from repro import obs
 from repro.serve.bucketing import BucketLadder
 from repro.serve.engine import ARCHS, Engine, EngineStats
 from repro.serve.router import DeviceRouter
-from repro.serve.workload import lidar_stream, stream_bound
+from repro.serve.workload import lidar_stream
 
 
 def _ms(v) -> str:
@@ -348,9 +348,17 @@ def run_fleet(tiny: bool = False, hosts: int = 2):
     the same rows measure scale-out."""
     import numpy as np
 
-    from repro.serve.fleet import FleetFrontend
+    from repro.serve.fleet import FleetFrontend, local_tpu_chips
     from repro.serve.service import ServiceConfig
 
+    if local_tpu_chips():
+        # the spawned worker holds the chips, so the in-process tiers could
+        # only run on the CPU — and a TPU-vs-CPU ratio prices nothing
+        raise RuntimeError(
+            "fleet_serving compares a spawned worker with in-process Engine "
+            "and DeviceRouter tiers, which cannot share this host's TPU "
+            "chips with it; run it with JAX_PLATFORMS=cpu (every tier on "
+            "the CPU) or on a host without chips")
     arch = "minkunet_kitti"
     if tiny:
         count, n_range = 6, (80, 400)
@@ -361,14 +369,12 @@ def run_fleet(tiny: bool = False, hosts: int = 2):
         ladder = BucketLadder((512, 1024, 2048), max_batch=4)
         reps = 3
     channels = ARCHS[arch].in_channels_of(ARCHS[arch].default_config)
-    cfg = ServiceConfig.from_ladder(ladder, spatial_bound=stream_bound())
-    # the fleet first: its workers spawn before this process starts a jax
-    # backend (the in-process tiers and lidar_stream below use jax)
+    scenes, bound = lidar_stream(0, count, channels, n_range=n_range)
+    cfg = ServiceConfig.from_ladder(ladder, spatial_bound=bound)
+    eng = Engine(arch, config=cfg)
+    rt = DeviceRouter(arch, devices=jax.device_count(), config=cfg)
     fl = FleetFrontend(arch, hosts=hosts, config=cfg)
     try:
-        scenes, _ = lidar_stream(0, count, channels, n_range=n_range)
-        eng = Engine(arch, config=cfg)
-        rt = DeviceRouter(arch, devices=jax.device_count(), config=cfg)
         warm = {}
         for tag, svc in (("engine", eng), ("router", rt), ("fleet", fl)):
             svc.warmup()

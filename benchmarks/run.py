@@ -89,10 +89,7 @@ def main(argv=None) -> None:
         ("fig8_generator", bench_generator.run),
     ]
     # Opt-in suites: spawn subprocesses (localhost fleet workers), so they
-    # run only when explicitly named in --only, never by default — and
-    # alone: the workers must claim the chip before this process starts a
-    # jax backend, and on a TPU host the front end then stays on the CPU,
-    # where any other suite would run too.
+    # run only when explicitly named in --only, never by default.
     opt_in = [
         ("fleet_serving", bench_serving.run_fleet),
     ]
@@ -101,14 +98,11 @@ def main(argv=None) -> None:
         unknown = keep - {name for name, _ in suites + opt_in}
         if unknown:
             raise SystemExit(f"unknown suites: {sorted(unknown)}")
-        if len(keep) > 1 and keep & {name for name, _ in opt_in}:
-            raise SystemExit(f"{sorted(keep)}: an opt-in suite spawns "
-                             f"workers and runs alone")
         suites = [(n, f) for n, f in suites + opt_in if n in keep]
 
     print("name,us_per_call,derived")
     failures = []
-    report = {"suites": {}}
+    report = {"meta": _metadata(args.tiny), "suites": {}}
     for name, fn in suites:
         start = len(common.RECORDS)
         t0 = time.perf_counter()
@@ -134,7 +128,6 @@ def main(argv=None) -> None:
             "rows": rows,
         }
     report["failures"] = failures
-    report["meta"] = _metadata(args.tiny)
 
     if args.out:
         with open(args.out, "w") as f:

@@ -260,9 +260,10 @@ def main(argv=None) -> int:
         train_phase(args.seed)
     else:
         router_phase(args.seed, args.chips)
+    # count: the chips this run used, not every chip the host shows
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
-        "count": len(jax.devices())}}), flush=True)
+        "count": args.chips}}), flush=True)
     return 0
 
 

@@ -22,6 +22,8 @@ import argparse
 import contextlib
 import time
 
+import jax
+
 from repro import obs
 from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import ARCHS, Engine
@@ -29,7 +31,7 @@ from repro.serve.fleet import FleetFrontend
 from repro.serve.plans import PlanRegistry
 from repro.serve.router import DeviceRouter
 from repro.serve.service import ServiceConfig
-from repro.serve.workload import lidar_stream, stream_bound
+from repro.serve.workload import lidar_stream
 
 
 def build_service(arch: str, buckets, max_batch: int, spatial_bound: int,
@@ -112,6 +114,10 @@ def main(argv=None):
                          "scene exceeds this age; doubles as the per-request "
                          "SLO reported in summary()['slo']")
     args = ap.parse_args(argv)
+    if args.hosts > 1:
+        # a fleet front end only packs and unpacks: keep this process off
+        # the accelerator so its spawned worker can claim the chip
+        jax.config.update("jax_platforms", "cpu")
     enable_compile_cache()
 
     if args.tiny:
@@ -121,17 +127,14 @@ def main(argv=None):
 
     binding = ARCHS[args.arch]
     channels = binding.in_channels_of(binding.default_config)
-    # the service before the stream: a fleet front end spawns its workers
-    # before this process starts a jax backend (lidar_stream uses jax), so
-    # a spawned worker can claim the chip
-    engine = build_service(args.arch, buckets, args.max_batch, stream_bound(),
+    scenes, bound = lidar_stream(args.seed, args.scenes, channels,
+                                 n_range=(args.min_points, args.max_points))
+    engine = build_service(args.arch, buckets, args.max_batch, bound,
                            plans_path=args.plans, seed=args.seed,
                            map_strategy=args.map_strategy,
                            devices=args.devices, hosts=args.hosts,
                            max_wait_ms=args.max_wait_ms,
                            replication=args.replication)
-    scenes, _ = lidar_stream(args.seed, args.scenes, channels,
-                             n_range=(args.min_points, args.max_points))
     sharded = isinstance(engine, DeviceRouter)
     fleet = isinstance(engine, FleetFrontend)
     if args.trace:

@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import glob
 import json
 import os
 import socket
@@ -412,25 +413,48 @@ def _src_pythonpath() -> str:
     return src + (os.pathsep + current if current else "")
 
 
-def _local_chips() -> int:
-    """TPU chips a locally spawned worker would claim, read off the PCI bus
-    without starting a jax backend — 0 where ``JAX_PLATFORMS`` keeps the
-    workers off the TPU."""
+#: PCI vendor id of Google devices, and the device ids of its TPU chips
+#: (v3, v4, v5p, v5e, v6e, 7x), as the host's PCI bus lists them.
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = frozenset(
+    {"0x0027", "0x005e", "0x0062", "0x0063", "0x006f", "0x0076"})
+
+
+def local_tpu_chips() -> int:
+    """TPU chips a locally spawned worker would claim, counted on the PCI bus
+    without starting a jax backend — 0 where ``JAX_PLATFORMS`` (which
+    spawned workers inherit) keeps the workers off the TPU."""
     platforms = os.environ.get("JAX_PLATFORMS", "")
     if platforms and "tpu" not in platforms.split(","):
         return 0
-    from jax._src import hardware_utils
-    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+    chips = 0
+    for dev in glob.glob("/sys/bus/pci/devices/*"):
+        try:
+            with open(os.path.join(dev, "vendor")) as f:
+                vendor = f.read().strip()
+            with open(os.path.join(dev, "device")) as f:
+                device = f.read().strip()
+        except OSError:
+            continue
+        chips += vendor == _GOOGLE_PCI_VENDOR and device in _TPU_PCI_DEVICES
+    return chips
 
 
-def _leave_chips_to_workers(hosts: int) -> None:
+def _front_end_platforms() -> str:
+    """The jax platforms this process is configured for ("" = any)."""
+    import jax
+    return jax.config.jax_platforms or ""
+
+
+def _check_local_spawn(hosts: int) -> None:
     """One process per chip.  A TPU chip belongs to the first process that
     starts a jax backend on it; a second one fails or hangs.  On a host with
-    chips, refuse more than one local worker, refuse when this process
-    already holds the TPU, and otherwise keep this front end — which only
-    packs and unpacks — on the CPU backend, leaving the chips to the one
-    spawned worker."""
-    chips = _local_chips()
+    chips, refuse more than one local worker, and refuse unless this front
+    end — which only packs and unpacks — is configured off the TPU, so the
+    chips are left to the one spawned worker.  Nothing here changes this
+    process's jax configuration: the caller moves it to the CPU at its entry
+    point (``serve_sparse --hosts`` does)."""
+    chips = local_tpu_chips()
     if not chips:
         return
     if hosts > 1:
@@ -439,14 +463,13 @@ def _leave_chips_to_workers(hosts: int) -> None:
             f"would all claim the same chips; spawn one worker (it routes "
             f"over the host's chips with devices_per_host) or start workers "
             f"on separate hosts and pass their addresses")
-    import jax
-    from jax._src import xla_bridge
-    if xla_bridge.backends_are_initialized() and "tpu" in xla_bridge.backends():
+    platforms = _front_end_platforms()
+    if not platforms or "tpu" in platforms.split(","):
         raise RuntimeError(
-            "this process already holds the TPU, so a spawned fleet worker "
-            "cannot claim it; create the FleetFrontend before any jax "
-            "computation")
-    jax.config.update("jax_platforms", "cpu")
+            f"this process may hold the TPU its spawned fleet worker needs "
+            f"(jax_platforms={platforms!r}); before any jax computation, "
+            f"call jax.config.update('jax_platforms', 'cpu') in the front "
+            f"end only, or start the worker elsewhere and pass its address")
 
 
 class FleetFrontend:
@@ -455,8 +478,8 @@ class FleetFrontend:
     arch: model architecture, as for ``Engine``.
     hosts: an int N — spawn N localhost worker processes — or a list of
         ``(host, port)`` addresses of already-running workers.  On a host
-        with TPU chips only one local worker may be spawned (see
-        ``_leave_chips_to_workers``).
+        with TPU chips only one local worker may be spawned, from a front
+        end configured off the TPU (see ``_check_local_spawn``).
     config: the ``ServiceConfig`` every worker serves with (shipped to
         spawned workers as JSON; remote workers must be started with the
         same config or bit-identity is forfeit).
@@ -508,7 +531,7 @@ class FleetFrontend:
         self._closed = False
         if isinstance(hosts, int):
             assert hosts >= 1, hosts
-            _leave_chips_to_workers(hosts)
+            _check_local_spawn(hosts)
             procs = [self._spawn_worker() for _ in range(hosts)]
             for proc in procs:
                 self._attach(self._handshake(proc))

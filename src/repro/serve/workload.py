@@ -16,13 +16,6 @@ from repro.data.synthetic import lidar_scene
 from repro.serve.batcher import Scene, SceneDelta, apply_delta, scene_from_tensor
 
 
-def stream_bound(extent: float = 50.0, voxel: float = 0.4) -> int:
-    """The spatial bound every ``lidar_stream(extent=, voxel=)`` scene
-    respects — computable without generating (or touching a device)."""
-    margin = 8.0
-    return int(np.ceil((extent + margin) / voxel)) + 2
-
-
 def lidar_stream(seed: int, count: int, channels: int,
                  n_range: Tuple[int, int] = (200, 1200),
                  extent: float = 50.0, voxel: float = 0.4) -> Tuple[List[Scene], int]:
@@ -34,7 +27,8 @@ def lidar_stream(seed: int, count: int, channels: int,
     """
     rng = np.random.default_rng(seed)
     lo, hi = n_range
-    bound = stream_bound(extent, voxel)
+    margin = 8.0
+    bound = int(np.ceil((extent + margin) / voxel)) + 2
     scenes: List[Scene] = []
     for i in range(count):
         n = int(rng.integers(lo, hi + 1))

@@ -364,8 +364,12 @@ def chip_host(monkeypatch):
 
     def spawn(self):
         raise AssertionError("spawned a worker on a refused chip host")
-    monkeypatch.setattr(fleet_mod, "_local_chips", lambda: 4)
+    monkeypatch.setattr(fleet_mod, "local_tpu_chips", lambda: 4)
     monkeypatch.setattr(FleetFrontend, "_spawn_worker", spawn)
+
+
+class _Spawned(Exception):
+    pass
 
 
 def test_fleet_refuses_local_workers_sharing_chips(chip_host):
@@ -374,13 +378,54 @@ def test_fleet_refuses_local_workers_sharing_chips(chip_host):
                       config=service_mod.ServiceConfig(buckets=(256,)))
 
 
-def test_fleet_refuses_when_front_end_holds_the_tpu(chip_host, monkeypatch):
-    from jax._src import xla_bridge
-    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: True)
-    monkeypatch.setattr(xla_bridge, "backends", lambda: {"tpu": None})
-    with pytest.raises(RuntimeError, match="already holds the TPU"):
+@pytest.mark.parametrize("platforms", ["", "tpu", "cpu,tpu"])
+def test_fleet_refuses_when_front_end_holds_the_tpu(chip_host, monkeypatch,
+                                                    platforms):
+    from repro.serve import fleet as fleet_mod
+    monkeypatch.setattr(fleet_mod, "_front_end_platforms", lambda: platforms)
+    with pytest.raises(RuntimeError, match="may hold the TPU"):
         FleetFrontend("minkunet_kitti", hosts=1,
                       config=service_mod.ServiceConfig(buckets=(256,)))
+
+
+def test_fleet_spawns_one_worker_from_a_cpu_front_end(chip_host, monkeypatch):
+    """A front end configured off the TPU spawns its one worker, and the
+    constructor leaves the process's jax configuration as it found it."""
+    def spawn(self):
+        raise _Spawned
+    monkeypatch.setattr(FleetFrontend, "_spawn_worker", spawn)
+    before = jax.config.jax_platforms
+    assert "tpu" not in before.split(",")
+    with pytest.raises(_Spawned):
+        FleetFrontend("minkunet_kitti", hosts=1,
+                      config=service_mod.ServiceConfig(buckets=(256,)))
+    assert jax.config.jax_platforms == before
+
+
+def test_local_tpu_chips_reads_the_pci_bus(tmp_path, monkeypatch):
+    from repro.serve import fleet as fleet_mod
+    for name, vendor, device in [("a", "0x1ae0", "0x0063"),   # v5e chip
+                                 ("b", "0x1ae0", "0x0063"),
+                                 ("c", "0x1ae0", "0x0001"),   # not a TPU
+                                 ("d", "0x8086", "0x0063")]:  # not Google
+        (tmp_path / name).mkdir()
+        (tmp_path / name / "vendor").write_text(vendor + "\n")
+        (tmp_path / name / "device").write_text(device + "\n")
+    (tmp_path / "e").mkdir()                                  # no ids
+    monkeypatch.setattr(fleet_mod.glob, "glob",
+                        lambda _: sorted(str(p) for p in tmp_path.iterdir()))
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert fleet_mod.local_tpu_chips() == 2
+    # spawned workers inherit JAX_PLATFORMS: kept off the TPU, they claim
+    # no chip
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert fleet_mod.local_tpu_chips() == 0
+
+
+def test_fleet_bench_refuses_on_a_chip_host(chip_host):
+    from benchmarks import bench_serving
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        bench_serving.run_fleet(tiny=True)
 
 
 # --------------------------------------------------- worker ops (in-process)

@@ -349,70 +349,82 @@ def build_kmap(x: SparseTensor, kernel_size: int, stride: int = 1,
     cap_in = x.capacity
     spec = cache.spec if cache is not None else hashing.key_spec_for(
         d, x.batch_bound, x.spatial_bound)
-    if cache is not None:
-        table = cache.table(x)
-    else:
-        table = CoordTable.build(x.coords, x.valid_mask, spec)
-
-    child_table = None
-    if transposed:
-        assert out_coords is not None and n_out is not None
-        out_stride = t // stride
-        assert out_stride >= 1
-        n_out_cap = out_capacity or out_coords.shape[0]
-        out_coords = out_coords[:n_out_cap]
-        # neighbor input coord = out + δ * out_stride mirrored (q = p - δ·t_f)
-        delta_scale = -out_stride
-    elif stride == 1:
-        out_coords, n_out = x.coords, x.num_valid
-        out_stride = t
-        n_out_cap = out_capacity or cap_in
-        out_coords = out_coords[:n_out_cap]
-        delta_scale = t
-    else:
-        out_stride = t * stride
-        n_out_cap = out_capacity or cap_in
-        pre = cache.table_for_stride(out_stride) if cache is not None else None
-        use_pre = pre is not None and pre[0].n == n_out_cap
-        uniq = None if use_pre else \
-            _unique_from_keys(table, out_stride, n_out_cap)
-        if use_pre:
-            # composed child table (scene-granular serving reuse): the
-            # output coords ARE the unpacked table keys — no unique argsort
-            child_table, n_out = pre
-            n_out = jnp.asarray(n_out, jnp.int32)
-            key_valid = jnp.arange(n_out_cap) < n_out
-            out_coords = jnp.where(key_valid[:, None],
-                                   hashing.unpack_keys(child_table.sorted_keys,
-                                                       spec), INVALID_COORD)
-        elif uniq is not None:
-            out_coords, n_out, child_table = uniq
+    # the sorted coordinate tables (and a strided map's unique output
+    # grid) apart from the neighbour search, by scope in a device trace
+    with jax.named_scope("table"):
+        if cache is not None:
+            table = cache.table(x)
         else:
-            # non-power-of-two stride (or too-narrow fields): fall back to
-            # the multi-word grid dedup — correctness over speed off the
-            # happy path
-            grid = jnp.concatenate(
-                [x.coords[:, :1],
-                 (x.coords[:, 1:] // out_stride) * out_stride], axis=1)
-            grid = jnp.where(x.valid_mask[:, None], grid, INVALID_COORD)
-            out_coords, n_out = _unique_coords(grid, x.valid_mask, n_out_cap)
-        delta_scale = t
+            table = CoordTable.build(x.coords, x.valid_mask, spec)
 
-    out_valid = jnp.arange(n_out_cap) < n_out
+        child_table = None
+        if transposed:
+            assert out_coords is not None and n_out is not None
+            out_stride = t // stride
+            assert out_stride >= 1
+            n_out_cap = out_capacity or out_coords.shape[0]
+            out_coords = out_coords[:n_out_cap]
+            # neighbor input coord = out + δ * out_stride mirrored
+            # (q = p - δ·t_f)
+            delta_scale = -out_stride
+        elif stride == 1:
+            out_coords, n_out = x.coords, x.num_valid
+            out_stride = t
+            n_out_cap = out_capacity or cap_in
+            out_coords = out_coords[:n_out_cap]
+            delta_scale = t
+        else:
+            out_stride = t * stride
+            n_out_cap = out_capacity or cap_in
+            pre = (cache.table_for_stride(out_stride) if cache is not None
+                   else None)
+            use_pre = pre is not None and pre[0].n == n_out_cap
+            uniq = None if use_pre else \
+                _unique_from_keys(table, out_stride, n_out_cap)
+            if use_pre:
+                # composed child table (scene-granular serving reuse): the
+                # output coords ARE the unpacked table keys — no unique
+                # argsort
+                child_table, n_out = pre
+                n_out = jnp.asarray(n_out, jnp.int32)
+                key_valid = jnp.arange(n_out_cap) < n_out
+                out_coords = jnp.where(
+                    key_valid[:, None],
+                    hashing.unpack_keys(child_table.sorted_keys, spec),
+                    INVALID_COORD)
+            elif uniq is not None:
+                out_coords, n_out, child_table = uniq
+            else:
+                # non-power-of-two stride (or too-narrow fields): fall back to
+                # the multi-word grid dedup — correctness over speed off the
+                # happy path
+                grid = jnp.concatenate(
+                    [x.coords[:, :1],
+                     (x.coords[:, 1:] // out_stride) * out_stride], axis=1)
+                grid = jnp.where(x.valid_mask[:, None], grid, INVALID_COORD)
+                out_coords, n_out = _unique_coords(grid, x.valid_mask,
+                                                   n_out_cap)
+            delta_scale = t
 
-    # Output-stationary map: ONE flattened batched lookup over all K^D·N
-    # shifted queries.  Padded/out-of-range rows pack to the MISS key.
-    shifts = np.concatenate([np.zeros((kd, 1), np.int32),
-                             offs * np.int32(delta_scale)], axis=1)
-    q = out_coords[None, :, :] + jnp.asarray(shifts)[:, None, :]  # (KD, N, 1+D)
-    qkeys = hashing.pack_keys(q.reshape(kd * n_out_cap, d + 1), spec, query=True)
-    m_out = table.lookup_keys(qkeys).reshape(kd, n_out_cap).T
-    m_out = jnp.where(out_valid[:, None], m_out, -1)
+    with jax.named_scope("search"):
+        out_valid = jnp.arange(n_out_cap) < n_out
 
-    # Weight-stationary lists: one fused sort-free pass for all K^D offsets.
-    ws_in, ws_out, ws_count = _compact_ws(m_out)
+        # Output-stationary map: ONE flattened batched lookup over all K^D·N
+        # shifted queries.  Padded/out-of-range rows pack to the MISS key.
+        shifts = np.concatenate([np.zeros((kd, 1), np.int32),
+                                 offs * np.int32(delta_scale)], axis=1)
+        # (KD, N, 1+D)
+        q = out_coords[None, :, :] + jnp.asarray(shifts)[:, None, :]
+        qkeys = hashing.pack_keys(q.reshape(kd * n_out_cap, d + 1), spec,
+                                  query=True)
+        m_out = table.lookup_keys(qkeys).reshape(kd, n_out_cap).T
+        m_out = jnp.where(out_valid[:, None], m_out, -1)
 
-    bm = jnp.where(out_valid, _bitmask(m_out >= 0), 0)
+        # Weight-stationary lists: one fused sort-free pass for all K^D
+        # offsets.
+        ws_in, ws_out, ws_count = _compact_ws(m_out)
+
+        bm = jnp.where(out_valid, _bitmask(m_out >= 0), 0)
 
     kmap = KernelMap(m_out=m_out, out_coords=out_coords, n_out=jnp.asarray(n_out, jnp.int32),
                      ws_in=ws_in, ws_out=ws_out, ws_count=ws_count, bitmask=bm,

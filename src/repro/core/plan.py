@@ -275,6 +275,10 @@ def build_maps_from_specs(specs: Sequence[KmapSpec], st: SparseTensor,
     None) are adopted per out-stride so the strided maps skip their
     floor-grid unique argsorts too.  Levels absent from ``tables`` build
     normally — composition degrades gracefully, never changes results.
+
+    Each spec's ops trace under ``jax.named_scope("kmap.<kind>_s<s>")``
+    (``s``: the stride of the tensor the map is built on, as in its
+    ``ref``), so a device trace can say which map an op builds.
     """
     if cache is None:   # NOT `or`: an empty caller cache is falsy but wanted
         cache = MapCache.for_tensor(st)
@@ -290,18 +294,20 @@ def build_maps_from_specs(specs: Sequence[KmapSpec], st: SparseTensor,
     tensors = {st.stride: st}
     for ms in specs:
         cur = tensors[ms.tensor_stride]
-        if ms.kind == "sub":
-            maps[ms.ref] = build_kmap(cur, ms.kernel_size, 1, cache=cache)
-        elif ms.kind == "down":
-            kd = build_kmap(cur, ms.kernel_size, ms.stride, cache=cache)
-            maps[ms.ref] = kd
-            tensors[kd.out_stride] = SparseTensor(
-                coords=kd.out_coords,
-                feats=jnp.zeros((kd.capacity, 1), st.feats.dtype),
-                num_valid=kd.n_out, stride=kd.out_stride,
-                batch_bound=st.batch_bound, spatial_bound=st.spatial_bound)
-        else:  # "up"
-            maps[ms.ref] = transpose_kmap(maps[ms.transpose_of], cur)
+        with jax.named_scope(f"kmap.{ms.kind}_s{ms.tensor_stride}"):
+            if ms.kind == "sub":
+                maps[ms.ref] = build_kmap(cur, ms.kernel_size, 1, cache=cache)
+            elif ms.kind == "down":
+                kd = build_kmap(cur, ms.kernel_size, ms.stride, cache=cache)
+                maps[ms.ref] = kd
+                tensors[kd.out_stride] = SparseTensor(
+                    coords=kd.out_coords,
+                    feats=jnp.zeros((kd.capacity, 1), st.feats.dtype),
+                    num_valid=kd.n_out, stride=kd.out_stride,
+                    batch_bound=st.batch_bound,
+                    spatial_bound=st.spatial_bound)
+            else:  # "up"
+                maps[ms.ref] = transpose_kmap(maps[ms.transpose_of], cur)
     return maps
 
 
@@ -547,6 +553,9 @@ class NetworkPlan:
         plans: optional pre-built split plans keyed ``(map_ref, n_splits,
         sorted)`` (see ``split_plan_specs``); layers without an entry build
         their plan in-trace as before.
+
+        Each conv layer (with its norm) traces under
+        ``jax.named_scope("layer.<name>")``.
         """
         if maps is None:
             maps = self.build_maps(st)
@@ -561,11 +570,13 @@ class NetworkPlan:
                 fwd = lp.dataflow.fwd
                 plan = (plans or {}).get(
                     (lp.map_ref, fwd.effective_splits, fwd.sorted))
-                x = apply_conv(params[lp.name], x, maps[lp.map_ref],
-                               lp.dataflow, precision=lp.precision, plan=plan)
-                if lp.bn:
-                    x = bn_relu(params[f"{lp.name}_bn"], x, relu=lp.relu,
-                                mode=bn_mode)
+                with jax.named_scope(f"layer.{lp.name}"):
+                    x = apply_conv(params[lp.name], x, maps[lp.map_ref],
+                                   lp.dataflow, precision=lp.precision,
+                                   plan=plan)
+                    if lp.bn:
+                        x = bn_relu(params[f"{lp.name}_bn"], x, relu=lp.relu,
+                                    mode=bn_mode)
             elif kind == "push":
                 skips.append(x)
             elif kind == "concat":

@@ -10,8 +10,14 @@ visibility at request granularity.  One ``Tracer`` holds:
   monotonic clocks (``time.perf_counter_ns``), with a per-thread span
   stack so router worker threads interleave correctly: every record
   carries its thread id/name and its nesting depth *within that thread*.
+  A live span also enters a ``jax.profiler.TraceAnnotation`` named
+  ``repro.<name>``, so while a jax profiler trace is recording, every
+  span sits on the profiler's host plane on the same clock as the
+  device's operations (jax is imported by the first enabled span).
   ``record_span`` retroactively records an interval measured elsewhere
-  (queue waits: the submit timestamp predates the flush that observes it);
+  (queue waits: the submit timestamp predates the flush that observes
+  it); those stay host-only, since the profiler cannot be told about an
+  interval after it ended;
 * **instant events** — ``event("compile", rung=..., device=...)`` for
   point-in-time facts like jit recompiles, routing decisions, checkpoint
   writes;
@@ -22,8 +28,9 @@ visibility at request granularity.  One ``Tracer`` holds:
 
 A process-global default tracer starts **disabled** and compiles to
 no-ops: the disabled ``span()`` fast path returns one preallocated
-singleton, so instrumented hot paths pay a truthiness check and retain
-zero allocations (asserted in tests/test_obs.py).  Enable it with
+singleton, so instrumented hot paths pay a truthiness check, retain
+zero allocations and create no profiler annotation (asserted in
+tests/test_obs.py).  Enable it with
 ``enable()`` (or install your own via ``set_tracer``), export with
 ``repro.obs.export`` (Chrome trace-event JSON for Perfetto /
 ``chrome://tracing``, or a flat JSONL event log).
@@ -90,7 +97,7 @@ NOOP_SPAN = _NoopSpan()
 class _Span:
     """A live span context manager (enabled tracer only)."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -107,11 +114,16 @@ class _Span:
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
+        # imported here, not at module level: repro.obs imports no jax
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation("repro." + self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()

@@ -169,35 +169,6 @@ def summarize_phases(windows: Dict[str, Sequence[float]]) -> Dict[str, dict]:
     return out
 
 
-def _overlap_ns(host_ivs: Sequence[tuple], dev_ivs: Sequence[tuple]):
-    """(host_total, device_total, overlap) in ns of two interval sets, each
-    union-merged first — the pipeline's host-busy/device-busy/overlap
-    accounting (overlap ≈ 0 for a serial depth-1 loop by construction)."""
-    def merge(ivs):
-        out: List[list] = []
-        for a, b in sorted(ivs):
-            if out and a <= out[-1][1]:
-                out[-1][1] = max(out[-1][1], b)
-            else:
-                out.append([a, b])
-        return out
-
-    h, d = merge(host_ivs), merge(dev_ivs)
-    ht = sum(b - a for a, b in h)
-    dt = sum(b - a for a, b in d)
-    ov = 0
-    i = j = 0
-    while i < len(h) and j < len(d):
-        lo, hi = max(h[i][0], d[j][0]), min(h[i][1], d[j][1])
-        if hi > lo:
-            ov += hi - lo
-        if h[i][1] < d[j][1]:
-            i += 1
-        else:
-            j += 1
-    return ht, dt, ov
-
-
 @dataclasses.dataclass
 class EngineStats:
     submitted: int = 0
@@ -219,15 +190,16 @@ class EngineStats:
     scene_misses: int = 0        # cold scenes that built their own stack
     composed_batches: int = 0    # batch map stacks merge-composed, not built
     delta_merges: int = 0        # streaming frames that delta-merged a table
+    # scene builds (cold or delta): valid rows, and the scene-rung rows the
+    # build's neighbour search runs over
+    scene_rows: int = 0
+    scene_rung_rows: int = 0
     # flush triggers beyond the explicit flush() call
     deadline_flushes: int = 0    # max_wait_ms expiries
     count_flushes: int = 0       # flush_count threshold crossings
     deadline_cuts: int = 0       # batches cut early by deadline admission
     # pipelined-flush accounting (summary()['pipeline'])
     inflight_peak: int = 0       # max dispatched-but-undrained batches seen
-    host_busy_s: float = 0.0     # union of host pack/map/dispatch/unpack time
-    device_busy_s: float = 0.0   # union of dispatch→ready device windows
-    overlap_s: float = 0.0       # host-busy ∩ device-busy
     # per-phase duration windows (queue_wait/pack/map/execute/unpack/…) —
     # always on (a perf_counter pair + deque append per phase), independent
     # of whether the tracer is enabled
@@ -270,17 +242,13 @@ class EngineStats:
                              "misses": self.scene_misses,
                              "composed_batches": self.composed_batches,
                              "delta_merges": self.delta_merges,
+                             "rows": self.scene_rows,
+                             "rung_rows": self.scene_rung_rows,
                              "compiles": dict(self.scene_compiles)},
             "deadline_flushes": self.deadline_flushes,
             "count_flushes": self.count_flushes,
             "deadline_cuts": self.deadline_cuts,
-            "pipeline": {
-                "inflight_peak": self.inflight_peak,
-                "host_busy_s": self.host_busy_s,
-                "device_busy_s": self.device_busy_s,
-                "overlap_s": self.overlap_s,
-                "overlap_frac": (self.overlap_s / self.device_busy_s
-                                 if self.device_busy_s else 0.0)},
+            "pipeline": {"inflight_peak": self.inflight_peak},
             "phases": summarize_phases(self.phases),
             "slo": {"deadline_ms": self.slo_deadline_ms,
                     "measured": self.slo_measured,
@@ -445,8 +413,29 @@ class Engine:
     @contextlib.contextmanager
     def _phase(self, name: str, **attrs):
         """Time one phase of the hot path into BOTH sinks: a tracer span
-        (rich, nestable, exportable — no-op singleton when disabled) and
-        the always-on ``EngineStats.phases`` histogram window."""
+        (rich, nestable, exportable, on the profiler's host plane as
+        ``repro.<name>`` — no-op singleton when disabled) and the always-on
+        ``EngineStats.phases`` histogram window.
+
+        The phases, nested as they run:
+
+        * ``pack`` (``batch_pack`` inside), then ``map``: the batch's maps,
+          from ``compose_kmaps`` (per-scene ``scene_build`` of cold scenes,
+          then host composition) and ``compose_plans``, or from a jitted
+          ``map_build`` and ``plan_build``; then ``dispatch`` of the
+          executor, which returns without waiting;
+        * ``apply_delta`` (at ``submit_delta``): a streamed frame's new
+          scene made from its delta, on the host;
+        * ``scene_build`` / ``delta_merge`` (at ``submit_delta``) each hold
+          ``scene_wait``, the host blocked on the scene builder's outputs:
+          the device's map build plus any device work queued ahead of it
+          (an in-flight executor), and ``scene_fetch``, the device→host
+          copies of the scene's maps into a ``SceneEntry``;
+        * ``drain_wait``: the host blocked on a dispatched executor's
+          outputs, then ``unpack``.
+
+        ``execute`` (dispatch to ready) and ``queue_wait`` are recorded
+        after the fact, not through this context manager."""
         t0 = time.perf_counter()
         with obs.span(name, **attrs) as sp:
             yield sp
@@ -467,6 +456,8 @@ class Engine:
             self._compile_marks.append((kind, cap))
             return fn(*args)
 
+        # the XLA module (and the profiler's module events) read jit_<kind>
+        traced.__name__ = traced.__qualname__ = kind
         jfn = jax.jit(traced)
 
         def wrapper(*args):
@@ -603,6 +594,19 @@ class Engine:
             while len(self._scene_store) > self.scene_cache_size:
                 self._scene_store.popitem(last=False)
 
+    def _fetch_scene_entry(self, built, n: int, cap: int) -> SceneEntry:
+        """Wait for a scene builder's outputs (``scene_wait``), copy them
+        into a host ``SceneEntry`` (``scene_fetch``), and count the build's
+        valid and scene-rung rows."""
+        with self._phase("scene_wait", cap=cap):
+            maps, keys, order = jax.block_until_ready(built)
+        with self._phase("scene_fetch", cap=cap):
+            ent = scene_entry_from_arrays(self.nplan.map_specs, maps, n,
+                                          keys, order)
+        self.stats.scene_rows += n
+        self.stats.scene_rung_rows += cap
+        return ent
+
     def _scene_entry(self, scene: Scene) -> SceneEntry:
         with self._scene_lock:
             ent = self._scene_store.get(scene.digest)
@@ -613,10 +617,9 @@ class Engine:
         self.stats.scene_misses += 1
         cap = self._scene_ladder.select(scene.num_points)
         with self._phase("scene_build", cap=cap, points=scene.num_points):
-            maps, keys, order = self._scene_builder_for(cap)(
-                self._scene_tensor(scene, cap))
-            ent = scene_entry_from_arrays(self.nplan.map_specs, maps,
-                                          scene.num_points, keys, order)
+            ent = self._fetch_scene_entry(
+                self._scene_builder_for(cap)(self._scene_tensor(scene, cap)),
+                scene.num_points, cap)
             if self.map_strategy == "incremental":
                 # seed the stream's cell ladder so later deltas propagate
                 # down the pyramid incrementally instead of re-deriving it
@@ -722,7 +725,8 @@ class Engine:
                 f"delta adds a coord violating declared spatial_bound "
                 f"{self.batcher.spatial_bound}: max |coord| = "
                 f"{np.abs(delta.added_coords).max()}")
-        scene = apply_delta(prev, delta)
+        with self._phase("apply_delta", stream=stream):
+            scene = apply_delta(prev, delta)
         if (self.map_strategy == "incremental"
                 and scene.digest not in self._scene_store):
             with self._scene_lock:
@@ -765,14 +769,14 @@ class Engine:
                     else:
                         lad = cell_ladder(spec, mkeys, self._down_strides)
                     tabs = ladder_tables(spec, lad, cap)
-                    maps, k, o = self._scene_delta_builder_for(cap)(
-                        self._scene_tensor(scene, cap), jnp.asarray(keys),
-                        jnp.asarray(order),
-                        {s: jnp.asarray(t[0]) for s, t in tabs.items()},
-                        {s: jnp.asarray(t[2], jnp.int32)
-                         for s, t in tabs.items()})
-                    ent = scene_entry_from_arrays(self.nplan.map_specs, maps,
-                                                  n, k, o)
+                    ent = self._fetch_scene_entry(
+                        self._scene_delta_builder_for(cap)(
+                            self._scene_tensor(scene, cap), jnp.asarray(keys),
+                            jnp.asarray(order),
+                            {s: jnp.asarray(t[0]) for s, t in tabs.items()},
+                            {s: jnp.asarray(t[2], jnp.int32)
+                             for s, t in tabs.items()}),
+                        n, cap)
                     ent.ladder = lad
                     self._store_scene(scene.digest, ent)
                     self.stats.delta_merges += 1
@@ -868,14 +872,14 @@ class Engine:
     def _finish_group(self, batch: PackedBatch, out,
                       t_disp_ns: Optional[int] = None):
         """Block on a dispatched batch and unpack it into per-scene rows.
-        Returns ``(ready_timestamp_ns, per_scene_results)``.
 
         ``t_disp_ns`` (pipelined drains) backdates the "execute" span to
         dispatch-return so it covers the device-side window the host
         overlapped — recorded retroactively via ``obs.record_span`` because
         the host was busy with batch k+1 while it ran."""
         t0 = time.perf_counter_ns()
-        out_coords, out_feats, n_out = jax.block_until_ready(out)
+        with self._phase("drain_wait", bucket=batch.bucket):
+            out_coords, out_feats, n_out = jax.block_until_ready(out)
         t1 = time.perf_counter_ns()
         start = t0 if t_disp_ns is None else t_disp_ns
         self.stats.observe("execute", (t1 - start) / 1e6)
@@ -887,7 +891,7 @@ class Engine:
                                             int(n_out), self.out_stride)
         self.stats.batches += 1
         self.stats.completed += batch.num_scenes
-        return t1, per_scene
+        return per_scene
 
     def _run_pipeline(self, scene_groups: Sequence[Sequence[Scene]],
                       on_done: Callable,
@@ -906,33 +910,22 @@ class Engine:
         draining the oldest in-flight batch before the next dispatch.
         """
         inflight: "collections.deque" = collections.deque()
-        host_ivs: List[tuple] = []
-        dev_ivs: List[tuple] = []
 
         def drain_one():
             gi, batch, out, t_disp = inflight.popleft()
-            t_ready, per_scene = self._finish_group(batch, out, t_disp)
-            dev_ivs.append((t_disp, t_ready))
-            host_ivs.append((t_ready, time.perf_counter_ns()))  # unpack
-            on_done(gi, batch, per_scene)
+            on_done(gi, batch, self._finish_group(batch, out, t_disp))
 
         for gi, scenes in enumerate(scene_groups):
             while inflight and (len(inflight) >= self.max_inflight or
                                 (urgent is not None and urgent(inflight[0][0]))):
                 drain_one()
-            h0 = time.perf_counter_ns()
             batch, out = self._dispatch_group(scenes)
             t_disp = time.perf_counter_ns()
-            host_ivs.append((h0, t_disp))
             inflight.append((gi, batch, out, t_disp))
             if len(inflight) > self.stats.inflight_peak:
                 self.stats.inflight_peak = len(inflight)
         while inflight:
             drain_one()
-        ht, dt, ov = _overlap_ns(host_ivs, dev_ivs)
-        self.stats.host_busy_s += ht / 1e9
-        self.stats.device_busy_s += dt / 1e9
-        self.stats.overlap_s += ov / 1e9
 
     def _run_queue(self) -> Dict[int, SceneResult]:
         if not self._queue:

@@ -124,6 +124,8 @@ class RouterStats:
             "misses": sum(s.scene_misses for s in stats),
             "composed_batches": sum(s.composed_batches for s in stats),
             "delta_merges": sum(s.delta_merges for s in stats),
+            "rows": sum(s.scene_rows for s in stats),
+            "rung_rows": sum(s.scene_rung_rows for s in stats),
             "compiles": self._merge_counter("scene_compiles"),
         }
         devices = {}
@@ -147,8 +149,6 @@ class RouterStats:
         slo_measured = self.slo_measured + sum(s.slo_measured for s in stats)
         slo_misses = (self.slo_miss_count
                       + sum(s.slo_miss_count for s in stats))
-        device_busy = sum(s.device_busy_s for s in stats)
-        overlap = sum(s.overlap_s for s in stats)
         return {
             "schema_version": STATS_SCHEMA_VERSION,
             "scenes": completed,
@@ -168,11 +168,7 @@ class RouterStats:
             "deadline_cuts": sum(s.deadline_cuts for s in stats),
             "pipeline": {
                 "inflight_peak": max((s.inflight_peak for s in stats),
-                                     default=0),
-                "host_busy_s": sum(s.host_busy_s for s in stats),
-                "device_busy_s": device_busy,
-                "overlap_s": overlap,
-                "overlap_frac": overlap / device_busy if device_busy else 0.0},
+                                     default=0)},
             "phases": summarize_phases(windows),
             "slo": {
                 "deadline_ms": self.slo_deadline_ms,

@@ -166,6 +166,29 @@ def test_disabled_span_is_the_noop_singleton():
     assert obs.get_tracer().events() == []
 
 
+def test_enabled_span_writes_a_profiler_annotation(tmp_path):
+    """Under a jax profiler trace an enabled span is a ``repro.<name>``
+    event on a host plane of the ``.xplane.pb``; a disabled one is the
+    no-op singleton and writes nothing there."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("quiet"):         # default tracer: disabled
+            jnp.ones(3).block_until_ready()
+        assert obs.span("quiet") is obs.NOOP_SPAN
+        obs.enable()
+        with obs.span("x", bucket=512):
+            jnp.ones(3).block_until_ready()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    names = {(p.name, e.name) for p in ProfileData.from_file(str(path)).planes
+             for line in p.lines for e in line.events}
+    assert any(n == "repro.x" and p.startswith("/host:") for p, n in names)
+    assert not any(n == "repro.quiet" for _, n in names)
+    assert [s.name for s in obs.get_tracer().spans()] == ["x"]
+
+
 def test_disabled_span_retains_zero_allocations():
     def burst(n):
         for _ in range(n):
@@ -384,6 +407,92 @@ def test_summary_reconciles_with_trace(traced_serving):
     assert s["slo"]["deadline_ms"] == 50.0
     assert s["slo"]["measured"] == s["scenes"] == 6
     assert phase_counts["request"] == 6
+
+
+@pytest.fixture(scope="module")
+def traced_streaming():
+    """A tiny incremental engine under an enabled tracer: cold scenes on
+    the first frame, then streaming frames rebuilt through the delta
+    builder.  Records what each build should count, from the traffic."""
+    from repro.serve.bucketing import BucketLadder
+    from repro.serve.engine import Engine
+    from repro.serve.workload import churned_stream
+
+    tracer = obs.enable()
+    try:
+        eng = Engine("centerpoint_waymo",
+                     ladder=BucketLadder((512,), max_batch=4),
+                     spatial_bound=64, map_strategy="incremental")
+        frames, _ = churned_stream(5, streams=3, frames=3, channels=5,
+                                   n_range=(40, 80), extent=16.0, voxel=0.4)
+        built = []          # (valid rows, scene-rung capacity) per build
+        for t, frame in enumerate(frames):
+            for sid, scene, delta in frame:
+                if t == 0 or delta is not None:
+                    n = scene.num_points
+                    built.append((n, eng._scene_ladder.select(n)))
+                if delta is not None:
+                    eng.submit_delta(sid, delta)
+                else:
+                    eng.submit(scene, stream=sid)
+            eng.flush()
+        yield {"engine": eng, "tracer": tracer, "built": built}
+    finally:
+        obs.disable()
+
+
+def test_scene_wait_and_fetch_nest_once_per_build(traced_streaming):
+    """Every scene build, cold (``scene_build``) or delta
+    (``delta_merge``), records one ``scene_wait`` and one ``scene_fetch``
+    inside it, in that order."""
+    eng, tracer = traced_streaming["engine"], traced_streaming["tracer"]
+    spans = tracer.spans()
+    parents = [s for s in spans if s.name in ("scene_build", "delta_merge")]
+    assert sum(p.name == "delta_merge" for p in parents) == \
+        eng.stats.delta_merges > 0
+    assert sum(p.name == "scene_build" for p in parents) == \
+        eng.stats.scene_misses > 0
+    for p in parents:
+        inside = {c.name: c for c in spans
+                  if c.name in ("scene_wait", "scene_fetch")
+                  and c.tid == p.tid and p.t0_ns <= c.t0_ns
+                  and c.t1_ns <= p.t1_ns}
+        assert sorted(inside) == ["scene_fetch", "scene_wait"], p.name
+        assert inside["scene_wait"].t1_ns <= inside["scene_fetch"].t0_ns
+        assert inside["scene_wait"].depth == p.depth + 1
+    assert sum(s.name == "apply_delta" for s in spans) == \
+        eng.stats.delta_merges
+    for name in ("scene_wait", "scene_fetch"):
+        assert sum(s.name == name for s in spans) == len(parents)
+        assert eng.stats.summary()["phases"][name]["count"] == len(parents)
+
+
+def test_scene_row_counters_count_every_build(traced_streaming):
+    """``scene_tables.rows`` / ``rung_rows``: the valid rows and the
+    scene-ladder capacities of every build, cold and delta."""
+    built = traced_streaming["built"]
+    st = traced_streaming["engine"].stats.summary()["scene_tables"]
+    assert len(built) == st["misses"] + st["delta_merges"]
+    assert st["rows"] == sum(n for n, _ in built)
+    assert st["rung_rows"] == sum(c for _, c in built)
+    assert st["rows"] < st["rung_rows"]
+
+
+def test_stage_modules_and_scopes_are_named(traced_streaming):
+    """Each jitted stage compiles to a module named for its kind, and its
+    ops carry the map-build and layer scopes."""
+    eng = traced_streaming["engine"]
+    cap = eng._scene_ladder.select(traced_streaming["built"][0][0])
+    text = eng.compiled_text("scene_builder", cap)
+    assert text.startswith("HloModule jit_scene_builder")
+    for scope in ("kmap.sub_s1/table", "kmap.sub_s1/search",
+                  "kmap.down_s1/"):
+        assert scope in text, scope
+    assert eng.compiled_text("scene_delta_builder", cap).startswith(
+        "HloModule jit_scene_delta_builder")
+    text = eng.compiled_text("executor", 512)
+    assert text.startswith("HloModule jit_executor")
+    assert "layer." in text
 
 
 def test_tuner_spans_carry_measured_latency():

@@ -110,8 +110,6 @@ def test_inflight_window_bounded_by_max_inflight():
     assert eng.stats.inflight_peak == 2
     s = eng.stats.summary()["pipeline"]
     assert s["inflight_peak"] == 2
-    assert s["host_busy_s"] > 0 and s["device_busy_s"] > 0
-    assert 0.0 <= s["overlap_frac"] <= 1.0
 
 
 def test_overlap_host_spans_inside_prior_execute_span():

@@ -1,11 +1,12 @@
 """Coordinate hashing and sort-based lookup — int32-only, collision-free.
 
 The paper builds kernel maps with a GPU hash table.  The TPU-idiomatic (and
-JAX-native) equivalent is a *sorted binary search*: sort the coordinate table
-once per map group and answer all K^D shifted queries with a vectorized
-binary search (O(log N) gathers, fully static shapes).  PointAcc (the ASIC
-the paper compares against) and Minuet make the same observation —
-point-cloud mapping operators reduce to sort/merge primitives.
+JAX-native) equivalent is a *sort-merge join*: sort the coordinate table
+once per map group and answer all K^D shifted queries by sorting them
+together with it (``join_lookup``: two sorts and two scans, fully static
+shapes, no data-dependent gather).  PointAcc (the ASIC the paper compares
+against) and Minuet make the same observation — point-cloud mapping
+operators reduce to sort/merge primitives.
 
 Packed-key engine (the fast path)
 ---------------------------------
@@ -14,8 +15,8 @@ Packed-key engine (the fast path)
 
 * table construction is **one** ``argsort`` over scalar keys (two chained
   stable argsorts for the pair case), not one stable argsort per column;
-* every binary-search step is a **scalar** compare (pair compare at worst),
-  not a 4-word lexicographic compare;
+* the lookup sorts **scalar** keys (the pair and raw cases keep a bisect
+  with pair or row compares), not 4-word rows;
 * all K^D shifted queries of a kernel map are answered as one flattened
   batched lookup of shape ``(K^D · N,)``.
 
@@ -28,7 +29,7 @@ which keeps negative coordinates sort-correct.  Tensors that declare no
 bounds (or whose bounds exceed the two-word budget) get the ``raw`` spec:
 the key words are the coordinate columns themselves — no range limits, the
 seed's multi-word contract — still driven through the batched-lookup,
-sort-free-compaction and MapCache machinery.  Packing is order-isomorphic
+pair-list compaction and MapCache machinery.  Packing is order-isomorphic
 to the lexicographic order on rows, so packed tables sort and deduplicate
 exactly like the multi-word path.
 
@@ -70,6 +71,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 _I32_MAX = int(jnp.iinfo(jnp.int32).max)
 
 # Usable bits per key word.  Both words are capped at 30 bits so that no
@@ -92,7 +95,7 @@ class KeySpec:
     ``raw=True`` is the no-range-limit fallback: the key "words" are simply
     the coordinate columns themselves (MSB-first: batch, x, y, z), valid for
     the full int32 range — exactly the seed's multi-word table, but still
-    driven through the batched-lookup / sort-free-compaction / MapCache
+    driven through the batched-lookup / pair-list compaction / MapCache
     machinery.  Used when no bounds are declared or the declared bounds
     exceed the two-word bit budget.
     """
@@ -379,6 +382,77 @@ def sort_keys(keys: jax.Array, spec: Optional[KeySpec] = None):
     return order, keys[order]
 
 
+def join_lookup(sorted_keys: jax.Array, order: jax.Array,
+                q: jax.Array) -> jax.Array:
+    """Sort-merge join of one-word query keys against a sorted table:
+    ``order`` of the table row whose key equals each query, or -1.
+
+    The table (``n ≥ 1`` rows) and the ``M`` queries are sorted together
+    once on (key, tag), table rows first among equal keys.  A cumulative
+    max carries the last table key forward (table keys are ascending), and
+    a cumulative sum its row: each table row's tag holds the step from the
+    previous table row's ``order`` to its own, so the running sum of those
+    steps is the ``order`` of the last table row seen.  A second sort on
+    the query index puts the answers back in query order; the table rows,
+    keyed below every query, drop off the front.  No data-dependent loop,
+    gather or scatter: two sorts and two scans of ``n + M`` elements.
+
+    MISS queries (-1) and PAD table rows (int32 max) never meet: MISS sorts
+    before every table key and no query packs to PAD, so duplicate PAD rows
+    are harmless.  Any number of queries may share a key."""
+    n = sorted_keys.shape[0]
+    m = q.shape[0]
+    step = order - jnp.concatenate([jnp.zeros((1,), order.dtype), order[:-1]])
+    # table tags in [-2n+1, -1] (so below every query index) carry the step
+    keys = jnp.concatenate([sorted_keys, q.astype(jnp.int32)])
+    tags = jnp.concatenate([(step - n).astype(jnp.int32),
+                            jnp.arange(m, dtype=jnp.int32)])
+    keys, tags = jax.lax.sort((keys, tags), num_keys=2)
+    is_table = tags < 0
+    last_key = jax.lax.cummax(
+        jnp.where(is_table, keys, jnp.iinfo(jnp.int32).min))
+    last_row = jnp.cumsum(jnp.where(is_table, tags + n, 0), dtype=jnp.int32)
+    found = jnp.where(~is_table & (last_key == keys), last_row, -1)
+    # the same sort as the first (two keys; table rows tie at (-1, -1)), so
+    # the compiled program holds one sort routine for both
+    _, found = jax.lax.sort((jnp.where(is_table, -1, tags), found),
+                            num_keys=2)
+    return found[n:]
+
+
+def lookup_batched(requests: Sequence[Tuple["CoordTable", jax.Array]]) -> list:
+    """``table.lookup_keys(q)`` for each ``(table, q)`` pair, as few joins
+    as the tables allow: the queries of one table are joined in one go, and
+    the one-word joins of tables of one size run as one batched (vmapped)
+    join, each query set padded with MISS keys to the longest."""
+    by_table: dict = {}
+    for i, (table, _) in enumerate(requests):
+        by_table.setdefault(id(table), (table, []))[1].append(i)
+    found: list = [None] * len(requests)
+    joins: dict = {}
+    for table, idx in by_table.values():
+        if table.spec.words != 1 or table.n == 0:
+            for i in idx:
+                found[i] = table.lookup_keys(requests[i][1])
+            continue
+        q = jnp.concatenate([requests[i][1] for i in idx])
+        joins.setdefault(table.n, []).append((table, q, idx))
+    for group in joins.values():
+        m = max(q.shape[0] for _, q, _ in group)
+        res = jax.vmap(join_lookup)(
+            jnp.stack([t.sorted_keys for t, _, _ in group]),
+            jnp.stack([t.order for t, _, _ in group]),
+            jnp.stack([jnp.pad(q, (0, m - q.shape[0]), constant_values=-1)
+                       for _, q, _ in group]))
+        for row, (_, _, idx) in zip(res, group):
+            lo = 0
+            for i in idx:
+                hi = lo + requests[i][1].shape[0]
+                found[i] = row[lo:hi]
+                lo = hi
+    return found
+
+
 class CoordTable:
     """Sorted packed-key coordinate table answering batched exact-match
     queries.  Construction: pack (elementwise) + one argsort."""
@@ -405,24 +479,28 @@ class CoordTable:
 
     def lookup_keys(self, q: jax.Array) -> jax.Array:
         """Original row index of each query key, or -1 if absent. q: (M,)
-        int32 or (M, 2) — any query count, e.g. the K^D·N flattened batch."""
+        int32 or (M, W) — any query count, e.g. the K^D·N flattened batch.
+
+        One-word keys take the sort-merge join (``join_lookup``); two-word
+        and raw keys a bisect unrolled over the table size, counted as
+        ``kmap.search_bisect`` at trace time."""
         sk = self.sorted_keys
         w = self.spec.words
+        if self.n == 0:
+            return jnp.full(q.shape[:1], -1, jnp.int32)
         if w == 1:
-            pos = jnp.searchsorted(sk, q, side="left").astype(jnp.int32)
-            pos = jnp.clip(pos, 0, self.n - 1)
-            hit = sk[pos] == q
-        else:
-            m = q.shape[0]
-            lo = jnp.zeros((m,), jnp.int32)
-            hi = jnp.full((m,), self.n, jnp.int32)
-            for _ in range(max(1, math.ceil(math.log2(max(self.n, 2))) + 1)):
-                mid = (lo + hi) // 2
-                less = keys_less(sk[jnp.clip(mid, 0, self.n - 1)], q, w)
-                lo = jnp.where(less, mid + 1, lo)
-                hi = jnp.where(less, hi, mid)
-            pos = jnp.clip(lo, 0, self.n - 1)
-            hit = keys_equal(sk[pos], q, w)
+            return join_lookup(sk, self.order, q)
+        obs.count("kmap.search_bisect")
+        m = q.shape[0]
+        lo = jnp.zeros((m,), jnp.int32)
+        hi = jnp.full((m,), self.n, jnp.int32)
+        for _ in range(max(1, math.ceil(math.log2(max(self.n, 2))) + 1)):
+            mid = (lo + hi) // 2
+            less = keys_less(sk[jnp.clip(mid, 0, self.n - 1)], q, w)
+            lo = jnp.where(less, mid + 1, lo)
+            hi = jnp.where(less, hi, mid)
+        pos = jnp.clip(lo, 0, self.n - 1)
+        hit = keys_equal(sk[pos], q, w)
         return jnp.where(hit, self.order[pos], -1).astype(jnp.int32)
 
     def lookup(self, query_coords: jax.Array, valid=None) -> jax.Array:

@@ -17,11 +17,13 @@ reordering) can dominate end-to-end rankings (Tables 3 vs 4).  The mapping
 path therefore minimizes sort work:
 
 * the coordinate table is a ``hashing.CoordTable`` — coordinates packed into
-  scalar int32 keys, **one** argsort, scalar binary-search compares;
+  scalar int32 keys, **one** argsort;
 * all K^D shifted queries are answered as one flattened ``(K^D·N,)`` batched
-  lookup instead of K^D independent searches;
-* the weight-stationary pair lists are compacted **sort-free** in one fused
-  segmented pass (per-offset cumsum + rank-select binary search) instead of
+  lookup instead of K^D independent searches: a sort-merge join of the
+  queries with the table (``hashing.join_lookup``), not a binary search,
+  whose data-dependent gather per step is the costly op on a TPU;
+* the weight-stationary pair lists are compacted by **one** sort of all
+  K^D offset columns at once (a stable partition, hits first) instead of
   one argsort per offset;
 * strided downsampling dedupes grid cells by masking the low stride bits of
   the *already-packed* sorted key array (power-of-two strides; one argsort),
@@ -302,46 +304,48 @@ def _unique_from_keys(table: CoordTable, out_stride: int, capacity: int):
 
 
 def _compact_ws(m_out: jax.Array):
-    """Weight-stationary pair lists via one fused segmented pass — NO sorts.
+    """Weight-stationary pair lists: a stable partition of every offset
+    column, hits first in row order and -1 after, in ONE sort.
 
-    A stable compaction is a rank-select over the per-column hit cumsum: the
-    source row of output slot ``i`` in offset column ``k`` is the first row
-    whose inclusive hit-count reaches ``i+1`` (a batched binary search over
-    a monotone array — all gathers, no scatters).  One 2-D cumsum plus one
-    vectorized searchsorted replaces the seed's K^D per-offset argsorts,
-    with identical output: hits first in row order, -1 padding after.
+    Each column of ``m_out`` (transposed to ``(KD, cap)``) is sorted on its
+    row index, with every miss keyed ``cap`` (past all rows), carrying the
+    input index along: the sorted keys are ``ws_out``, the carried indices
+    ``ws_in``.  Every miss comes out as -1, so the order of the misses does
+    not show, and the sort need not be stable.
     """
-    cap, kd = m_out.shape
-    hit = m_out >= 0
-    cs = jnp.cumsum(hit, axis=0, dtype=jnp.int32)  # monotone per column
-    ws_count = cs[-1]
-    slot = jnp.arange(cap, dtype=jnp.int32)
-
-    def col(c, mk, ck):
-        # rank-select: source row of output slot i = first row with cumsum i+1
-        src = jnp.searchsorted(c, slot + 1, side="left").astype(jnp.int32)
-        src = jnp.clip(src, 0, cap - 1)
-        ok = slot < ck
-        return jnp.where(ok, mk[src], -1), jnp.where(ok, src, -1)
-
-    ws_in, ws_out = jax.vmap(col, in_axes=(1, 1, 0))(cs, m_out, ws_count)
-    return ws_in, ws_out, ws_count
+    cap, _ = m_out.shape
+    cols = m_out.T
+    hit = cols >= 0
+    row = jax.lax.broadcasted_iota(jnp.int32, cols.shape, 1)
+    rows, ins = jax.lax.sort((jnp.where(hit, row, cap), cols), dimension=1,
+                             num_keys=1)
+    found = rows < cap
+    return (jnp.where(found, ins, -1), jnp.where(found, rows, -1),
+            jnp.sum(hit, axis=1, dtype=jnp.int32))
 
 
-def build_kmap(x: SparseTensor, kernel_size: int, stride: int = 1,
-               transposed: bool = False, out_coords: Optional[jax.Array] = None,
-               n_out: Optional[jax.Array] = None, out_capacity: Optional[int] = None,
-               cache: Optional[MapCache] = None) -> KernelMap:
-    """Build the kernel map for a sparse convolution over ``x``.
+@dataclasses.dataclass(frozen=True)
+class PendingKmap:
+    """One kernel map between its table phase and its neighbour search:
+    the coordinate table it searches, its output rows, and the packed
+    shifted query keys (``(KD·cap,)``, offset-major)."""
 
-    stride == 1                 : submanifold conv, outputs = inputs.
-    stride > 1, not transposed  : downsample; outputs = unique(floor-grid).
-    transposed                  : upsample (inverse conv); ``out_coords`` (the
-        cached finer coordinates) and ``n_out`` must be given.
+    table: CoordTable
+    out_coords: jax.Array
+    n_out: jax.Array
+    qkeys: jax.Array
+    out_stride: int
+    kernel_size: int
 
-    ``cache``: optional ``MapCache`` — reuses the sorted coordinate table
-    across calls at the same stride and adopts strided outputs' tables.
-    """
+
+def prepare_kmap(x: SparseTensor, kernel_size: int, stride: int = 1,
+                 transposed: bool = False, out_coords: Optional[jax.Array] = None,
+                 n_out: Optional[jax.Array] = None,
+                 out_capacity: Optional[int] = None,
+                 cache: Optional[MapCache] = None) -> PendingKmap:
+    """The table phase of ``build_kmap`` (same arguments): the sorted
+    coordinate table, the output rows (a strided map's unique floor grid,
+    whose table is adopted into ``cache`` here) and the query keys."""
     d = x.ndim_space
     t = x.stride
     offs = kernel_offsets(kernel_size, d)
@@ -406,32 +410,80 @@ def build_kmap(x: SparseTensor, kernel_size: int, stride: int = 1,
                                                    n_out_cap)
             delta_scale = t
 
-    with jax.named_scope("search"):
-        out_valid = jnp.arange(n_out_cap) < n_out
-
-        # Output-stationary map: ONE flattened batched lookup over all K^D·N
-        # shifted queries.  Padded/out-of-range rows pack to the MISS key.
+    with jax.named_scope("search"), jax.named_scope("join"):
+        # all K^D·N shifted queries, packed; padded/out-of-range rows pack
+        # to the MISS key
         shifts = np.concatenate([np.zeros((kd, 1), np.int32),
                                  offs * np.int32(delta_scale)], axis=1)
-        # (KD, N, 1+D)
         q = out_coords[None, :, :] + jnp.asarray(shifts)[:, None, :]
         qkeys = hashing.pack_keys(q.reshape(kd * n_out_cap, d + 1), spec,
                                   query=True)
-        m_out = table.lookup_keys(qkeys).reshape(kd, n_out_cap).T
-        m_out = jnp.where(out_valid[:, None], m_out, -1)
-
-        # Weight-stationary lists: one fused sort-free pass for all K^D
-        # offsets.
-        ws_in, ws_out, ws_count = _compact_ws(m_out)
-
-        bm = jnp.where(out_valid, _bitmask(m_out >= 0), 0)
-
-    kmap = KernelMap(m_out=m_out, out_coords=out_coords, n_out=jnp.asarray(n_out, jnp.int32),
-                     ws_in=ws_in, ws_out=ws_out, ws_count=ws_count, bitmask=bm,
-                     out_stride=out_stride, kernel_size=kernel_size)
     if cache is not None and child_table is not None:
-        cache.adopt(kmap.out_coords, child_table)
-    return kmap
+        cache.adopt(out_coords, child_table)
+    return PendingKmap(table=table, out_coords=out_coords,
+                       n_out=jnp.asarray(n_out, jnp.int32), qkeys=qkeys,
+                       out_stride=out_stride, kernel_size=kernel_size)
+
+
+def search_kmaps(pending: Sequence[PendingKmap]) -> list:
+    """The neighbour search of many kernel maps at once: their lookups as
+    joins batched by table and shape (``hashing.lookup_batched``), then
+    their pair lists compacted in one sort per output capacity.  Batching
+    changes no result; it keeps the count of sorts, and so the compiled
+    program, small (a TPU sort compiles to megabytes of code)."""
+    with jax.named_scope("search"), jax.named_scope("join"):
+        found = hashing.lookup_batched([(p.table, p.qkeys) for p in pending])
+    with jax.named_scope("search"), jax.named_scope("compact"):
+        m_outs = []
+        for p, f in zip(pending, found):
+            cap = p.out_coords.shape[0]
+            out_valid = jnp.arange(cap) < p.n_out
+            m_outs.append(jnp.where(out_valid[:, None],
+                                    f.reshape(-1, cap).T, -1))
+        # every offset column compacts alone, so the columns of all maps
+        # of one capacity share one sort
+        by_cap: Dict[int, list] = {}
+        for i, m in enumerate(m_outs):
+            by_cap.setdefault(m.shape[0], []).append(i)
+        ws = [None] * len(pending)
+        for idx in by_cap.values():
+            ws_in, ws_out, ws_count = _compact_ws(
+                jnp.concatenate([m_outs[i] for i in idx], axis=1))
+            lo = 0
+            for i in idx:
+                hi = lo + m_outs[i].shape[1]
+                ws[i] = (ws_in[lo:hi], ws_out[lo:hi], ws_count[lo:hi])
+                lo = hi
+        maps = []
+        for p, m_out, (ws_in, ws_out, ws_count) in zip(pending, m_outs, ws):
+            out_valid = jnp.arange(m_out.shape[0]) < p.n_out
+            maps.append(KernelMap(
+                m_out=m_out, out_coords=p.out_coords, n_out=p.n_out,
+                ws_in=ws_in, ws_out=ws_out, ws_count=ws_count,
+                bitmask=jnp.where(out_valid, _bitmask(m_out >= 0), 0),
+                out_stride=p.out_stride, kernel_size=p.kernel_size))
+    return maps
+
+
+def build_kmap(x: SparseTensor, kernel_size: int, stride: int = 1,
+               transposed: bool = False, out_coords: Optional[jax.Array] = None,
+               n_out: Optional[jax.Array] = None, out_capacity: Optional[int] = None,
+               cache: Optional[MapCache] = None) -> KernelMap:
+    """Build the kernel map for a sparse convolution over ``x``.
+
+    stride == 1                 : submanifold conv, outputs = inputs.
+    stride > 1, not transposed  : downsample; outputs = unique(floor-grid).
+    transposed                  : upsample (inverse conv); ``out_coords`` (the
+        cached finer coordinates) and ``n_out`` must be given.
+
+    ``cache``: optional ``MapCache`` — reuses the sorted coordinate table
+    across calls at the same stride and adopts strided outputs' tables.
+    A map program builds its maps in two phases instead (``prepare_kmap``
+    for each, then one ``search_kmaps``), so the searches batch.
+    """
+    return search_kmaps([prepare_kmap(x, kernel_size, stride, transposed,
+                                      out_coords, n_out, out_capacity,
+                                      cache)])[0]
 
 
 def transpose_kmap(fwd: KernelMap, x_fine: SparseTensor) -> KernelMap:

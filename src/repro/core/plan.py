@@ -53,8 +53,8 @@ from repro.core import precision as prec
 from repro.core.autotuner import (Autotuner, TrainingAutotuner,
                                   partition_groups)
 from repro.core.hashing import CoordTable
-from repro.core.kmap import (MapCache, SceneEntry, build_kmap,
-                             make_split_plan, transpose_kmap)
+from repro.core.kmap import (MapCache, SceneEntry, make_split_plan,
+                             prepare_kmap, search_kmaps, transpose_kmap)
 from repro.core.precision import FP32, PrecisionPolicy
 from repro.core.sparse_conv import (ConvSpec, TrainDataflowConfig, apply_conv)
 from repro.core.sparse_tensor import SparseTensor
@@ -276,9 +276,11 @@ def build_maps_from_specs(specs: Sequence[KmapSpec], st: SparseTensor,
     floor-grid unique argsorts too.  Levels absent from ``tables`` build
     normally — composition degrades gracefully, never changes results.
 
-    Each spec's ops trace under ``jax.named_scope("kmap.<kind>_s<s>")``
-    (``s``: the stride of the tensor the map is built on, as in its
-    ``ref``), so a device trace can say which map an op builds.
+    Each spec's table phase (and a transposed map) traces under
+    ``jax.named_scope("kmap.<kind>_s<s>")`` (``s``: the stride of the
+    tensor the map is built on, as in its ``ref``), so a device trace can
+    say which map an op builds; the searches of all specs run batched,
+    under ``search/join`` and ``search/compact`` alone.
     """
     if cache is None:   # NOT `or`: an empty caller cache is falsy but wanted
         cache = MapCache.for_tensor(st)
@@ -290,24 +292,32 @@ def build_maps_from_specs(specs: Sequence[KmapSpec], st: SparseTensor,
             else:
                 cache.adopt_for_stride(s, CoordTable.from_sorted_keys(
                     cache.spec, keys), n)
-    maps: dict = {}
+    # table phase, level by level; then every map's search at once (the
+    # searches batch, see ``kmap.search_kmaps``); then the transposed maps
+    pending: dict = {}
     tensors = {st.stride: st}
     for ms in specs:
-        cur = tensors[ms.tensor_stride]
+        if ms.kind == "up":
+            continue
         with jax.named_scope(f"kmap.{ms.kind}_s{ms.tensor_stride}"):
-            if ms.kind == "sub":
-                maps[ms.ref] = build_kmap(cur, ms.kernel_size, 1, cache=cache)
-            elif ms.kind == "down":
-                kd = build_kmap(cur, ms.kernel_size, ms.stride, cache=cache)
-                maps[ms.ref] = kd
-                tensors[kd.out_stride] = SparseTensor(
-                    coords=kd.out_coords,
-                    feats=jnp.zeros((kd.capacity, 1), st.feats.dtype),
-                    num_valid=kd.n_out, stride=kd.out_stride,
-                    batch_bound=st.batch_bound,
-                    spatial_bound=st.spatial_bound)
-            else:  # "up"
-                maps[ms.ref] = transpose_kmap(maps[ms.transpose_of], cur)
+            p = prepare_kmap(tensors[ms.tensor_stride], ms.kernel_size,
+                             1 if ms.kind == "sub" else ms.stride,
+                             cache=cache)
+        pending[ms.ref] = p
+        if ms.kind == "down":
+            tensors[p.out_stride] = SparseTensor(
+                coords=p.out_coords,
+                feats=jnp.zeros((p.out_coords.shape[0], 1), st.feats.dtype),
+                num_valid=p.n_out, stride=p.out_stride,
+                batch_bound=st.batch_bound, spatial_bound=st.spatial_bound)
+    built = dict(zip(pending, search_kmaps(list(pending.values()))))
+    maps: dict = {}
+    for ms in specs:
+        if ms.kind == "up":
+            with jax.named_scope(f"kmap.{ms.kind}_s{ms.tensor_stride}"):
+                built[ms.ref] = transpose_kmap(built[ms.transpose_of],
+                                               tensors[ms.tensor_stride])
+        maps[ms.ref] = built[ms.ref]
     return maps
 
 

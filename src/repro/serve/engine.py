@@ -557,7 +557,7 @@ class Engine:
         argsort — and, when the stream's cell ladder is live, adopting the
         incrementally-updated down-level tables (``lkeys``/``lns``, also
         padded to ``cap``) so no per-level masked-key argsort runs either:
-        the whole delta rebuild is binary searches over adopted tables."""
+        the whole delta rebuild is lookup joins over adopted tables."""
         fn = self._scene_delta_builders.get(cap)
         if fn is None:
             specs = self.nplan.map_specs

@@ -13,6 +13,7 @@ import pytest
 
 from conftest import property_test
 
+from repro import obs
 from repro.core import dataflows as df
 from repro.core import hashing
 from repro.core import kmap as km
@@ -122,6 +123,10 @@ def assert_kmap_matches_ref(kmap: km.KernelMap, ref: dict):
 # Packed lookup ≡ brute-force dict lookup (all three key-spec modes)
 # ---------------------------------------------------------------------------
 
+def _bisect_count() -> int:
+    return obs.get_tracer().snapshot()["counters"].get("kmap.search_bisect", 0)
+
+
 def _spec_of_kind(kind, batch, lo, extent):
     """One spec per packing mode: single int32 word, packed [hi, lo] pair,
     and the raw no-range-limit fallback (default when bounds are unknown)."""
@@ -164,7 +169,10 @@ def test_property_packed_lookup_matches_bruteforce(seed, extent, lo, batch,
     lut = {tuple(c): i for i, c in
            enumerate(np.asarray(stx.coords)[: int(stx.num_valid)])}
     ref = np.asarray([lut.get(tuple(row), -1) for row in q], np.int32)
+    before = _bisect_count()
     np.testing.assert_array_equal(np.asarray(packed.lookup(jnp.asarray(q))), ref)
+    # one-word keys join; pair and raw keys bisect, and say so
+    assert (_bisect_count() - before) == (spec_kind != "one")
 
 
 def test_pack_unpack_roundtrip_with_negatives():
@@ -234,6 +242,144 @@ def test_out_of_range_queries_miss():
 
 
 # ---------------------------------------------------------------------------
+# Sort-merge join lookup and sort compaction ≡ numpy brute force
+# ---------------------------------------------------------------------------
+
+@property_test(
+    "seed,rows,pad,extent,lo,batch,queries",
+    cases=[(0, 80, 16, 8, 0, 1, "mixed"), (1, 60, 36, 16, -8, 3, "dupes"),
+           (2, 50, 14, 6, -5, 2, "all_miss"), (3, 1, 0, 4, -2, 1, "dupes"),
+           (4, 0, 16, 8, 0, 1, "mixed"), (5, 0, 0, 8, 0, 1, "mixed"),
+           (6, 40, 0, 10, -12, 4, "dupes"), (7, 1, 5, 5, -3, 2, "mixed")],
+    strategies=lambda st: dict(seed=st.integers(0, 10_000),
+                               rows=st.integers(0, 60),
+                               pad=st.integers(0, 20),
+                               extent=st.integers(4, 20),
+                               lo=st.integers(-12, 0),
+                               batch=st.integers(1, 4),
+                               queries=st.sampled_from(
+                                   ["mixed", "dupes", "all_miss"])),
+    max_examples=24)
+def test_property_join_lookup_matches_bruteforce(seed, rows, pad, extent, lo,
+                                                 batch, queries):
+    """One-word lookups take the sort-merge join, and it answers as a dict
+    does: duplicate and MISS queries, PAD table rows (``pad`` of them),
+    all-miss query sets, 1-row, all-PAD and n = 0 tables, negative
+    coordinates and several batches."""
+    rng = np.random.default_rng(seed)
+    spec = hashing.key_spec_for(3, batch_bound=batch,
+                                spatial_bound=max(abs(lo), extent) + 8)
+    assert spec.words == 1 and not spec.raw
+    cand = np.concatenate([rng.integers(0, batch, (4 * rows + 4, 1)),
+                           rng.integers(lo, extent, (4 * rows + 4, 3))], 1)
+    cand = np.unique(cand, axis=0)
+    table_rows = cand[rng.permutation(len(cand))[:rows]]
+    rows = len(table_rows)
+    coords = np.concatenate([table_rows, np.full((pad, 4), int(INVALID_COORD))])
+    valid = np.arange(rows + pad) < rows
+    table = hashing.CoordTable.build(jnp.asarray(coords, jnp.int32),
+                                     jnp.asarray(valid), spec)
+    miss = np.full((8, 4), int(INVALID_COORD))
+    if queries == "dupes" and rows:
+        q = np.concatenate([table_rows[rng.integers(0, rows, 96)], miss])
+    elif queries == "all_miss":
+        far = np.concatenate([rng.integers(0, batch, (64, 1)),
+                              rng.integers(extent + 1, extent + 8, (64, 3))], 1)
+        q = np.concatenate([far, miss])
+    else:
+        near = coords[rng.integers(0, max(len(coords), 1), 48)] \
+            if len(coords) else np.zeros((0, 4), np.int64)
+        near = near + rng.integers(-1, 2, size=near.shape)
+        rand = np.concatenate([rng.integers(0, batch, (48, 1)),
+                               rng.integers(lo - 2, extent + 2, (48, 3))], 1)
+        q = np.concatenate([near, rand, miss])
+    q = q.astype(np.int32)
+    lut = {tuple(c): i for i, c in enumerate(table_rows)}
+    ref = np.asarray([lut.get(tuple(row), -1) for row in q], np.int32)
+    if queries == "all_miss":
+        assert (ref == -1).all()
+    before = _bisect_count()
+    got = np.asarray(table.lookup(jnp.asarray(q)))
+    assert _bisect_count() == before
+    np.testing.assert_array_equal(got, ref)
+
+
+@pytest.mark.parametrize("seed,cap,kd", [(0, 64, 27), (1, 1, 8), (2, 200, 8),
+                                         (3, 33, 3)])
+def test_compact_ws_matches_per_column_nonzero(seed, cap, kd):
+    """The sort compaction gives each column's hits in row order, then -1,
+    bit for bit, all-hit and no-hit columns included."""
+    rng = np.random.default_rng(seed)
+    m_out = np.where(rng.random((cap, kd)) < 0.4,
+                     rng.integers(0, 1000, (cap, kd)), -1).astype(np.int32)
+    m_out[:, 0] = rng.integers(0, 1000, cap)   # every row a hit
+    m_out[:, -1] = -1                          # no hit
+    ws_in, ws_out, ws_count = km._compact_ws(jnp.asarray(m_out))
+    ref_in = np.full((kd, cap), -1, np.int32)
+    ref_out = np.full((kd, cap), -1, np.int32)
+    ref_count = np.zeros((kd,), np.int32)
+    for k in range(kd):
+        rows = np.nonzero(m_out[:, k] >= 0)[0]
+        ref_count[k] = len(rows)
+        ref_in[k, :len(rows)] = m_out[rows, k]
+        ref_out[k, :len(rows)] = rows
+    np.testing.assert_array_equal(np.asarray(ws_in), ref_in)
+    np.testing.assert_array_equal(np.asarray(ws_out), ref_out)
+    np.testing.assert_array_equal(np.asarray(ws_count), ref_count)
+
+
+def _search_whiles(hlo: str) -> list:
+    """The ``while`` ops of a compiled module whose ``op_name`` lies in a
+    ``search`` scope."""
+    return [ln for ln in hlo.splitlines()
+            if " while(" in ln and "/search/" in ln]
+
+
+@pytest.mark.parametrize("arch", ["minkunet", "centerpoint"])
+def test_scene_builds_search_without_while_loops(arch):
+    """The engine's scene builder and delta builder (``scene_entry_arrays``
+    on a fresh scene, and on adopted root and level tables) compile with no
+    ``while`` op in any ``search`` scope, and no lookup takes the bisect."""
+    from repro.configs import centerpoint_waymo, minkunet_kitti
+    from repro.core.plan import scene_entry_arrays
+    from repro.models import centerpoint, minkunet
+    specs = (minkunet.declare(minkunet_kitti.CONFIG_1X) if arch == "minkunet"
+             else centerpoint.declare(centerpoint_waymo.CONFIG)).map_specs
+    cap, n = 256, 200
+    rng = np.random.default_rng(0)
+    cells = np.unique(rng.integers(-40, 40, (2 * n, 3)), axis=0)[:n]
+    coords = np.full((cap, 4), int(INVALID_COORD), np.int32)
+    coords[:n, 0] = 0
+    coords[:n, 1:] = cells
+    st = SparseTensor(coords=jnp.asarray(coords),
+                      feats=jnp.zeros((cap, 1), jnp.float32),
+                      num_valid=jnp.asarray(n, jnp.int32), stride=1,
+                      batch_bound=2, spatial_bound=147)
+    spec = hashing.key_spec_for(3, 2, 147)
+    assert spec.words == 1
+    root = hashing.CoordTable.build(st.coords, st.valid_mask, spec)
+    downs = [ms.tensor_stride * 2 for ms in specs if ms.kind == "down"]
+    lkeys = {s: root.sorted_keys for s in downs}
+    lns = {s: jnp.asarray(n, jnp.int32) for s in downs}
+
+    def delta_build(st, keys, order, lkeys, lns):
+        tables = {s: (lkeys[s], None, lns[s]) for s in lkeys}
+        return scene_entry_arrays(
+            specs, st, root_table=hashing.CoordTable(spec, keys, order),
+            tables=tables)
+
+    before = _bisect_count()
+    fresh = jax.jit(lambda st: scene_entry_arrays(specs, st)).lower(st)
+    delta = jax.jit(delta_build).lower(st, root.sorted_keys, root.order,
+                                       lkeys, lns)
+    assert _bisect_count() == before
+    for lowered in (fresh, delta):
+        hlo = lowered.compile().as_text()
+        assert "/search/join/" in hlo and "/search/compact/" in hlo
+        assert _search_whiles(hlo) == []
+
+
+# ---------------------------------------------------------------------------
 # build_kmap ≡ numpy reference, with and without the MapCache
 # ---------------------------------------------------------------------------
 
@@ -287,6 +433,59 @@ def test_build_kmap_inside_jit_with_cache():
     a, b = build()
     assert_kmap_matches_ref(a, np_build_kmap(stx, 3, 1))
     assert_kmap_matches_ref(b, np_build_kmap(stx, 2, 2))
+
+
+@pytest.mark.parametrize("with_up", [False, True])
+def test_map_program_matches_map_by_map_builds(with_up):
+    """A map program searches all its maps in batched joins and one
+    compaction; each map equals a lone ``build_kmap`` on the same level."""
+    from repro.core.plan import build_maps_from_specs, pyramid_map_specs
+    stx = random_tensor(7, n=200, cap=256, extent=24, lo=-24, batch=2,
+                        bounds=True)
+    specs = pyramid_map_specs(3, with_up=with_up)
+    maps = build_maps_from_specs(specs, stx)
+    assert list(maps) == [ms.ref for ms in specs]
+    cur = stx
+    for ms in specs:
+        if ms.kind == "up":
+            continue
+        one = km.build_kmap(cur, ms.kernel_size,
+                            1 if ms.kind == "sub" else ms.stride)
+        assert_kmaps_equal(maps[ms.ref], one)
+        if ms.kind == "down":
+            cur = SparseTensor(coords=one.out_coords, feats=jnp.zeros(
+                (one.capacity, 1)), num_valid=one.n_out,
+                stride=one.out_stride, batch_bound=stx.batch_bound,
+                spatial_bound=stx.spatial_bound)
+
+
+def test_lookup_batched_matches_lookup_keys():
+    """Batched lookups give each pair what its own ``lookup_keys`` gives:
+    tables shared by several query sets, query sets of other lengths padded
+    into one batched join, an n = 0 table and a two-word table among them."""
+    one = hashing.key_spec_for(3, batch_bound=2, spatial_bound=30)
+    two = hashing.key_spec_for(3, batch_bound=500, spatial_bound=12000)
+    tables = []
+    for seed, spec, cap in [(0, one, 96), (1, one, 96), (2, one, 40),
+                            (3, two, 96)]:
+        stx = random_tensor(seed, n=cap - 16, cap=cap, extent=10, lo=-10,
+                            batch=2)
+        tables.append((stx, hashing.CoordTable.build(stx.coords,
+                                                     stx.valid_mask, spec)))
+    empty = hashing.CoordTable.build(jnp.zeros((0, 4), jnp.int32),
+                                     jnp.zeros((0,), bool), one)
+    rng = np.random.default_rng(9)
+    requests = []
+    for (stx, table), m in zip(tables + tables[:2], [64, 128, 32, 50, 17, 9]):
+        q = np.asarray(stx.coords)[rng.integers(0, stx.capacity, m)]
+        q = q + rng.integers(-1, 2, size=q.shape)
+        requests.append((table, hashing.pack_keys(jnp.asarray(q, jnp.int32),
+                                                  table.spec, query=True)))
+    requests.append((empty, requests[0][1]))
+    got = hashing.lookup_batched(requests)
+    for (table, q), g in zip(requests, got):
+        np.testing.assert_array_equal(np.asarray(g),
+                                      np.asarray(table.lookup_keys(q)))
 
 
 # ---------------------------------------------------------------------------

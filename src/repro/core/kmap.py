@@ -52,6 +52,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import math
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
@@ -60,29 +61,40 @@ import numpy as np
 
 from repro.core import hashing
 from repro.core.hashing import CoordTable, KeySpec
-from repro.core.sparse_tensor import INVALID_COORD, SparseTensor
+from repro.core.sparse_tensor import (INVALID_COORD, SparseTensor,
+                                      axis_strides, stride_mul, stride_name)
 
 _I32_MAX = int(jnp.iinfo(jnp.int32).max)
 
 
-def kernel_offsets(kernel_size: int, ndim: int) -> np.ndarray:
-    """Δ^D(K) as an (K^D, D) int array.
+def kernel_offsets(kernel_size, ndim: int) -> np.ndarray:
+    """Δ^D(K) as an (K^D, D) int array; ``kernel_size`` is an int (K on
+    every axis) or one extent per axis, as the (1, 3, 3) of an asymmetric
+    kernel.
 
-    Odd K: centered window {-(K//2)..K//2}^D (submanifold convention).
-    Even K: forward window {0..K-1}^D (downsampling convention, e.g. K=2,s=2).
+    Odd K: centered window {-(K//2)..K//2} (submanifold convention).
+    Even K: forward window {0..K-1} (downsampling convention, e.g. K=2,s=2).
     The *center-first* ordering puts δ=0 (or the lowest corner for even K)
     first: the center offset is always dense for submanifold convs, and
-    leading with it makes split 0 the "dense" split.
+    leading with it makes split 0 the "dense" split.  It is product order
+    stably sorted by L1 norm, so the offsets of a sub-kernel keep the
+    relative order they have in the cubic kernel (``offset_columns``).
     """
-    if kernel_size % 2 == 1:
-        r = range(-(kernel_size // 2), kernel_size // 2 + 1)
-    else:
-        r = range(kernel_size)
-    offs = np.array(list(itertools.product(r, repeat=ndim)), dtype=np.int32)
+    ranges = [range(-(k // 2), k // 2 + 1) if k % 2 else range(k)
+              for k in axis_strides(kernel_size, ndim)]
+    offs = np.array(list(itertools.product(*ranges)), dtype=np.int32)
     # center-first ordering
     norm = np.abs(offs).sum(axis=1)
     order = np.argsort(norm, kind="stable")
     return offs[order]
+
+
+def offset_columns(kernel_size, full: int, ndim: int) -> np.ndarray:
+    """Columns of ``kernel_size``'s offsets in the cubic kernel of extent
+    ``full``, in the sub-kernel's own offset order."""
+    index = {tuple(o): i for i, o in enumerate(kernel_offsets(full, ndim))}
+    return np.array([index[tuple(o)]
+                     for o in kernel_offsets(kernel_size, ndim)], np.int32)
 
 
 def _bitmask(hit: jax.Array) -> jax.Array:
@@ -125,8 +137,11 @@ class KernelMap:
     ws_count: jax.Array       # (KD,) int32
     bitmask: jax.Array        # (N_out_cap,) int32 neighbor bitmask (0 pad;
                               # composite popcount proxy when KD > 31)
-    out_stride: int = dataclasses.field(metadata=dict(static=True), default=1)
-    kernel_size: int = dataclasses.field(metadata=dict(static=True), default=3)
+    # static: an int, or one int per spatial axis
+    out_stride: int | tuple = dataclasses.field(metadata=dict(static=True),
+                                                default=1)
+    kernel_size: int | tuple = dataclasses.field(metadata=dict(static=True),
+                                                 default=3)
 
     @property
     def volume(self) -> int:
@@ -222,28 +237,30 @@ def _unique_coords(coords: jax.Array, valid: jax.Array, capacity: int):
     return out[:capacity], jnp.minimum(jnp.sum(is_first), capacity).astype(jnp.int32)
 
 
-def _grid_mask_ints(spec: KeySpec, out_stride: int):
+def _grid_mask_ints(spec: KeySpec, out_stride):
     """Per-key-column AND masks (MSB-first, plain python ints) clearing the
-    low ``log2(out_stride)`` bits of every spatial field — turning a
-    coordinate key into its floor-grid key in one bit op.  For ``raw`` specs
-    the columns ARE the coordinates, and two's-complement masking floors
-    negatives correctly.  Returns None when the stride is not a power of two
-    or a packed field is too narrow (callers fall back to the multi-word
-    grid dedup)."""
-    if out_stride & (out_stride - 1):
+    low ``log2`` of each axis' ``out_stride`` bits of that axis' field —
+    turning a coordinate key into its floor-grid key in one bit op.  For
+    ``raw`` specs the columns ARE the coordinates, and two's-complement
+    masking floors negatives correctly.  Returns None when a stride is not a
+    power of two, every axis has stride 1, or a packed field is too narrow
+    (callers fall back to the multi-word grid dedup)."""
+    strides = axis_strides(out_stride, spec.ndim_space)
+    if any(s & (s - 1) for s in strides):
         return None
-    log2s = out_stride.bit_length() - 1
-    if log2s == 0:
+    log2s = [s.bit_length() - 1 for s in strides]
+    if not any(log2s):
         return None
     if spec.raw:
-        return (-1,) + (~(out_stride - 1),) * spec.ndim_space
+        return (-1,) + tuple(~(s - 1) for s in strides)
     masks = [np.int64(2 ** 31 - 1), np.int64(2 ** 31 - 1)]
     for f, (word, shift, width) in enumerate(spec.layout()):
         if f == 0:
             continue  # batch never strides
-        if log2s > width - 1:
+        bits = log2s[f - 1]
+        if bits > width - 1:
             return None  # bias 2^(width-1) must stay divisible by the stride
-        masks[word] &= ~(((1 << log2s) - 1) << shift) & (2 ** 32 - 1)
+        masks[word] &= ~(((1 << bits) - 1) << shift) & (2 ** 32 - 1)
     cols = [int(np.int32(m)) for m in masks]
     # MSB-first column order: single word → (lo,), pair → (hi, lo)
     return (cols[0],) if spec.words == 1 else (cols[1], cols[0])
@@ -280,27 +297,85 @@ def _unique_from_keys(table: CoordTable, out_stride: int, capacity: int):
     if w == 1:
         row_valid = table.sorted_keys != _I32_MAX
         masked = jnp.where(row_valid, table.sorted_keys & masks[0], _I32_MAX)
-        same = lambda ks: ks[1:] == ks[:-1]
-        pad_shape = (capacity + 1,)
     else:
         row_valid = table.sorted_keys[:, 0] != _I32_MAX
         masked = jnp.where(row_valid[:, None], table.sorted_keys &
                            jnp.stack(list(masks)), _I32_MAX)
-        same = lambda ks: hashing.keys_equal(ks[1:], ks[:-1], w)
-        pad_shape = (capacity + 1, w)
     order, ks = hashing.sort_keys(masked, spec)
-    first_valid = row_valid[order]
-    same_as_prev = same(ks)
-    is_first = jnp.concatenate([jnp.ones((1,), bool), ~same_as_prev]) & first_valid
+    return _unique_level(ks, row_valid[order], spec, capacity, clamp=True)
+
+
+def _unique_level(ks, valid, spec: KeySpec, capacity: int, clamp: bool):
+    """A level's table from sorted keys ``ks`` (rows with ``valid``): the
+    first of each distinct key, compacted in order into ``capacity`` rows.
+    Returns ``(out_coords, n, child_table)``; ``n`` counts the distinct
+    keys, at most ``capacity`` with ``clamp``."""
+    same = hashing.keys_equal(ks[1:], ks[:-1], spec.words)
+    is_first = jnp.concatenate([jnp.ones((1,), bool), ~same]) & valid
     dest = jnp.where(is_first, jnp.cumsum(is_first) - 1, capacity)
-    out_keys = jnp.full(pad_shape, _I32_MAX, jnp.int32)
+    out_keys = jnp.full((capacity + 1,) + ks.shape[1:], _I32_MAX, jnp.int32)
     out_keys = out_keys.at[dest].set(ks, mode="drop")[:capacity]
-    n_out = jnp.minimum(jnp.sum(is_first), capacity).astype(jnp.int32)
-    key_valid = jnp.arange(capacity) < n_out
+    n = jnp.sum(is_first, dtype=jnp.int32)
+    if clamp:
+        n = jnp.minimum(n, capacity)
+    key_valid = jnp.arange(capacity) < n
     out_coords = jnp.where(key_valid[:, None],
                            hashing.unpack_keys(out_keys, spec), INVALID_COORD)
-    child = CoordTable.from_sorted_keys(spec, out_keys)
-    return out_coords, n_out, child
+    return out_coords, n, CoordTable.from_sorted_keys(spec, out_keys)
+
+
+def _dilate(x: SparseTensor, kernel_size, stride, spec: KeySpec,
+            capacity: int):
+    """Output cells of a strided conv with an odd kernel, as spconv's
+    ``SparseConv3d`` (padding K//2) makes them: every multiple of the output
+    stride ``t·s`` (per axis) that reaches an input voxel ``i`` as
+    ``o + δ·t`` for a kernel offset δ — a dilation of the input, not its
+    floor grid.  Along an axis each input emits at most ceil(K/s) cells
+    (2 for K=3, s=2; 3 for s=1), so an input emits ≤ 2×2×2 candidates at
+    stride (2, 2, 2) and ≤ 2×2×3 at (2, 2, 1); one sort dedupes them.
+
+    Returns ``(out_coords, n_need, child_table)`` at ``capacity`` rows.
+    ``n_need`` counts every distinct cell, so it exceeds ``capacity`` when
+    the level overflows; ``check_capacity`` raises on that on the host."""
+    d = x.ndim_space
+    t = np.asarray(axis_strides(x.stride, d), np.int32)
+    s = np.asarray(axis_strides(stride, d), np.int32)
+    r = np.asarray(axis_strides(kernel_size, d), np.int32) // 2
+    ts = t * s
+    c = x.coords[:, 1:]
+    top = (c + r * t) // ts * ts          # the highest cell in reach
+    axes = []
+    for a in range(d):
+        cells = [top[:, a] - j * ts[a]
+                 for j in range(-(-(2 * r[a] + 1) // s[a]))]
+        axes.append([(o, o >= c[:, a] - r[a] * t[a]) for o in cells])
+    cand, ok = [], []
+    for combo in itertools.product(*axes):
+        cand.append(jnp.stack([x.coords[:, 0]] + [o for o, _ in combo], 1))
+        reach = x.valid_mask
+        for _, k in combo:
+            reach = reach & k
+        ok.append(reach)
+    keys = hashing.pack_keys(jnp.concatenate(cand), spec,
+                             valid=jnp.concatenate(ok))
+    if spec.words == 1:
+        ks = jnp.sort(keys)
+        row_valid = ks != _I32_MAX
+    else:
+        ks = jnp.stack(jax.lax.sort(tuple(keys.T), num_keys=spec.words), 1)
+        row_valid = ks[:, 0] != _I32_MAX
+    return _unique_level(ks, row_valid, spec, capacity, clamp=False)
+
+
+def check_capacity(maps: Dict[tuple, "KernelMap"]) -> None:
+    """Raise when a dilated level needed more rows than its static capacity
+    (its ``n_out`` then counts every cell it needed): a scene's rows are
+    never dropped to fit.  Reads each map's ``n_out`` on the host."""
+    for ref, km in maps.items():
+        need = int(km.n_out)
+        if need > km.capacity:
+            raise ValueError(f"map {ref} needs {need} rows, more than its "
+                             f"level's capacity {km.capacity}")
 
 
 def _compact_ws(m_out: jax.Array):
@@ -334,18 +409,20 @@ class PendingKmap:
     out_coords: jax.Array
     n_out: jax.Array
     qkeys: jax.Array
-    out_stride: int
-    kernel_size: int
+    out_stride: int | tuple
+    kernel_size: int | tuple
 
 
-def prepare_kmap(x: SparseTensor, kernel_size: int, stride: int = 1,
+def prepare_kmap(x: SparseTensor, kernel_size, stride=1,
                  transposed: bool = False, out_coords: Optional[jax.Array] = None,
                  n_out: Optional[jax.Array] = None,
                  out_capacity: Optional[int] = None,
-                 cache: Optional[MapCache] = None) -> PendingKmap:
+                 cache: Optional[MapCache] = None,
+                 dilate: bool = False) -> PendingKmap:
     """The table phase of ``build_kmap`` (same arguments): the sorted
-    coordinate table, the output rows (a strided map's unique floor grid,
-    whose table is adopted into ``cache`` here) and the query keys."""
+    coordinate table, the output rows (a strided map's unique floor grid, or
+    with ``dilate`` its dilation, whose table is adopted into ``cache``
+    here) and the query keys."""
     d = x.ndim_space
     t = x.stride
     offs = kernel_offsets(kernel_size, d)
@@ -378,14 +455,19 @@ def prepare_kmap(x: SparseTensor, kernel_size: int, stride: int = 1,
             out_coords = out_coords[:n_out_cap]
             delta_scale = t
         else:
-            out_stride = t * stride
+            out_stride = stride_mul(t, stride)
             n_out_cap = out_capacity or cap_in
             pre = (cache.table_for_stride(out_stride) if cache is not None
                    else None)
             use_pre = pre is not None and pre[0].n == n_out_cap
-            uniq = None if use_pre else \
+            dilated = dilate and not use_pre
+            uniq = None if use_pre or dilated else \
                 _unique_from_keys(table, out_stride, n_out_cap)
-            if use_pre:
+            if dilated:
+                with jax.named_scope(f"kmap.dilate_s{stride_name(t)}"):
+                    out_coords, n_out, child_table = _dilate(
+                        x, kernel_size, stride, spec, n_out_cap)
+            elif use_pre:
                 # composed child table (scene-granular serving reuse): the
                 # output coords ARE the unpacked table keys — no unique
                 # argsort
@@ -402,9 +484,9 @@ def prepare_kmap(x: SparseTensor, kernel_size: int, stride: int = 1,
                 # non-power-of-two stride (or too-narrow fields): fall back to
                 # the multi-word grid dedup — correctness over speed off the
                 # happy path
+                g = np.asarray(axis_strides(out_stride, d), np.int32)
                 grid = jnp.concatenate(
-                    [x.coords[:, :1],
-                     (x.coords[:, 1:] // out_stride) * out_stride], axis=1)
+                    [x.coords[:, :1], (x.coords[:, 1:] // g) * g], axis=1)
                 grid = jnp.where(x.valid_mask[:, None], grid, INVALID_COORD)
                 out_coords, n_out = _unique_coords(grid, x.valid_mask,
                                                    n_out_cap)
@@ -413,8 +495,10 @@ def prepare_kmap(x: SparseTensor, kernel_size: int, stride: int = 1,
     with jax.named_scope("search"), jax.named_scope("join"):
         # all K^D·N shifted queries, packed; padded/out-of-range rows pack
         # to the MISS key
-        shifts = np.concatenate([np.zeros((kd, 1), np.int32),
-                                 offs * np.int32(delta_scale)], axis=1)
+        shifts = np.concatenate(
+            [np.zeros((kd, 1), np.int32),
+             offs * np.asarray(axis_strides(delta_scale, d), np.int32)],
+            axis=1)
         q = out_coords[None, :, :] + jnp.asarray(shifts)[:, None, :]
         qkeys = hashing.pack_keys(q.reshape(kd * n_out_cap, d + 1), spec,
                                   query=True)
@@ -465,14 +549,18 @@ def search_kmaps(pending: Sequence[PendingKmap]) -> list:
     return maps
 
 
-def build_kmap(x: SparseTensor, kernel_size: int, stride: int = 1,
+def build_kmap(x: SparseTensor, kernel_size, stride=1,
                transposed: bool = False, out_coords: Optional[jax.Array] = None,
                n_out: Optional[jax.Array] = None, out_capacity: Optional[int] = None,
-               cache: Optional[MapCache] = None) -> KernelMap:
+               cache: Optional[MapCache] = None,
+               dilate: bool = False) -> KernelMap:
     """Build the kernel map for a sparse convolution over ``x``.
 
     stride == 1                 : submanifold conv, outputs = inputs.
-    stride > 1, not transposed  : downsample; outputs = unique(floor-grid).
+    stride > 1, not transposed  : downsample; outputs = unique(floor-grid)
+        (MinkowskiEngine's rule), or with ``dilate`` (an odd kernel) the
+        dilation of the inputs (spconv's ``SparseConv3d``, ``_dilate``).
+        ``kernel_size`` and ``stride`` may be per-axis tuples.
     transposed                  : upsample (inverse conv); ``out_coords`` (the
         cached finer coordinates) and ``n_out`` must be given.
 
@@ -483,7 +571,7 @@ def build_kmap(x: SparseTensor, kernel_size: int, stride: int = 1,
     """
     return search_kmaps([prepare_kmap(x, kernel_size, stride, transposed,
                                       out_coords, n_out, out_capacity,
-                                      cache)])[0]
+                                      cache, dilate)])[0]
 
 
 def transpose_kmap(fwd: KernelMap, x_fine: SparseTensor) -> KernelMap:
@@ -509,6 +597,22 @@ def transpose_kmap(fwd: KernelMap, x_fine: SparseTensor) -> KernelMap:
     return KernelMap(m_out=m_out, out_coords=x_fine.coords, n_out=x_fine.num_valid,
                      ws_in=fwd.ws_out, ws_out=fwd.ws_in, ws_count=fwd.ws_count,
                      bitmask=bm, out_stride=x_fine.stride, kernel_size=fwd.kernel_size)
+
+
+def slice_kmap(km: KernelMap, kernel_size) -> KernelMap:
+    """The map of a kernel whose offsets are a subset of ``km``'s cubic
+    kernel — an asymmetric (1, 3, 3) out of a 3x3x3 submanifold map: the
+    subset's columns, with no search of its own.  Works on traced and on
+    composed (concrete) maps alike; padded rows stay all -1, so their
+    bitmask stays 0."""
+    cols = offset_columns(kernel_size, km.kernel_size,
+                          km.out_coords.shape[1] - 1)
+    m_out = km.m_out[:, cols]
+    return KernelMap(m_out=m_out, out_coords=km.out_coords, n_out=km.n_out,
+                     ws_in=km.ws_in[cols], ws_out=km.ws_out[cols],
+                     ws_count=km.ws_count[cols],
+                     bitmask=_bitmask(m_out >= 0), out_stride=km.out_stride,
+                     kernel_size=kernel_size)
 
 
 # ---------------------------------------------------------------------------
@@ -539,7 +643,9 @@ class SceneEntry:
     n:          scene row count (level-1 size).
     sizes:      tensor-stride -> per-scene row count at that pyramid level.
     maps:       map ref -> numpy kernel-map fields plus the static metadata
-                composition needs (``in_stride``/``out_stride``/``kernel``).
+                composition needs (``in_stride``/``out_stride``/``kernel``);
+                a column-subset map holds only its ``slice_of`` ref and
+                kernel, and is cut from the composed parent.
     root_keys/root_order: the scene's sorted batch-0 CoordTable — the object
                 ``CoordTable.delta_merge`` updates on streaming frames.
     splits:     lazily-filled (map ref, ranges) -> per-split (sorted bitmask
@@ -656,6 +762,9 @@ def compose_kmaps(entries: Sequence[SceneEntry],
     maps: Dict[tuple, KernelMap] = {}
     for ref in entries[0].maps:
         m0 = entries[0].maps[ref]
+        if "slice_of" in m0:   # specs put a slice after its parent
+            maps[ref] = slice_kmap(maps[m0["slice_of"]], m0["kernel_size"])
+            continue
         in_s, out_s = m0["in_stride"], m0["out_stride"]
         if in_s not in strides or out_s not in strides:
             return None
@@ -704,8 +813,8 @@ def compose_kmaps(entries: Sequence[SceneEntry],
             m_out=jnp.asarray(m_out), out_coords=jnp.asarray(oc),
             n_out=jnp.asarray(int(offs[out_s][-1]), jnp.int32),
             ws_in=ws_in_j, ws_out=ws_out_j, ws_count=wc_j,
-            bitmask=jnp.asarray(bm), out_stride=int(out_s),
-            kernel_size=int(m0["kernel_size"]))
+            bitmask=jnp.asarray(bm), out_stride=out_s,
+            kernel_size=m0["kernel_size"])
     return maps
 
 
@@ -944,6 +1053,12 @@ def _scene_split_keys(entry: SceneEntry, ref: tuple,
     if cached is not None:
         return cached
     sm = entry.maps[ref]
+    if "slice_of" in sm:
+        parent = entry.maps[sm["slice_of"]]
+        m_out = parent["m_out"][:, offset_columns(
+            sm["kernel_size"], parent["kernel_size"],
+            parent["out_coords"].shape[1] - 1)]
+        sm = {**parent, "m_out": m_out, "bitmask": _np_bitmask(m_out >= 0)}
     n_o = entry.sizes[sm["out_stride"]]
     kd = sm["m_out"].shape[1]
     runs = []
@@ -991,7 +1106,8 @@ def compose_split_plans(entries: Sequence[SceneEntry], ref: tuple,
     kernel maps.
     """
     m0 = entries[0].maps[ref]
-    kd = m0["m_out"].shape[1]
+    kd = (m0["m_out"].shape[1] if "m_out" in m0
+          else math.prod(m0["kernel_size"]))   # a slice's per-axis shape
     ranges = split_ranges(kd, n_splits)
     cap = capacity
     if not sort:

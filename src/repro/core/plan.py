@@ -53,26 +53,38 @@ from repro.core import precision as prec
 from repro.core.autotuner import (Autotuner, TrainingAutotuner,
                                   partition_groups)
 from repro.core.hashing import CoordTable
-from repro.core.kmap import (MapCache, SceneEntry, make_split_plan,
-                             prepare_kmap, search_kmaps, transpose_kmap)
+from repro.core.kmap import (MapCache, SceneEntry, check_capacity,
+                             make_split_plan, prepare_kmap, search_kmaps,
+                             slice_kmap, transpose_kmap)
 from repro.core.precision import FP32, PrecisionPolicy
 from repro.core.sparse_conv import (ConvSpec, TrainDataflowConfig, apply_conv)
-from repro.core.sparse_tensor import SparseTensor
+from repro.core.sparse_tensor import SparseTensor, stride_mul, stride_name
 
-PLAN_VERSION = 2
+#: 3: named-value ops and per-layer activations; version-2 plans (stack
+#: ops, a ``relu`` flag per layer) still load (``NetworkPlan.from_dict``)
+PLAN_VERSION = 3
 
 
 # ---------------------------------------------------------------------------
-# Shared layers: masked batch norm (+ ReLU)
+# Shared layers: masked batch norm and an activation
 # ---------------------------------------------------------------------------
 
 def bn_relu_init(c: int) -> dict:
     return {"scale": jnp.ones((c,)), "bias": jnp.zeros((c,))}
 
 
-def bn_relu(p, st: SparseTensor, relu: bool = True,
-            mode: str = "batch") -> SparseTensor:
-    """Masked batch norm (stats over valid rows) + ReLU.
+#: activations a layer (``LayerPlan.act``) or an ``act`` op applies;
+#: LeakyReLU takes PyTorch's default slope, as Cylinder3D uses it
+ACTS = {"none": lambda y: y, "relu": jax.nn.relu,
+        "leaky_relu": lambda y: jax.nn.leaky_relu(y, 0.01),
+        "sigmoid": jax.nn.sigmoid}
+
+
+def norm_act(p, st: SparseTensor, act: str = "relu", act_first: bool = False,
+             mode: str = "batch") -> SparseTensor:
+    """Masked batch norm (stats over valid rows; ``p`` None for none) and
+    the activation ``act``, after the norm or, with ``act_first``, before it
+    (Cylinder3D's order).
 
     ``mode="batch"`` (training/eval parity with the seed) normalizes with
     statistics over all valid rows — which couples every row in a *batched*
@@ -90,18 +102,25 @@ def bn_relu(p, st: SparseTensor, relu: bool = True,
     feature dtype, so bf16 activations stay bf16 across the layer.
     """
     mask = st.valid_mask[:, None]
-    x = st.feats.astype(jnp.float32)
-    if mode == "affine":
-        y = x * p["scale"] + p["bias"]
-    else:
+    y = st.feats.astype(jnp.float32)
+    if act_first:
+        y = ACTS[act](y)
+    if p is not None and mode == "affine":
+        y = y * p["scale"] + p["bias"]
+    elif p is not None:
         assert mode == "batch", mode
         n = jnp.maximum(st.num_valid, 1).astype(jnp.float32)
-        mean = jnp.sum(jnp.where(mask, x, 0), axis=0) / n
-        var = jnp.sum(jnp.where(mask, jnp.square(x - mean), 0), axis=0) / n
-        y = (x - mean) * jax.lax.rsqrt(var + 1e-5) * p["scale"] + p["bias"]
-    if relu:
-        y = jax.nn.relu(y)
+        mean = jnp.sum(jnp.where(mask, y, 0), axis=0) / n
+        var = jnp.sum(jnp.where(mask, jnp.square(y - mean), 0), axis=0) / n
+        y = (y - mean) * jax.lax.rsqrt(var + 1e-5) * p["scale"] + p["bias"]
+    if not act_first:
+        y = ACTS[act](y)
     return st.replace_feats(jnp.where(mask, y, 0).astype(st.feats.dtype))
+
+
+def _tup(v):
+    """A JSON list back to the tuple it was (kernel shapes, strides, refs)."""
+    return tuple(_tup(x) for x in v) if isinstance(v, list) else v
 
 
 # ---------------------------------------------------------------------------
@@ -119,7 +138,9 @@ class LayerPlan:
     group:    tuner group name, filled by ``compile_plan``.
     dataflow: decoupled fwd/dgrad/wgrad configs (paper Fig. 13).
     precision: numeric policy threaded through all three dataflow kernels.
-    bn/relu:  whether the layer is followed by masked BN / ReLU.
+    bn:       whether the layer is followed by masked BN.
+    act:      the activation after it (a key of ``ACTS``), before the BN
+              when ``act_first``.
     """
 
     name: str
@@ -130,35 +151,47 @@ class LayerPlan:
     dataflow: TrainDataflowConfig = TrainDataflowConfig()
     precision: PrecisionPolicy = FP32
     bn: bool = True
-    relu: bool = True
+    act: str = "relu"
+    act_first: bool = False
+
+    def __post_init__(self):
+        assert self.act in ACTS, self.act
 
     def to_dict(self) -> dict:
         return {"name": self.name, "spec": dataclasses.asdict(self.spec),
                 "map_ref": list(self.map_ref), "sig": list(self.sig),
                 "group": self.group, "dataflow": self.dataflow.to_dict(),
                 "precision": self.precision.to_dict(),
-                "bn": self.bn, "relu": self.relu}
+                "bn": self.bn, "act": self.act, "act_first": self.act_first}
 
     @staticmethod
     def from_dict(d: dict) -> "LayerPlan":
+        d = dict(d)
+        if "relu" in d:     # version 2: a ReLU flag in place of ``act``
+            d["act"] = "relu" if d.pop("relu") else "none"
         known = {f.name for f in dataclasses.fields(LayerPlan)}
         unknown = set(d) - known
         if unknown:
             raise ValueError(f"unknown LayerPlan fields: {sorted(unknown)}")
         return LayerPlan(
-            name=d["name"], spec=ConvSpec(**d["spec"]),
-            map_ref=tuple(d["map_ref"]), sig=tuple(d["sig"]),
+            name=d["name"],
+            spec=ConvSpec(**{k: _tup(v) for k, v in d["spec"].items()}),
+            map_ref=_tup(d["map_ref"]), sig=_tup(d["sig"]),
             group=d.get("group", ""),
             dataflow=TrainDataflowConfig.from_dict(d["dataflow"]),
             precision=PrecisionPolicy.from_dict(d["precision"]),
-            bn=d.get("bn", True), relu=d.get("relu", True))
+            bn=d.get("bn", True), act=d.get("act", "relu"),
+            act_first=d.get("act_first", False))
 
 
 @dataclasses.dataclass(frozen=True)
 class KmapSpec:
     """One kernel-map build step, with explicit dependency edges.
 
-    kind:          "sub" (submanifold), "down" (strided), "up" (transposed).
+    kind:          "sub" (submanifold), "down" (strided), "up" (transposed),
+                   "slice" (the columns of a sub-kernel's offsets in another
+                   map, ``slice_of``).
+    kernel_size, stride, tensor_stride: ints, or one int per axis.
     tensor_stride: stride of the tensor the map is built on ("up": the fine
                    tensor whose coordinates the inverse conv restores).
     adopts_output_table: a "down" map's strided-unique pass emits the child
@@ -167,16 +200,23 @@ class KmapSpec:
                    before this IR — part of the plan.
     transpose_of:  for "up" maps, the forward map whose pair lists are
                    swapped (decoder layers reuse encoder maps — same group).
+    slice_of:      for "slice" maps, the map whose columns they take.
+    dilate:        a "down" map's outputs are the dilation of its inputs by
+                   its (odd) kernel, as spconv's ``SparseConv3d`` makes
+                   them, not their floor grid; such a level may overflow its
+                   capacity (``kmap.check_capacity``).
     """
 
     ref: Tuple
     kind: str
-    kernel_size: int
-    stride: int
-    tensor_stride: int
+    kernel_size: int | tuple
+    stride: int | tuple
+    tensor_stride: int | tuple
     adopts_output_table: bool = False
     transpose_of: Optional[Tuple] = None
     table: str = "sort"
+    slice_of: Optional[Tuple] = None
+    dilate: bool = False
 
     #: coordinate-table strategies: "sort" rebuilds every table with a fresh
     #: argsort; "composed" allows scene-granular merge-composition of cached
@@ -187,19 +227,16 @@ class KmapSpec:
     TABLE_STRATEGIES = ("sort", "composed", "incremental")
 
     def __post_init__(self):
-        assert self.kind in ("sub", "down", "up"), self.kind
+        assert self.kind in ("sub", "down", "up", "slice"), self.kind
         assert self.table in self.TABLE_STRATEGIES, self.table
-        if self.kind == "up":
-            assert self.transpose_of is not None
+        assert (self.kind == "up") == (self.transpose_of is not None)
+        assert (self.kind == "slice") == (self.slice_of is not None)
+        assert not self.dilate or self.kind == "down", self.kind
 
     def to_dict(self) -> dict:
-        return {"ref": list(self.ref), "kind": self.kind,
-                "kernel_size": self.kernel_size, "stride": self.stride,
-                "tensor_stride": self.tensor_stride,
-                "adopts_output_table": self.adopts_output_table,
-                "transpose_of": (None if self.transpose_of is None
-                                 else list(self.transpose_of)),
-                "table": self.table}
+        d = dataclasses.asdict(self)
+        return {k: list(v) if isinstance(v, tuple) else v
+                for k, v in d.items()}
 
     @staticmethod
     def from_dict(d: dict) -> "KmapSpec":
@@ -207,21 +244,17 @@ class KmapSpec:
         unknown = set(d) - known
         if unknown:
             raise ValueError(f"unknown KmapSpec fields: {sorted(unknown)}")
-        t = d.get("transpose_of")
-        return KmapSpec(ref=tuple(d["ref"]), kind=d["kind"],
-                        kernel_size=d["kernel_size"], stride=d["stride"],
-                        tensor_stride=d["tensor_stride"],
-                        adopts_output_table=d.get("adopts_output_table", False),
-                        transpose_of=None if t is None else tuple(t),
-                        table=d.get("table", "sort"))
+        return KmapSpec(**{k: _tup(v) for k, v in d.items()})
 
 
-#: Structural ops of the execution program.  ("conv", name) runs a LayerPlan;
-#: the rest wire skips/residuals/head exactly as the models' hand-written
-#: forwards did: push/concat implement U-Net skip connections as a stack,
-#: res_begin/res_end bracket a residual block, ("head", pname) is a final
-#: dense projection.  A program with no head op returns the last features.
-OPS = ("conv", "push", "concat", "res_begin", "res_end", "head")
+#: Ops of the execution program, over a current tensor and named values.
+#: ("conv", layer) runs a LayerPlan on the current tensor; ("save", v) names
+#: it, ("load", v) makes a named value current; ("add", v), ("mul", v) and
+#: ("concat", v) combine it with a named value on the same voxels (residual
+#: sums, skip additions, gates; U-Net skip concatenation); ("act", kind)
+#: applies an activation of ``ACTS``; ("head", pname) is a final dense
+#: projection.  A program with no head op returns the last features.
+OPS = ("conv", "save", "load", "add", "mul", "concat", "act", "head")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -276,11 +309,13 @@ def build_maps_from_specs(specs: Sequence[KmapSpec], st: SparseTensor,
     floor-grid unique argsorts too.  Levels absent from ``tables`` build
     normally — composition degrades gracefully, never changes results.
 
-    Each spec's table phase (and a transposed map) traces under
-    ``jax.named_scope("kmap.<kind>_s<s>")`` (``s``: the stride of the
-    tensor the map is built on, as in its ``ref``), so a device trace can
-    say which map an op builds; the searches of all specs run batched,
-    under ``search/join`` and ``search/compact`` alone.
+    Each spec's table phase (and a transposed or column-subset map) traces
+    under ``jax.named_scope("kmap.<kind>_s<s>")`` (``s``: the stride of the
+    tensor the map is built on, ``8x8x4`` where the axes differ), and a
+    dilated level's output cells under ``kmap.dilate_s<s>`` inside it, so a
+    device trace can say which map an op builds; the searches of all specs
+    run batched, under ``search/join`` and ``search/compact`` alone.  Each
+    column-subset map counts ``kmap.offset_slices`` at trace time.
     """
     if cache is None:   # NOT `or`: an empty caller cache is falsy but wanted
         cache = MapCache.for_tensor(st)
@@ -297,12 +332,12 @@ def build_maps_from_specs(specs: Sequence[KmapSpec], st: SparseTensor,
     pending: dict = {}
     tensors = {st.stride: st}
     for ms in specs:
-        if ms.kind == "up":
+        if ms.kind in ("up", "slice"):
             continue
-        with jax.named_scope(f"kmap.{ms.kind}_s{ms.tensor_stride}"):
+        with jax.named_scope(f"kmap.{ms.kind}_s{stride_name(ms.tensor_stride)}"):
             p = prepare_kmap(tensors[ms.tensor_stride], ms.kernel_size,
                              1 if ms.kind == "sub" else ms.stride,
-                             cache=cache)
+                             cache=cache, dilate=ms.dilate)
         pending[ms.ref] = p
         if ms.kind == "down":
             tensors[p.out_stride] = SparseTensor(
@@ -313,10 +348,15 @@ def build_maps_from_specs(specs: Sequence[KmapSpec], st: SparseTensor,
     built = dict(zip(pending, search_kmaps(list(pending.values()))))
     maps: dict = {}
     for ms in specs:
+        scope = f"kmap.{ms.kind}_s{stride_name(ms.tensor_stride)}"
         if ms.kind == "up":
-            with jax.named_scope(f"kmap.{ms.kind}_s{ms.tensor_stride}"):
+            with jax.named_scope(scope):
                 built[ms.ref] = transpose_kmap(built[ms.transpose_of],
                                                tensors[ms.tensor_stride])
+        elif ms.kind == "slice":
+            obs.count("kmap.offset_slices")
+            with jax.named_scope(scope):
+                built[ms.ref] = slice_kmap(built[ms.slice_of], ms.kernel_size)
         maps[ms.ref] = built[ms.ref]
     return maps
 
@@ -333,11 +373,16 @@ def scene_entry_arrays(map_specs: Sequence[KmapSpec], st: SparseTensor,
     delta path) — adopted so the build skips the scene's root argsort.
     tables: optional pre-composed deeper-level tables (the incremental
     cell-ladder path) — see ``build_maps_from_specs``.
+
+    Column-subset ("slice") maps are left out: a composed batch cuts them
+    from its composed parents (``kmap.compose_kmaps``).
     """
     cache = MapCache.for_tensor(st)
     if root_table is not None:
         cache.adopt(st.coords, root_table)
-    maps = build_maps_from_specs(map_specs, st, cache, tables=tables)
+    maps = build_maps_from_specs([ms for ms in map_specs
+                                  if ms.kind != "slice"], st, cache,
+                                 tables=tables)
     root = cache.table(st)   # cache hit: the table the build sorted/adopted
     return maps, root.sorted_keys, root.order
 
@@ -348,10 +393,16 @@ def scene_entry_from_arrays(map_specs: Sequence[KmapSpec], maps: dict,
     """Extract the host-side ``SceneEntry`` from a (possibly padded) scene
     build: numpy kernel-map fields, per-level valid row counts, and the
     root table trimmed to its valid prefix (PAD keys sort last, so the
-    first ``n`` entries ARE the exact-size table delta-merge expects)."""
+    first ``n`` entries ARE the exact-size table delta-merge expects).
+    Raises when a dilated level overflowed its capacity."""
+    check_capacity({ms.ref: maps[ms.ref] for ms in map_specs if ms.dilate})
     sizes = {root_stride: n}
     entry_maps: dict = {}
     for ms in map_specs:
+        if ms.kind == "slice":
+            entry_maps[ms.ref] = {"slice_of": ms.slice_of,
+                                  "kernel_size": ms.kernel_size}
+            continue
         km = maps[ms.ref]
         if ms.kind == "down":
             sizes[km.out_stride] = int(km.n_out)
@@ -361,8 +412,8 @@ def scene_entry_from_arrays(map_specs: Sequence[KmapSpec], maps: dict,
             "ws_in": np.asarray(km.ws_in), "ws_out": np.asarray(km.ws_out),
             "ws_count": np.asarray(km.ws_count),
             "bitmask": np.asarray(km.bitmask),
-            "in_stride": ms.tensor_stride * (ms.stride if ms.kind == "up"
-                                             else 1),
+            "in_stride": (stride_mul(ms.tensor_stride, ms.stride)
+                          if ms.kind == "up" else ms.tensor_stride),
             "out_stride": km.out_stride, "kernel_size": km.kernel_size,
             "transpose_of": ms.transpose_of}
     return SceneEntry(n=n, sizes=sizes, maps=entry_maps,
@@ -571,12 +622,11 @@ class NetworkPlan:
             maps = self.build_maps(st)
         by_name = {lp.name: lp for lp in self.layers}
         x = st
-        skips: list = []
-        resid: list = []
-        for op in self.ops:
-            kind = op[0]
+        named: dict = {}
+        for kind, *arg in self.ops:
+            v = arg[0] if arg else None
             if kind == "conv":
-                lp = by_name[op[1]]
+                lp = by_name[v]
                 fwd = lp.dataflow.fwd
                 plan = (plans or {}).get(
                     (lp.map_ref, fwd.effective_splits, fwd.sorted))
@@ -584,26 +634,28 @@ class NetworkPlan:
                     x = apply_conv(params[lp.name], x, maps[lp.map_ref],
                                    lp.dataflow, precision=lp.precision,
                                    plan=plan)
-                    if lp.bn:
-                        x = bn_relu(params[f"{lp.name}_bn"], x, relu=lp.relu,
-                                    mode=bn_mode)
-            elif kind == "push":
-                skips.append(x)
+                    if lp.bn or lp.act != "none":
+                        x = norm_act(params[f"{lp.name}_bn"] if lp.bn
+                                     else None, x, lp.act, lp.act_first,
+                                     mode=bn_mode)
+            elif kind == "save":
+                named[v] = x
+            elif kind == "load":
+                x = named[v]
+            elif kind == "add":
+                x = x.replace_feats(x.feats + named[v].feats)
+            elif kind == "mul":
+                x = x.replace_feats(x.feats * named[v].feats)
             elif kind == "concat":
-                skip = skips.pop()
-                x = x.replace_feats(jnp.concatenate([x.feats, skip.feats],
+                x = x.replace_feats(jnp.concatenate([x.feats, named[v].feats],
                                                     axis=1))
-            elif kind == "res_begin":
-                resid.append(x.feats)
-            elif kind == "res_end":
-                idn = resid.pop()
-                y = jax.nn.relu(x.feats +
-                                (idn if idn.shape == x.feats.shape else 0))
-                x = x.replace_feats(jnp.where(x.valid_mask[:, None], y, 0))
+            elif kind == "act":
+                x = x.replace_feats(jnp.where(x.valid_mask[:, None],
+                                              ACTS[v](x.feats), 0))
             elif kind == "head":
-                return x.feats @ params[op[1]]["w"]
+                return x.feats @ params[v]["w"]
             else:
-                raise ValueError(f"unknown plan op {op!r}")
+                raise ValueError(f"unknown plan op {(kind, *arg)!r}")
         return x.feats
 
     # -------------------------------------------------------- serialization
@@ -616,18 +668,46 @@ class NetworkPlan:
     @staticmethod
     def from_dict(d: dict) -> "NetworkPlan":
         version = d.get("version", PLAN_VERSION)
-        if version != PLAN_VERSION:
+        if version not in (2, PLAN_VERSION):
             raise ValueError(f"unsupported NetworkPlan version {version!r} "
                              f"(expected {PLAN_VERSION})")
         known = {"version", "arch", "layers", "ops", "map_specs"}
         unknown = set(d) - known
         if unknown:
             raise ValueError(f"unknown NetworkPlan fields: {sorted(unknown)}")
+        layers = tuple(LayerPlan.from_dict(x) for x in d["layers"])
+        ops = tuple(tuple(op) for op in d["ops"])
         return NetworkPlan(
-            arch=d["arch"],
-            layers=tuple(LayerPlan.from_dict(x) for x in d["layers"]),
-            ops=tuple(tuple(op) for op in d["ops"]),
+            arch=d["arch"], layers=layers,
+            ops=ops if version == PLAN_VERSION else _ops_from_v2(ops, layers),
             map_specs=tuple(KmapSpec.from_dict(x) for x in d["map_specs"]))
+
+
+def _ops_from_v2(ops: Sequence[tuple], layers: Sequence[LayerPlan]) -> tuple:
+    """A version-2 program in named values: its skip stack (``push`` /
+    ``concat``) and residual brackets (``res_begin`` / ``res_end``: ReLU of
+    the sum, the identity added only where its width matches)."""
+    width = {lp.name: lp.spec.out_channels for lp in layers}
+    out, stack, c = [], [], None
+    for op in ops:
+        kind = op[0]
+        if kind in ("push", "res_begin"):
+            name = f"v{len(out)}"
+            stack.append((name, c))
+            out.append(("save", name))
+            continue
+        if kind == "concat":
+            name, w = stack.pop()
+            out.append(("concat", name))
+            c += w
+        elif kind == "res_end":
+            name, w = stack.pop()
+            out += [("add", name)] if w == c else []
+            out.append(("act", "relu"))
+        else:
+            out.append(tuple(op))
+            c = width.get(op[1], c)
+    return tuple(out)
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,7 @@ schemes tune.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
@@ -17,7 +18,7 @@ import jax.numpy as jnp
 from repro.core import dataflows as df
 from repro.core.kmap import KernelMap, MapCache, build_kmap, transpose_kmap
 from repro.core.precision import FP32, PrecisionPolicy
-from repro.core.sparse_tensor import SparseTensor
+from repro.core.sparse_tensor import SparseTensor, axis_strides
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,20 +101,25 @@ def sparse_conv_apply(feats: jax.Array, w: jax.Array, kmap: KernelMap,
 
 @dataclasses.dataclass(frozen=True)
 class ConvSpec:
+    """One conv layer's shape.  ``kernel_size`` and ``stride`` are an int
+    (the same on every axis) or a tuple of one int per spatial axis, as
+    Cylinder3D's (1, 3, 3) kernels and (2, 2, 1) pools have."""
+
     in_channels: int
     out_channels: int
-    kernel_size: int = 3
-    stride: int = 1
+    kernel_size: int | tuple = 3
+    stride: int | tuple = 1
     transposed: bool = False
     bias: bool = False
 
     @property
     def volume(self) -> int:
-        return self.kernel_size ** 3  # models here are 3D
+        """Kernel offsets: the product of the kernel's extent per axis."""
+        return math.prod(axis_strides(self.kernel_size))
 
 
 def init_conv(key: jax.Array, spec: ConvSpec, ndim: int = 3, dtype=jnp.float32) -> dict:
-    kd = spec.kernel_size ** ndim
+    kd = math.prod(axis_strides(spec.kernel_size, ndim))
     fan_in = spec.in_channels * kd
     w = jax.random.normal(key, (kd, spec.in_channels, spec.out_channels), dtype) * (fan_in ** -0.5)
     params = {"w": w}

@@ -23,6 +23,28 @@ import numpy as np
 INVALID_COORD = np.int32(0x3FFFFFF)
 
 
+def axis_strides(s, ndim: int = 3) -> tuple:
+    """A stride (or kernel shape) as one int per spatial axis: an int is the
+    same on every axis, a tuple is already per axis."""
+    return tuple(s) if isinstance(s, tuple) else (s,) * ndim
+
+
+def stride_mul(a, b):
+    """Per-axis product of two strides; an int when it is the same on every
+    axis, so each stride has one spelling (a dict key, a static field)."""
+    if isinstance(a, int) and isinstance(b, int):
+        return a * b
+    s = tuple(x * y for x, y in zip(axis_strides(a), axis_strides(b)))
+    return s[0] if len(set(s)) == 1 else s
+
+
+def stride_name(s) -> str:
+    """A stride as it appears in scope names: ``4``, or ``8x8x4`` when the
+    axes differ."""
+    s = axis_strides(s)
+    return str(s[0]) if len(set(s)) == 1 else "x".join(map(str, s))
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class SparseTensor:
@@ -32,7 +54,9 @@ class SparseTensor:
         INVALID_COORD in every spatial column.
     feats:  (Nmax, C) — feature rows; padded rows are zero.
     num_valid: () int32 — number of real rows.
-    stride: static int — the tensor stride (grows by conv stride).
+    stride: static int, or a tuple of one int per spatial axis — the tensor
+        stride (grows by conv stride; ``(16, 16, 4)`` after strides that
+        leave an axis alone).
     batch_bound: static int — declared number of batches (0 = unknown).
     spatial_bound: static int — declared max |spatial coordinate| (0 =
         unknown).  The packed-key mapping engine (core/hashing.py) derives

@@ -22,7 +22,7 @@ from repro.core.plan import (LayerPlan, ModelDecl, NetworkPlan, compile_plan,
                              pyramid_map_specs)
 from repro.core.sparse_conv import ConvSpec, TrainDataflowConfig, init_conv
 from repro.core.sparse_tensor import SparseTensor
-from repro.models.minkunet import _bn_relu, _bn_relu_init  # noqa: F401 (re-export)
+from repro.models.minkunet import _bn_relu_init
 
 
 @dataclasses.dataclass(frozen=True)

@@ -27,9 +27,8 @@ from repro.core.plan import (KmapSpec, LayerPlan, ModelDecl, NetworkPlan,
 from repro.core.sparse_conv import ConvSpec, TrainDataflowConfig, init_conv
 from repro.core.sparse_tensor import SparseTensor
 
-# Shared masked-BN(+ReLU) now lives with the plan executor; these aliases
-# keep the historical names importable (centerpoint, tests).
-_bn_relu = planlib.bn_relu
+# Shared masked-BN init lives with the plan executor; the alias keeps the
+# historical name importable (centerpoint).
 _bn_relu_init = planlib.bn_relu_init
 
 
@@ -68,14 +67,17 @@ def declare(cfg: MinkUNetConfig) -> ModelDecl:
         LayerPlan("stem1", ConvSpec(cfg.in_channels, c0, 3), ("sub", 1), (1, 3, "sub")),
         LayerPlan("stem2", ConvSpec(c0, c0, 3), ("sub", 1), (1, 3, "sub")),
     ]
-    ops = [("conv", "stem1"), ("conv", "stem2"), ("push",)]
+    ops = [("conv", "stem1"), ("conv", "stem2"), ("save", "skip0")]
 
     def res_block(prefix: str, cin_b: int, c: int, sig, ref):
+        # ReLU(conv2(conv1(x)) + x); the identity only where widths match
         layers.append(LayerPlan(f"{prefix}_1", ConvSpec(cin_b, c, 3), ref, sig))
         layers.append(LayerPlan(f"{prefix}_2", ConvSpec(c, c, 3), ref, sig,
-                                relu=False))
-        ops.extend([("res_begin",), ("conv", f"{prefix}_1"),
-                    ("conv", f"{prefix}_2"), ("res_end",)])
+                                act="none"))
+        ops.extend([("save", prefix), ("conv", f"{prefix}_1"),
+                    ("conv", f"{prefix}_2")]
+                   + ([("add", prefix)] if cin_b == c else [])
+                   + [("act", "relu")])
 
     cin = c0
     stride = 1
@@ -88,7 +90,7 @@ def declare(cfg: MinkUNetConfig) -> ModelDecl:
         for b in range(cfg.blocks_per_stage):
             res_block(f"enc{i}b{b}", ce, ce, (stride, 3, "sub"), ("sub", stride))
         if i < len(cfg.enc_channels) - 1:
-            ops.append(("push",))
+            ops.append(("save", f"skip{i + 1}"))
         cin = ce
 
     skips = [c0] + [w(c) for c in cfg.enc_channels[:-1]]
@@ -99,7 +101,7 @@ def declare(cfg: MinkUNetConfig) -> ModelDecl:
         s = 2 ** lvl
         layers.append(LayerPlan(f"up{i}", ConvSpec(cin, cd, 2, stride=2, transposed=True),
                                 ("up", s), (s, 2, "up")))
-        ops.extend([("conv", f"up{i}"), ("concat",)])
+        ops.extend([("conv", f"up{i}"), ("concat", f"skip{lvl}")])
         cskip = skips[-(i + 1)]
         for b in range(cfg.blocks_per_stage):
             cin_b = cd + cskip if b == 0 else cd
@@ -149,7 +151,7 @@ def apply(params, st: SparseTensor, cfg: MinkUNetConfig,
     pre-compiled ``nplan``, in which case ``assignment``/``precision`` are
     already baked in) — bit-identical to the historical hand-written
     forward.  ``bn_mode="affine"`` runs inference-mode normalization (see
-    ``core.plan.bn_relu``) — required by the serving engine so batched and
+    ``core.plan.norm_act``) — required by the serving engine so batched and
     per-scene forwards agree bit-for-bit."""
     if nplan is None:
         nplan = network_plan(cfg, assignment=assignment, precision=precision)

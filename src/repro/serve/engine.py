@@ -71,12 +71,14 @@ from repro.core import dataflows as df
 from repro.core import hashing
 from repro.core.autotuner import timeit_fn
 from repro.core.kmap import (SceneEntry, cell_ladder, cell_ladder_delta,
-                             compose_kmaps, compose_split_plans, ladder_tables)
+                             check_capacity, compose_kmaps,
+                             compose_split_plans, ladder_tables)
 from repro.core.plan import (KmapSpec, NetworkPlan, PlanTuner,
                              scene_entry_arrays, scene_entry_from_arrays)
 from repro.core.sparse_conv import TrainDataflowConfig
-from repro.core.sparse_tensor import INVALID_COORD, SparseTensor
-from repro.models import centerpoint, minkunet
+from repro.core.sparse_tensor import (INVALID_COORD, SparseTensor,
+                                      axis_strides, stride_mul)
+from repro.models import centerpoint, cylinder3d, minkunet
 from repro.serve.batcher import (PackedBatch, Scene, SceneBatcher, SceneDelta,
                                  SceneResult, apply_delta)
 from repro.serve.bucketing import BucketLadder
@@ -97,7 +99,7 @@ class ArchBinding:
     in_channels_of: Callable[[object], int]
 
 
-def _minkunet_outputs(cfg, st, maps, feats):
+def _input_voxel_outputs(cfg, st, maps, feats):
     # logits are per input voxel: rows align with the stride-1 input coords
     return st.coords, feats, st.num_valid
 
@@ -109,20 +111,26 @@ def _centerpoint_outputs(cfg, st, maps, feats):
 
 
 def _arch_bindings() -> Dict[str, ArchBinding]:
-    from repro.configs import centerpoint_waymo, minkunet_kitti
+    from repro.configs import centerpoint_waymo, cylinder3d_kitti, minkunet_kitti
 
     return {
         "minkunet_kitti": ArchBinding(
             name="minkunet_kitti", model=minkunet,
             default_config=minkunet_kitti.CONFIG_BENCH,
             out_stride_of=lambda cfg: 1,
-            outputs_of=_minkunet_outputs,
+            outputs_of=_input_voxel_outputs,
             in_channels_of=lambda cfg: cfg.in_channels),
         "centerpoint_waymo": ArchBinding(
             name="centerpoint_waymo", model=centerpoint,
             default_config=centerpoint_waymo.CONFIG_BENCH,
             out_stride_of=lambda cfg: 2 ** len(cfg.channels),
             outputs_of=_centerpoint_outputs,
+            in_channels_of=lambda cfg: cfg.in_channels),
+        "cylinder3d_kitti": ArchBinding(
+            name="cylinder3d_kitti", model=cylinder3d,
+            default_config=cylinder3d_kitti.CONFIG_BENCH,
+            out_stride_of=lambda cfg: 1,
+            outputs_of=_input_voxel_outputs,
             in_channels_of=lambda cfg: cfg.in_channels),
     }
 
@@ -139,6 +147,15 @@ LATENCY_WINDOW = 8192
 
 #: per-phase duration samples kept per phase name (same rationale)
 PHASE_WINDOW = 4096
+
+
+def _box_coords(n: int) -> np.ndarray:
+    """``n`` distinct voxels filling a cube about the origin: a warm-up
+    scene whose dilated levels (``kmap._dilate``) hold fewer rows than it."""
+    side = int(np.ceil(n ** (1 / 3) - 1e-9))
+    axis = np.arange(side, dtype=np.int32) - side // 2
+    grid = np.stack(np.meshgrid(axis, axis, axis, indexing="ij"), -1)
+    return grid.reshape(-1, 3)[:n]
 
 
 def _spec_of(leaf):
@@ -194,6 +211,10 @@ class EngineStats:
     # build's neighbour search runs over
     scene_rows: int = 0
     scene_rung_rows: int = 0
+    # the same builds' levels below the root: rows they emitted, and the
+    # capacity those levels were padded to
+    level_rows: int = 0
+    level_cap_rows: int = 0
     # flush triggers beyond the explicit flush() call
     deadline_flushes: int = 0    # max_wait_ms expiries
     count_flushes: int = 0       # flush_count threshold crossings
@@ -245,6 +266,8 @@ class EngineStats:
                              "rows": self.scene_rows,
                              "rung_rows": self.scene_rung_rows,
                              "compiles": dict(self.scene_compiles)},
+            "level_tables": {"rows": self.level_rows,
+                             "cap_rows": self.level_cap_rows},
             "deadline_flushes": self.deadline_flushes,
             "count_flushes": self.count_flushes,
             "deadline_cuts": self.deadline_cuts,
@@ -261,7 +284,8 @@ class EngineStats:
 class Engine:
     """Front end: ``submit()`` scenes, ``flush()`` to run queued work.
 
-    arch: "minkunet_kitti" | "centerpoint_waymo" (see ``ARCHS``).
+    arch: "minkunet_kitti" | "centerpoint_waymo" | "cylinder3d_kitti" (see
+        ``ARCHS``).
     plans: a PlanRegistry (or path to one) holding tuned per-group dataflow
         assignments; missing entries fall back to the default config.
     map_strategy: coordinate-table strategy override ("sort" / "composed" /
@@ -385,10 +409,15 @@ class Engine:
         self._plan_builders: Dict[int, Callable] = {}
         self._scene_builders: Dict[int, Callable] = {}
         self._scene_delta_builders: Dict[int, Callable] = {}
-        #: down-map out-strides, ascending — the cell ladder's levels
+        #: floor-grid down-map out-strides, ascending — the cell ladder's
+        #: levels (a dilated level is no floor grid: it builds afresh)
         self._down_strides = tuple(sorted(
-            ms.tensor_stride * ms.stride for ms in self.nplan.map_specs
-            if ms.kind == "down"))
+            (stride_mul(ms.tensor_stride, ms.stride)
+             for ms in self.nplan.map_specs
+             if ms.kind == "down" and not ms.dilate), key=axis_strides))
+        #: maps whose level may overflow its capacity (dilated outputs)
+        self._dilated = [ms.ref for ms in self.nplan.map_specs
+                         if ms.dilate]
         #: (kind, rung) marks queued by trace-time side effects, drained by
         #: the jit wrappers into structured ``compile`` trace events
         self._compile_marks: List[tuple] = []
@@ -596,8 +625,9 @@ class Engine:
 
     def _fetch_scene_entry(self, built, n: int, cap: int) -> SceneEntry:
         """Wait for a scene builder's outputs (``scene_wait``), copy them
-        into a host ``SceneEntry`` (``scene_fetch``), and count the build's
-        valid and scene-rung rows."""
+        into a host ``SceneEntry`` (``scene_fetch``; raises when a dilated
+        level overflowed), and count the build's valid and scene-rung rows,
+        at the root and at the levels below it."""
         with self._phase("scene_wait", cap=cap):
             maps, keys, order = jax.block_until_ready(built)
         with self._phase("scene_fetch", cap=cap):
@@ -605,6 +635,8 @@ class Engine:
                                           keys, order)
         self.stats.scene_rows += n
         self.stats.scene_rung_rows += cap
+        self.stats.level_rows += sum(ent.sizes.values()) - n
+        self.stats.level_cap_rows += cap * (len(ent.sizes) - 1)
         return ent
 
     def _scene_entry(self, scene: Scene) -> SceneEntry:
@@ -662,6 +694,8 @@ class Engine:
         if maps is None:
             with self._phase("map_build", bucket=batch.bucket):
                 maps = self._builder_for(batch.bucket)(batch.st)
+                if self._dilated:
+                    check_capacity({r: maps[r] for r in self._dilated})
             if pspecs:
                 with self._phase("plan_build", bucket=batch.bucket):
                     plans = self._plan_builder_for(batch.bucket)(maps)
@@ -994,10 +1028,7 @@ class Engine:
         c = channels or self.binding.in_channels_of(self.cfg)
         if self.map_strategy in ("composed", "incremental"):
             for cap in self._scene_ladder.capacities:
-                rng = np.random.default_rng(cap)
-                coords = np.unique(rng.integers(
-                    -self.batcher.spatial_bound, self.batcher.spatial_bound,
-                    size=(2 * cap, 3), dtype=np.int32), axis=0)[:cap]
+                coords = _box_coords(cap)
                 st = self._scene_tensor(
                     Scene(coords=coords,
                           feats=np.zeros((coords.shape[0], c), np.float32)),
@@ -1022,9 +1053,7 @@ class Engine:
         for cap in self.ladder.capacities:
             n = cap   # fill the bucket exactly so every rung compiles
             rng = np.random.default_rng(cap)
-            coords = rng.integers(-self.batcher.spatial_bound,
-                                  self.batcher.spatial_bound, size=(n, 3),
-                                  dtype=np.int32)
+            coords = _box_coords(n)
             scene = Scene(coords=coords, feats=rng.normal(size=(n, c)).astype(np.float32))
             # go through the REAL dispatch path: it commits the packed batch
             # to this engine's device, and a warmup executed with any other

@@ -133,15 +133,15 @@ def test_plan_rejects_unknown_fields_and_versions():
 def _precall_centerpoint(params, st, cfg, maps, bn_mode="batch"):
     """The pre-plan hand-written CenterPoint forward, verbatim."""
     x = apply_conv(params["stem"], st, maps[("sub", 1)])
-    x = planlib.bn_relu(params["stem_bn"], x, mode=bn_mode)
+    x = planlib.norm_act(params["stem_bn"], x, mode=bn_mode)
     stride = 1
     for i in range(len(cfg.channels)):
         x = apply_conv(params[f"down{i}"], x, maps[("down", stride)])
-        x = planlib.bn_relu(params[f"down{i}_bn"], x, mode=bn_mode)
+        x = planlib.norm_act(params[f"down{i}_bn"], x, mode=bn_mode)
         stride *= 2
         for b in range(cfg.sub_convs_per_stage):
             x = apply_conv(params[f"sub{i}_{b}"], x, maps[("sub", stride)])
-            x = planlib.bn_relu(params[f"sub{i}_{b}_bn"], x, mode=bn_mode)
+            x = planlib.norm_act(params[f"sub{i}_{b}_bn"], x, mode=bn_mode)
     return x.feats
 
 

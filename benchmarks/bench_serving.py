@@ -138,7 +138,7 @@ def _plan_compose_leg(arch: str, scenes, bound: int, ladder: BucketLadder,
     the bitmask argsorts on every batch.  Same composed batch maps for
     both; plans are built, never executed, so the leg runs everywhere."""
     from repro.core import dataflows as df
-    from repro.core.kmap import compose_kmaps, compose_split_plans
+    from repro.core.kmap import compose_split_plans
     from repro.core.sparse_conv import TrainDataflowConfig
     from repro.serve.plans import PlanRegistry
 
@@ -153,12 +153,13 @@ def _plan_compose_leg(arch: str, scenes, bound: int, ladder: BucketLadder,
     group_idx = eng.batcher.plan([s.num_points for s in scenes])[0]
     group = [scenes[i] for i in group_idx]
     batch = eng.batcher.pack(group)
-    entries = [eng._scene_entry(s) for s in group]
-    maps = compose_kmaps(entries, batch.bucket)
+    entries = eng._scene_entries(group)
+    maps = eng._compose(entries, batch.bucket)     # the device composer
     builder = eng._plan_builder_for(batch.bucket)
 
     def composed():
-        return [compose_split_plans(entries, ref, ns, srt, batch.bucket)
+        return [compose_split_plans(eng.nplan.map_specs, entries, ref, ns,
+                                    srt, batch.bucket)
                 for ref, ns, srt in specs]
 
     def jitted():

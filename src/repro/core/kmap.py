@@ -53,7 +53,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import math
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -372,10 +372,15 @@ def check_capacity(maps: Dict[tuple, "KernelMap"]) -> None:
     (its ``n_out`` then counts every cell it needed): a scene's rows are
     never dropped to fit.  Reads each map's ``n_out`` on the host."""
     for ref, km in maps.items():
-        need = int(km.n_out)
-        if need > km.capacity:
-            raise ValueError(f"map {ref} needs {need} rows, more than its "
-                             f"level's capacity {km.capacity}")
+        check_rows(ref, int(km.n_out), km.capacity)
+
+
+def check_rows(ref, need: int, capacity: int) -> None:
+    """``check_capacity`` of one map from host counts: raise when ``need``
+    rows do not fit the level's ``capacity``."""
+    if need > capacity:
+        raise ValueError(f"map {ref} needs {need} rows, more than its "
+                         f"level's capacity {capacity}")
 
 
 def _compact_ws(m_out: jax.Array):
@@ -397,6 +402,25 @@ def _compact_ws(m_out: jax.Array):
     found = rows < cap
     return (jnp.where(found, ins, -1), jnp.where(found, rows, -1),
             jnp.sum(hit, axis=1, dtype=jnp.int32))
+
+
+def _compact_groups(m_outs: Sequence[jax.Array]) -> list:
+    """``_compact_ws`` of many maps: every offset column compacts alone, so
+    the columns of all maps of one capacity share one sort.  Returns one
+    (ws_in, ws_out, ws_count) per map, in order."""
+    by_cap: Dict[int, list] = {}
+    for i, m in enumerate(m_outs):
+        by_cap.setdefault(m.shape[0], []).append(i)
+    ws = [None] * len(m_outs)
+    for idx in by_cap.values():
+        ws_in, ws_out, ws_count = _compact_ws(
+            jnp.concatenate([m_outs[i] for i in idx], axis=1))
+        lo = 0
+        for i in idx:
+            hi = lo + m_outs[i].shape[1]
+            ws[i] = (ws_in[lo:hi], ws_out[lo:hi], ws_count[lo:hi])
+            lo = hi
+    return ws
 
 
 @dataclasses.dataclass(frozen=True)
@@ -524,20 +548,7 @@ def search_kmaps(pending: Sequence[PendingKmap]) -> list:
             out_valid = jnp.arange(cap) < p.n_out
             m_outs.append(jnp.where(out_valid[:, None],
                                     f.reshape(-1, cap).T, -1))
-        # every offset column compacts alone, so the columns of all maps
-        # of one capacity share one sort
-        by_cap: Dict[int, list] = {}
-        for i, m in enumerate(m_outs):
-            by_cap.setdefault(m.shape[0], []).append(i)
-        ws = [None] * len(pending)
-        for idx in by_cap.values():
-            ws_in, ws_out, ws_count = _compact_ws(
-                jnp.concatenate([m_outs[i] for i in idx], axis=1))
-            lo = 0
-            for i in idx:
-                hi = lo + m_outs[i].shape[1]
-                ws[i] = (ws_in[lo:hi], ws_out[lo:hi], ws_count[lo:hi])
-                lo = hi
+        ws = _compact_groups(m_outs)
         maps = []
         for p, m_out, (ws_in, ws_out, ws_count) in zip(pending, m_outs, ws):
             out_valid = jnp.arange(m_out.shape[0]) < p.n_out
@@ -630,10 +641,27 @@ def slice_kmap(km: KernelMap, kernel_size) -> KernelMap:
 #   table ladders merge-composed into batch tables (adopted into a MapCache
 #   via ``build_maps_from_specs(..., tables=...)``, killing every argsort of
 #   a batch map build);
-# * ``compose_kmaps`` — per-scene *kernel map* stacks concatenated into the
-#   batch map stack (host-side numpy, no device compute at all): warm scenes
-#   skip mapping entirely; only cold scenes ever build maps, at their own
-#   size.  Bit-identical to a fresh batch build (tests/test_streaming.py).
+# * ``compose_kmaps`` — per-scene *kernel map* stacks, kept on the device,
+#   placed into the batch map stack on the device (``compose_place`` per
+#   scene, then ``compose_finish``): warm scenes skip mapping entirely; only
+#   cold scenes ever build maps, at their own size.  Bit-identical to a
+#   fresh batch build (tests/test_streaming.py).
+
+
+def level_key(stride) -> str:
+    """A pyramid level's key in a scene entry's device arrays: its stride's
+    name (``4``, ``8x8x4``), so int and per-axis strides sort together."""
+    return stride_name(stride)
+
+
+def map_strides(ms) -> tuple:
+    """(input, output) tensor strides of a map spec: a "down" map outputs
+    the next level, an "up" map reads it, the others stay on their level."""
+    if ms.kind == "down":
+        return ms.tensor_stride, stride_mul(ms.tensor_stride, ms.stride)
+    if ms.kind == "up":
+        return stride_mul(ms.tensor_stride, ms.stride), ms.tensor_stride
+    return ms.tensor_stride, ms.tensor_stride
 
 
 @dataclasses.dataclass
@@ -641,13 +669,16 @@ class SceneEntry:
     """Cached per-scene mapping work, keyed by the scene's content digest.
 
     n:          scene row count (level-1 size).
-    sizes:      tensor-stride -> per-scene row count at that pyramid level.
-    maps:       map ref -> numpy kernel-map fields plus the static metadata
-                composition needs (``in_stride``/``out_stride``/``kernel``);
-                a column-subset map holds only its ``slice_of`` ref and
-                kernel, and is cut from the composed parent.
-    root_keys/root_order: the scene's sorted batch-0 CoordTable — the object
-                ``CoordTable.delta_merge`` updates on streaming frames.
+    sizes:      tensor-stride -> per-scene row count at that pyramid level
+                (host ints).
+    arrays:     the scene builder's device arrays, padded to the scene's
+                rung: ``"m_out"`` (map ref -> output-stationary map of each
+                searched "sub"/"down" map) and ``"coords"`` (``level_key``
+                -> the level's coordinates).  Pair lists, bitmasks, "up"
+                and "slice" maps are derived from the composed batch maps.
+    root_keys/root_order: the scene's sorted batch-0 CoordTable (host) —
+                the object ``CoordTable.delta_merge`` updates on streaming
+                frames.
     splits:     lazily-filled (map ref, ranges) -> per-split (sorted bitmask
                 values, local stable order) numpy pairs — the per-scene half
                 of ``compose_split_plans``.
@@ -657,29 +688,23 @@ class SceneEntry:
 
     n: int
     sizes: Dict[int, int]
-    maps: Dict[tuple, dict]
+    arrays: dict
     root_keys: np.ndarray
     root_order: np.ndarray
     splits: Dict[tuple, list] = dataclasses.field(default_factory=dict)
     ladder: Dict[int, tuple] = dataclasses.field(default_factory=dict)
 
     @property
+    def ndim(self) -> int:
+        """Spatial dimensions of the scene's coordinates."""
+        return next(iter(self.arrays["coords"].values())).shape[1] - 1
+
+    @property
     def nbytes(self) -> int:
-        """Host-memory footprint — the byte-aware scene-store LRU's unit.
-        Sums ``.nbytes`` of every numpy array the entry pins (maps, root
-        table, lazily-added split orders and ladder state); O(#arrays),
-        never touches array data."""
-        total = self.root_keys.nbytes + self.root_order.nbytes
-        for sm in self.maps.values():
-            for v in sm.values():
-                if isinstance(v, np.ndarray):
-                    total += v.nbytes
-        for runs in self.splits.values():
-            for vals, loc in runs:
-                total += vals.nbytes + loc.nbytes
-        for cells, counts in self.ladder.values():
-            total += cells.nbytes + counts.nbytes
-        return total
+        """Device-memory footprint — the byte-aware scene-store LRU's unit:
+        the bytes of the device arrays the entry pins.  O(#arrays), never
+        touches array data."""
+        return sum(a.nbytes for a in jax.tree.leaves(self.arrays))
 
 
 def scene_table_ladder(coords: np.ndarray, spec: KeySpec,
@@ -737,85 +762,135 @@ def compose_batch_tables(spec: KeySpec, ladders: Sequence[Dict[int, tuple]],
     return out
 
 
-def compose_kmaps(entries: Sequence[SceneEntry],
-                  capacity: int) -> Optional[Dict[tuple, KernelMap]]:
-    """Concatenate per-scene kernel-map stacks into the batch map stack.
+def compose_blank(entry: SceneEntry, capacity: int) -> dict:
+    """The empty batch map stack ``compose_place`` fills, as numpy: every
+    map row a miss (-1), every level row ``INVALID_COORD``, at
+    ``capacity`` rows and the widths of ``entry``'s arrays."""
+    a = entry.arrays
+    return {"m_out": {r: np.full((capacity, m.shape[1]), -1, np.int32)
+                      for r, m in a["m_out"].items()},
+            "coords": {k: np.full((capacity, c.shape[1]),
+                                  int(INVALID_COORD), np.int32)
+                       for k, c in a["coords"].items()}}
 
-    entries: cached SceneEntry per scene, in batch (= packing) order.
-    capacity: the batch bucket capacity every composed map is padded to.
 
-    Pure host-side numpy — scene blocks are copied with their input/output
-    row offsets added (misses stay -1), weight-stationary lists concatenate
-    valid prefixes per offset (scene blocks are already hits-first in row
-    order), bitmasks/coords concatenate with the batch column rewritten.
-    Returns None when composition does not apply (an empty scene, or a level
-    size exceeding the capacity).
-    """
+def _put_rows(dst: jax.Array, block: jax.Array, off) -> jax.Array:
+    """``block``'s rows written into ``dst`` from row ``off`` on; rows past
+    ``dst``'s end are dropped (a block may be taller than the batch).  The
+    start never clamps: ``dst`` is extended by the block's height first."""
+    cap = dst.shape[0]
+    block = block[:cap]
+    ext = jnp.concatenate([dst, jnp.zeros_like(block)])
+    return jax.lax.dynamic_update_slice(ext, block, (off, 0))[:cap]
+
+
+def compose_place(specs, acc: dict, scene: dict, b, offs: dict) -> dict:
+    """Traceable: scene ``b``'s map blocks placed into the batch map stack
+    ``acc`` (``compose_blank``'s structure, on the device).
+
+    scene: the scene entry's ``arrays``.  offs: ``level_key`` -> (the
+    scene's first row in the batch level, its rows there), int32.  Each
+    level's rows land at the scene's offset with the batch column
+    rewritten to ``b``; each map's rows at the offset of its output level,
+    its neighbour indices shifted by the offset of its input level (misses
+    stay -1).  The block's padding rows land past the scene's rows, where
+    ``acc`` still holds padding: scenes must be placed in batch order."""
+    with jax.named_scope("kmap.compose"):
+        out = {"m_out": {}, "coords": {}}
+        for k, dst in acc["coords"].items():
+            off, n = offs[k]
+            blk = scene["coords"][k]
+            first = jnp.where(jnp.arange(blk.shape[0]) < n, b, blk[:, 0])
+            out["coords"][k] = _put_rows(dst, blk.at[:, 0].set(first), off)
+        for ms in specs:
+            if ms.ref not in acc["m_out"]:
+                continue
+            in_s, out_s = map_strides(ms)
+            blk = scene["m_out"][ms.ref]
+            blk = jnp.where(blk >= 0, blk + offs[level_key(in_s)][0], -1)
+            out["m_out"][ms.ref] = _put_rows(acc["m_out"][ms.ref], blk,
+                                             offs[level_key(out_s)][0])
+    return out
+
+
+def compose_finish(specs, acc: dict, totals: dict) -> Dict[tuple, KernelMap]:
+    """Traceable: the batch kernel maps from a filled stack (``totals``:
+    ``level_key`` -> the batch's rows at that level, int32).  What a batch
+    build derives from its searched maps is derived here the same way: the
+    weight-stationary pair lists of every searched map (one compaction
+    sort), the bitmasks, the transposed "up" maps (``transpose_kmap``) and
+    the column-subset "slice" maps (``slice_kmap``) — so the result is
+    bit-identical to a fresh batch build."""
+    with jax.named_scope("kmap.compose"):
+        coords = acc["coords"]
+        searched = [ms for ms in specs if ms.ref in acc["m_out"]]
+        ws = _compact_groups([acc["m_out"][ms.ref] for ms in searched])
+        maps: Dict[tuple, KernelMap] = {}
+        for ms, (ws_in, ws_out, ws_count) in zip(searched, ws):
+            m_out = acc["m_out"][ms.ref]
+            out_s = map_strides(ms)[1]
+            maps[ms.ref] = KernelMap(
+                m_out=m_out, out_coords=coords[level_key(out_s)],
+                n_out=totals[level_key(out_s)], ws_in=ws_in, ws_out=ws_out,
+                ws_count=ws_count, bitmask=_bitmask(m_out >= 0),
+                out_stride=out_s, kernel_size=ms.kernel_size)
+        for ms in specs:
+            if ms.kind == "up":
+                c = coords[level_key(ms.tensor_stride)]
+                fine = SparseTensor(
+                    coords=c, feats=jnp.zeros((c.shape[0], 1), jnp.float32),
+                    num_valid=totals[level_key(ms.tensor_stride)],
+                    stride=ms.tensor_stride)
+                maps[ms.ref] = transpose_kmap(maps[ms.transpose_of], fine)
+            elif ms.kind == "slice":
+                maps[ms.ref] = slice_kmap(maps[ms.slice_of], ms.kernel_size)
+    return {ms.ref: maps[ms.ref] for ms in specs}
+
+
+def compose_offsets(entries: Sequence[SceneEntry],
+                    capacity: int) -> Optional[Dict[int, np.ndarray]]:
+    """Per level (tensor stride), each scene's first batch row and, last,
+    the batch's rows there (cumulative sizes, ``len(entries) + 1`` long).
+    None when composition does not apply: no scene, an empty scene, or a
+    level with more rows than ``capacity`` (a batch build then runs)."""
     if not entries or any(e.n == 0 for e in entries):
         return None
-    strides = set(entries[0].sizes)
-    for e in entries[1:]:
-        strides &= set(e.sizes)
-    offs = {s: np.cumsum([0] + [e.sizes[s] for e in entries]) for s in strides}
-    if any(offs[s][-1] > capacity for s in strides):
+    offs = {s: np.cumsum([0] + [e.sizes[s] for e in entries])
+            for s in entries[0].sizes}
+    if any(o[-1] > capacity for o in offs.values()):
         return None
-    maps: Dict[tuple, KernelMap] = {}
-    for ref in entries[0].maps:
-        m0 = entries[0].maps[ref]
-        if "slice_of" in m0:   # specs put a slice after its parent
-            maps[ref] = slice_kmap(maps[m0["slice_of"]], m0["kernel_size"])
-            continue
-        in_s, out_s = m0["in_stride"], m0["out_stride"]
-        if in_s not in strides or out_s not in strides:
-            return None
-        kd = m0["m_out"].shape[1]
-        d1 = m0["out_coords"].shape[1]
-        m_out = np.full((capacity, kd), -1, np.int32)
-        oc = np.full((capacity, d1), int(INVALID_COORD), np.int32)
-        bm = np.zeros((capacity,), np.int32)
-        for b, e in enumerate(entries):
-            sm = e.maps[ref]
-            n_o = e.sizes[out_s]
-            off_in, off_out = int(offs[in_s][b]), int(offs[out_s][b])
-            blk = sm["m_out"][:n_o]
-            m_out[off_out:off_out + n_o] = np.where(blk >= 0, blk + off_in, -1)
-            c = sm["out_coords"][:n_o].copy()
-            c[:, 0] = b
-            oc[off_out:off_out + n_o] = c
-            bm[off_out:off_out + n_o] = sm["bitmask"][:n_o]
-        transpose_of = m0.get("transpose_of")
-        if transpose_of is not None and transpose_of in maps:
-            # a fresh batch build derives an up map's pair lists by swapping
-            # the forward strided map's (transpose_kmap) — mirror that
-            # exactly, from the already-composed down map (map-spec order
-            # puts downs before ups), so slot layout matches bit-for-bit
-            # even when scene rows are not lexicographically sorted
-            fwd = maps[transpose_of]
-            ws_in_j, ws_out_j, wc_j = fwd.ws_out, fwd.ws_in, fwd.ws_count
-        else:
-            # weight-stationary lists re-derived from the composed m_out in
-            # one vectorized pass — hits first in row order per offset
-            # column, the exact ``_compact_ws`` layout (scene blocks are
-            # row-ordered, so this equals concatenating the per-scene valid
-            # prefixes)
-            ws_in = np.full((kd, capacity), -1, np.int32)
-            ws_out = np.full((kd, capacity), -1, np.int32)
-            hit = m_out >= 0
-            k_idx, row_idx = np.nonzero(hit.T)  # sorted by offset, then row
-            counts = hit.sum(axis=0)
-            slot = np.arange(k_idx.size) - np.concatenate(
-                [[0], np.cumsum(counts)[:-1]])[k_idx]
-            ws_in[k_idx, slot] = m_out[row_idx, k_idx]
-            ws_out[k_idx, slot] = row_idx
-            ws_in_j, ws_out_j = jnp.asarray(ws_in), jnp.asarray(ws_out)
-            wc_j = jnp.asarray(counts.astype(np.int32))
-        maps[ref] = KernelMap(
-            m_out=jnp.asarray(m_out), out_coords=jnp.asarray(oc),
-            n_out=jnp.asarray(int(offs[out_s][-1]), jnp.int32),
-            ws_in=ws_in_j, ws_out=ws_out_j, ws_count=wc_j,
-            bitmask=jnp.asarray(bm), out_stride=out_s,
-            kernel_size=m0["kernel_size"])
-    return maps
+    return offs
+
+
+def compose_kmaps(specs, entries: Sequence[SceneEntry], capacity: int,
+                  place: Optional[Callable] = None,
+                  finish: Optional[Callable] = None,
+                  blank: Optional[dict] = None
+                  ) -> Optional[Dict[tuple, KernelMap]]:
+    """Compose per-scene kernel-map stacks into the batch map stack, on the
+    device: ``compose_place`` for each scene in batch (= packing) order,
+    then ``compose_finish``.  Reads only the entries' device arrays and
+    their host sizes.
+
+    specs: the plan's map specs.  capacity: the batch bucket every composed
+    map is padded to.  place/finish: the two stages as ``(acc, scene, b,
+    offs)`` / ``(acc, totals)`` callables (the serving engine passes them
+    jitted; the default runs them op by op).  blank: ``compose_blank`` of
+    this capacity, already on the device, to reuse.  Returns None when
+    composition does not apply (``compose_offsets``).
+    """
+    offs = compose_offsets(entries, capacity)
+    if offs is None:
+        return None
+    place = place or (lambda *a: compose_place(specs, *a))
+    finish = finish or (lambda *a: compose_finish(specs, *a))
+    acc = compose_blank(entries[0], capacity) if blank is None else blank
+    for b, e in enumerate(entries):
+        acc = place(acc, e.arrays, np.int32(b),
+                    {level_key(s): (np.int32(o[b]), np.int32(o[b + 1] - o[b]))
+                     for s, o in offs.items()})
+    return finish(acc, {level_key(s): np.int32(o[-1])
+                        for s, o in offs.items()})
 
 
 # ---------------------------------------------------------------------------
@@ -1041,8 +1116,30 @@ def make_split_plan(kmap: KernelMap, n_splits: int, sort: bool = True,
                      occupancy=occ, tile_m=tile_m or 0)
 
 
-def _scene_split_keys(entry: SceneEntry, ref: tuple,
-                      ranges: Tuple[Tuple[int, int], ...]) -> list:
+def _scene_hits(specs: Dict[tuple, object], entry: SceneEntry,
+                ref: tuple) -> np.ndarray:
+    """One scene's (rows, KD) hit matrix of map ``ref`` (``specs``: map ref
+    -> spec), fetched from its device arrays: a searched map's own rows, an
+    "up" map's as the transpose of its forward map's pairs, a "slice"
+    map's as its parent's columns."""
+    ms = specs[ref]
+    if ms.kind == "slice":
+        return _scene_hits(specs, entry, ms.slice_of)[:, offset_columns(
+            ms.kernel_size, specs[ms.slice_of].kernel_size, entry.ndim)]
+    if ms.kind == "up":
+        coarse, fine = map_strides(ms)
+        fwd = np.asarray(entry.arrays["m_out"][ms.transpose_of])
+        fwd = fwd[:entry.sizes[coarse]]
+        hit = np.zeros((entry.sizes[fine], fwd.shape[1]), bool)
+        c, k = np.nonzero(fwd >= 0)
+        hit[fwd[c, k], k] = True
+        return hit
+    n_o = entry.sizes[map_strides(ms)[1]]
+    return np.asarray(entry.arrays["m_out"][ref])[:n_o] >= 0
+
+
+def _scene_split_keys(specs: Dict[tuple, object], entry: SceneEntry,
+                      ref: tuple, ranges: Tuple[Tuple[int, int], ...]) -> list:
     """Per-split (sorted bitmask values, local stable order) of one scene's
     cached map — the per-scene half of a composed ``SplitPlan``.  Computed
     once per (ref, ranges) with numpy stable argsorts and cached on the
@@ -1052,22 +1149,12 @@ def _scene_split_keys(entry: SceneEntry, ref: tuple,
     cached = entry.splits.get(ck)
     if cached is not None:
         return cached
-    sm = entry.maps[ref]
-    if "slice_of" in sm:
-        parent = entry.maps[sm["slice_of"]]
-        m_out = parent["m_out"][:, offset_columns(
-            sm["kernel_size"], parent["kernel_size"],
-            parent["out_coords"].shape[1] - 1)]
-        sm = {**parent, "m_out": m_out, "bitmask": _np_bitmask(m_out >= 0)}
-    n_o = entry.sizes[sm["out_stride"]]
-    kd = sm["m_out"].shape[1]
+    hit = _scene_hits(specs, entry, ref)
+    kd = hit.shape[1]
     runs = []
     for a, b in ranges:
-        if kd <= 31:
-            bm = ((sm["bitmask"][:n_o].astype(np.int32) >> np.int32(a))
-                  & np.int32((1 << (b - a)) - 1))
-        else:
-            bm = _np_bitmask(sm["m_out"][:n_o, a:b] >= 0)
+        # the split's bits of the row bitmask (``make_split_plan``)
+        bm = _np_bitmask(hit[:, a:b])
         if kd <= 31 and (b - a) <= 29 and hashing.radix_enabled():
             loc = hashing.np_radix_argsort_bits(bm, b - a)
         else:
@@ -1092,23 +1179,23 @@ def _merge_sorted_runs(vals_a, ord_a, vals_b, ord_b):
     return vals, order
 
 
-def compose_split_plans(entries: Sequence[SceneEntry], ref: tuple,
+def compose_split_plans(specs, entries: Sequence[SceneEntry], ref: tuple,
                         n_splits: int, sort: bool, capacity: int) -> SplitPlan:
     """Merge-compose per-scene sorted split orders into the batch
     ``SplitPlan`` — host-side numpy, no device argsort on the batch path.
 
-    Bit-identical to ``make_split_plan(compose_kmaps(entries, capacity)[ref],
-    n_splits, sort)``: jnp's argsort is stable, so sorting the concatenated
-    per-scene bitmask blocks (pad tail at int32 max) IS the stable k-way
-    merge of the per-scene stable-sorted runs — ties break toward the lower
-    global row, i.e. the earlier scene — followed by the pad rows in slot
-    order.  Callers must pass the same entries/capacity that composed the
-    kernel maps.
+    Bit-identical to ``make_split_plan(compose_kmaps(specs, entries,
+    capacity)[ref], n_splits, sort)``: jnp's argsort is stable, so sorting
+    the concatenated per-scene bitmask blocks (pad tail at int32 max) IS
+    the stable k-way merge of the per-scene stable-sorted runs — ties break
+    toward the lower global row, i.e. the earlier scene — followed by the
+    pad rows in slot order.  Callers must pass the same entries/capacity
+    that composed the kernel maps.
     """
-    m0 = entries[0].maps[ref]
-    kd = (m0["m_out"].shape[1] if "m_out" in m0
-          else math.prod(m0["kernel_size"]))   # a slice's per-axis shape
-    ranges = split_ranges(kd, n_splits)
+    specs = {ms.ref: ms for ms in specs}
+    ms = specs[ref]
+    ranges = split_ranges(
+        math.prod(axis_strides(ms.kernel_size, entries[0].ndim)), n_splits)
     cap = capacity
     if not sort:
         eye = np.ascontiguousarray(np.broadcast_to(
@@ -1116,10 +1203,10 @@ def compose_split_plans(entries: Sequence[SceneEntry], ref: tuple,
         order = jnp.asarray(eye)
         return SplitPlan(order=order, inv_order=order, ranges=ranges,
                          sorted_=False)
-    out_s = m0["out_stride"]
+    out_s = map_strides(ms)[1]
     offs = np.cumsum([0] + [e.sizes[out_s] for e in entries])
     total = int(offs[-1])
-    per_scene = [_scene_split_keys(e, ref, ranges) for e in entries]
+    per_scene = [_scene_split_keys(specs, e, ref, ranges) for e in entries]
     order = np.empty((len(ranges), cap), np.int32)
     for s in range(len(ranges)):
         vals, merged = per_scene[0][s]

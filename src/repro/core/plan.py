@@ -53,12 +53,12 @@ from repro.core import precision as prec
 from repro.core.autotuner import (Autotuner, TrainingAutotuner,
                                   partition_groups)
 from repro.core.hashing import CoordTable
-from repro.core.kmap import (MapCache, SceneEntry, check_capacity,
-                             make_split_plan, prepare_kmap, search_kmaps,
-                             slice_kmap, transpose_kmap)
+from repro.core.kmap import (MapCache, SceneEntry, check_rows, level_key,
+                             make_split_plan, map_strides, prepare_kmap,
+                             search_kmaps, slice_kmap, transpose_kmap)
 from repro.core.precision import FP32, PrecisionPolicy
 from repro.core.sparse_conv import (ConvSpec, TrainDataflowConfig, apply_conv)
-from repro.core.sparse_tensor import SparseTensor, stride_mul, stride_name
+from repro.core.sparse_tensor import SparseTensor, stride_name
 
 #: 3: named-value ops and per-layer activations; version-2 plans (stack
 #: ops, a ``relu`` flag per layer) still load (``NetworkPlan.from_dict``)
@@ -364,61 +364,62 @@ def build_maps_from_specs(specs: Sequence[KmapSpec], st: SparseTensor,
 def scene_entry_arrays(map_specs: Sequence[KmapSpec], st: SparseTensor,
                        root_table: Optional[CoordTable] = None,
                        tables: Optional[dict] = None):
-    """The traceable core of a per-scene mapping build: the kernel-map
-    stack plus the scene's sorted root table arrays.  ``st`` is a
-    single-scene tensor (batch column 0, padding allowed — the serving
-    engine buckets scene capacities so this jits once per rung).
+    """The traceable core of a per-scene mapping build: what a batch
+    composes from (``kmap.compose_kmaps``) plus the scene's sorted root
+    table arrays.  ``st`` is a single-scene tensor (batch column 0, padding
+    allowed — the serving engine buckets scene capacities so this jits
+    once per rung).
 
     root_table: an already-merged ``CoordTable`` for ``st`` (streaming
     delta path) — adopted so the build skips the scene's root argsort.
     tables: optional pre-composed deeper-level tables (the incremental
     cell-ladder path) — see ``build_maps_from_specs``.
 
-    Column-subset ("slice") maps are left out: a composed batch cuts them
-    from its composed parents (``kmap.compose_kmaps``).
+    Returns ``(arrays, root_keys, root_order)``; ``arrays`` holds
+    ``"m_out"`` (ref -> output-stationary map of each "sub"/"down" map),
+    ``"coords"`` (``kmap.level_key`` -> each level's coordinates) and
+    ``"sizes"`` (``level_key`` -> rows of each level below the root).
+    Only the searched maps are built: pair lists, bitmasks, "up" and
+    "slice" maps are derived from the composed batch maps.
     """
     cache = MapCache.for_tensor(st)
     if root_table is not None:
         cache.adopt(st.coords, root_table)
     maps = build_maps_from_specs([ms for ms in map_specs
-                                  if ms.kind != "slice"], st, cache,
+                                  if ms.kind in ("sub", "down")], st, cache,
                                  tables=tables)
     root = cache.table(st)   # cache hit: the table the build sorted/adopted
-    return maps, root.sorted_keys, root.order
+    downs = [maps[ms.ref] for ms in map_specs if ms.kind == "down"]
+    arrays = {"m_out": {ref: km.m_out for ref, km in maps.items()},
+              "coords": {level_key(st.stride): st.coords,
+                         **{level_key(km.out_stride): km.out_coords
+                            for km in downs}},
+              "sizes": {level_key(km.out_stride): km.n_out for km in downs}}
+    return arrays, root.sorted_keys, root.order
 
 
-def scene_entry_from_arrays(map_specs: Sequence[KmapSpec], maps: dict,
+def scene_entry_from_arrays(map_specs: Sequence[KmapSpec], arrays: dict,
                             n: int, root_keys, root_order,
                             root_stride: int = 1) -> SceneEntry:
-    """Extract the host-side ``SceneEntry`` from a (possibly padded) scene
-    build: numpy kernel-map fields, per-level valid row counts, and the
-    root table trimmed to its valid prefix (PAD keys sort last, so the
-    first ``n`` entries ARE the exact-size table delta-merge expects).
-    Raises when a dilated level overflowed its capacity."""
-    check_capacity({ms.ref: maps[ms.ref] for ms in map_specs if ms.dilate})
-    sizes = {root_stride: n}
-    entry_maps: dict = {}
+    """The ``SceneEntry`` of a (possibly padded) scene build: its map and
+    level arrays stay on the device; only the per-level row counts and the
+    root table come to the host (either may be passed in already fetched),
+    the table trimmed to its valid prefix (PAD keys sort last, so the first
+    ``n`` entries ARE the exact-size table delta-merge expects).  Raises
+    when a dilated level overflowed its capacity (the scene's rung)."""
+    sizes, keys, order = jax.device_get((arrays["sizes"], root_keys,
+                                         root_order))
+    cap = arrays["coords"][level_key(root_stride)].shape[0]
+    by_key = {level_key(s): s for s in
+              [root_stride] + [map_strides(ms)[1] for ms in map_specs]}
+    sizes = {root_stride: n, **{by_key[k]: int(v) for k, v in sizes.items()}}
     for ms in map_specs:
-        if ms.kind == "slice":
-            entry_maps[ms.ref] = {"slice_of": ms.slice_of,
-                                  "kernel_size": ms.kernel_size}
-            continue
-        km = maps[ms.ref]
-        if ms.kind == "down":
-            sizes[km.out_stride] = int(km.n_out)
-        entry_maps[ms.ref] = {
-            "m_out": np.asarray(km.m_out),
-            "out_coords": np.asarray(km.out_coords),
-            "ws_in": np.asarray(km.ws_in), "ws_out": np.asarray(km.ws_out),
-            "ws_count": np.asarray(km.ws_count),
-            "bitmask": np.asarray(km.bitmask),
-            "in_stride": (stride_mul(ms.tensor_stride, ms.stride)
-                          if ms.kind == "up" else ms.tensor_stride),
-            "out_stride": km.out_stride, "kernel_size": km.kernel_size,
-            "transpose_of": ms.transpose_of}
-    return SceneEntry(n=n, sizes=sizes, maps=entry_maps,
-                      root_keys=np.asarray(root_keys)[:n],
-                      root_order=np.asarray(root_order)[:n])
+        if ms.dilate:
+            check_rows(ms.ref, sizes[map_strides(ms)[1]], cap)
+    return SceneEntry(n=n, sizes=sizes,
+                      arrays={k: arrays[k] for k in ("m_out", "coords")},
+                      root_keys=np.asarray(keys)[:n],
+                      root_order=np.asarray(order)[:n])
 
 
 def build_scene_entry(map_specs: Sequence[KmapSpec], st: SparseTensor,
@@ -426,8 +427,8 @@ def build_scene_entry(map_specs: Sequence[KmapSpec], st: SparseTensor,
     """Build one scene's cached mapping work for scene-granular composition
     (eager convenience wrapper; the serving engine jits
     ``scene_entry_arrays`` per scene-capacity rung instead)."""
-    maps, keys, order = scene_entry_arrays(map_specs, st, root_table)
-    return scene_entry_from_arrays(map_specs, maps, int(st.num_valid),
+    arrays, keys, order = scene_entry_arrays(map_specs, st, root_table)
+    return scene_entry_from_arrays(map_specs, arrays, int(st.num_valid),
                                    keys, order, root_stride=st.stride)
 
 

@@ -15,9 +15,9 @@ Ties the subsystem together (DESIGN: ISSUE 2 tentpole):
   (a small LRU maps digest → device-resident map stack, so exact replays
   skip mapping entirely), and — under the plan's ``"composed"`` /
   ``"incremental"`` table strategies — *scenes* are keyed individually: a
-  per-scene store caches each scene's kernel-map stack and sorted table
-  ladder, and batch maps are **merge-composed** from the cached per-scene
-  stacks (host-side concatenation with index offsets; bit-identical to a
+  per-scene store caches each scene's kernel-map stack (on the device)
+  and sorted table ladder, and batch maps are **composed** from the cached
+  per-scene stacks (on the device, with index offsets; bit-identical to a
   fresh build because batch bits keep scenes disjoint).  Under churning
   batch composition — the common case in real traffic — only cold scenes
   ever build maps, at their own size (Minuet §4 proper).  ``"incremental"``
@@ -71,7 +71,8 @@ from repro.core import dataflows as df
 from repro.core import hashing
 from repro.core.autotuner import timeit_fn
 from repro.core.kmap import (SceneEntry, cell_ladder, cell_ladder_delta,
-                             check_capacity, compose_kmaps,
+                             check_capacity, compose_blank, compose_finish,
+                             compose_kmaps, compose_place,
                              compose_split_plans, ladder_tables)
 from repro.core.plan import (KmapSpec, NetworkPlan, PlanTuner,
                              scene_entry_arrays, scene_entry_from_arrays)
@@ -186,6 +187,14 @@ def summarize_phases(windows: Dict[str, Sequence[float]]) -> Dict[str, dict]:
     return out
 
 
+def scene_stage_compiles(stats: "EngineStats") -> Dict:
+    """The scene-granular stages' compiles: the scene and delta builders'
+    per scene rung, and the map composer's as ``compose@<bucket>``."""
+    return {**stats.scene_compiles,
+            **{f"compose@{cap}": n
+               for cap, n in stats.compose_compiles.items()}}
+
+
 @dataclasses.dataclass
 class EngineStats:
     submitted: int = 0
@@ -205,7 +214,10 @@ class EngineStats:
     scene_compiles: Dict[int, int] = dataclasses.field(default_factory=dict)
     scene_hits: int = 0          # batch slots served from the scene store
     scene_misses: int = 0        # cold scenes that built their own stack
-    composed_batches: int = 0    # batch map stacks merge-composed, not built
+    composed_batches: int = 0    # batch map stacks composed, not built
+    # the device map composer's compiles, per bucket (its placing stage
+    # once per scene rung met there, its finishing stage once)
+    compose_compiles: Dict[int, int] = dataclasses.field(default_factory=dict)
     delta_merges: int = 0        # streaming frames that delta-merged a table
     # scene builds (cold or delta): valid rows, and the scene-rung rows the
     # build's neighbour search runs over
@@ -215,6 +227,8 @@ class EngineStats:
     # capacity those levels were padded to
     level_rows: int = 0
     level_cap_rows: int = 0
+    # bytes the same builds copied device→host (level sizes, root table)
+    fetch_bytes: int = 0
     # flush triggers beyond the explicit flush() call
     deadline_flushes: int = 0    # max_wait_ms expiries
     count_flushes: int = 0       # flush_count threshold crossings
@@ -265,7 +279,8 @@ class EngineStats:
                              "delta_merges": self.delta_merges,
                              "rows": self.scene_rows,
                              "rung_rows": self.scene_rung_rows,
-                             "compiles": dict(self.scene_compiles)},
+                             "fetch_bytes": self.fetch_bytes,
+                             "compiles": scene_stage_compiles(self)},
             "level_tables": {"rows": self.level_rows,
                              "cap_rows": self.level_cap_rows},
             "deadline_flushes": self.deadline_flushes,
@@ -297,12 +312,13 @@ class Engine:
         automatic flushes (None disables each); auto-flushed results are
         returned by the next ``flush()``/``poll()``.
     scene_cache_size: LRU bound of the per-scene store.  Entries are
-        host-resident numpy map stacks (~ refs x KD x scene-rung int32
-        words each), so size this by host RAM, not device memory.
+        device-resident map stacks (~ searched maps x KD x scene-rung int32
+        words each), so size this by device memory (HBM), next to the
+        executor's working set.
     scene_cache_bytes: optional byte bound on the same store — eviction by
-        the actual ``SceneEntry.nbytes`` footprint (split-order and ladder
-        caches included), which tracks residency far better than an entry
-        count when scene sizes span rungs.  Both bounds apply when set.
+        the device bytes an entry pins (``SceneEntry.nbytes``), which
+        tracks HBM residency far better than an entry count when scene
+        sizes span rungs.  Both bounds apply when set.
     max_inflight: dispatched-but-undrained batch window of a pipelined
         flush.  1 restores the strictly serial dispatch→block loop; the
         default 2 double-buffers host mapping/packing against device
@@ -394,10 +410,11 @@ class Engine:
         self._next_ticket = 0
         self._ready: Dict[int, SceneResult] = {}   # auto-flushed results
         self._map_store: "collections.OrderedDict[str, dict]" = collections.OrderedDict()
-        # The scene store is device-agnostic (host numpy), so a DeviceRouter
-        # shares ONE store — and its lock — across all its workers; the lock
-        # only guards dict mutation, never a build (concurrent builds of the
-        # same digest are idempotent: entries are bit-identical).
+        # A DeviceRouter shares ONE scene store — and its lock — across all
+        # its workers: an entry lives on the device that built it, and a
+        # worker on another device copies it over to compose.  The lock only
+        # guards dict mutation, never a build (concurrent builds of the same
+        # digest are idempotent: entries are bit-identical).
         self._scene_lock = threading.Lock()
         self._scene_store: "collections.OrderedDict[str, SceneEntry]" = collections.OrderedDict()
         # stream id -> last scene, LRU-bounded: serve-forever processes see
@@ -409,6 +426,12 @@ class Engine:
         self._plan_builders: Dict[int, Callable] = {}
         self._scene_builders: Dict[int, Callable] = {}
         self._scene_delta_builders: Dict[int, Callable] = {}
+        #: the map composer's stages: (scene rung, bucket) -> placing
+        #: stage, bucket -> finishing stage, and bucket -> the empty batch
+        #: map stack the first scene is placed into
+        self._placers: Dict[tuple, Callable] = {}
+        self._composers: Dict[int, Callable] = {}
+        self._compose_blanks: Dict[int, dict] = {}
         #: floor-grid down-map out-strides, ascending — the cell ladder's
         #: levels (a dilated level is no floor grid: it builds afresh)
         self._down_strides = tuple(sorted(
@@ -449,17 +472,19 @@ class Engine:
         The phases, nested as they run:
 
         * ``pack`` (``batch_pack`` inside), then ``map``: the batch's maps,
-          from ``compose_kmaps`` (per-scene ``scene_build`` of cold scenes,
-          then host composition) and ``compose_plans``, or from a jitted
-          ``map_build`` and ``plan_build``; then ``dispatch`` of the
-          executor, which returns without waiting;
+          from ``compose_kmaps`` (every cold scene's build dispatched, then
+          per cold scene ``scene_build``, then the device composer's
+          dispatch) and ``compose_plans``, or from a jitted ``map_build``
+          and ``plan_build``; then ``dispatch`` of the executor, which
+          returns without waiting;
         * ``apply_delta`` (at ``submit_delta``): a streamed frame's new
           scene made from its delta, on the host;
         * ``scene_build`` / ``delta_merge`` (at ``submit_delta``) each hold
           ``scene_wait``, the host blocked on the scene builder's outputs:
           the device's map build plus any device work queued ahead of it
-          (an in-flight executor), and ``scene_fetch``, the device→host
-          copies of the scene's maps into a ``SceneEntry``;
+          (an in-flight executor, the batch's earlier builds), and
+          ``scene_fetch``, the device→host copies of the scene's level
+          sizes and root table into a ``SceneEntry``;
         * ``drain_wait``: the host blocked on a dispatched executor's
           outputs, then ``unpack``.
 
@@ -471,18 +496,20 @@ class Engine:
         self.stats.observe(name, (time.perf_counter() - t0) * 1e3)
 
     def _jit_counting(self, fn, kind: str, counter_attr: str,
-                      cap: int) -> Callable:
+                      cap: int, rung=None) -> Callable:
         """jit ``fn`` with the trace-time side effect that counts *actual*
         recompiles (not calls) into ``stats.<counter_attr>[cap]``, plus a
         structured ``compile`` trace event carrying (kind, rung, device,
-        wall time).  The side effect fires mid-trace, where the compile's
-        duration is unknowable, so it queues a mark; the wrapper drains
-        marks after the triggering call returns and stamps the event with
-        that call's wall time (trace + compile + first execution)."""
+        wall time); ``rung`` (default ``cap``) names the compile in the
+        event and in ``compiled_text``.  The side effect fires mid-trace,
+        where the compile's duration is unknowable, so it queues a mark;
+        the wrapper drains marks after the triggering call returns and
+        stamps the event with that call's wall time (trace + compile +
+        first execution)."""
         def traced(*args):
             counters = getattr(self.stats, counter_attr)
             counters[cap] = counters.get(cap, 0) + 1
-            self._compile_marks.append((kind, cap))
+            self._compile_marks.append((kind, cap if rung is None else rung))
             return fn(*args)
 
         # the XLA module (and the profiler's module events) read jit_<kind>
@@ -501,16 +528,17 @@ class Engine:
                     obs.event("compile", kind=k, rung=c,
                               device=self.device_name,
                               wall_ms=round(wall_ms, 3))
-                self._compiled[(kind, cap)] = (jfn, jax.tree.map(_spec_of,
-                                                                 args))
+                self._compiled[marks[-1]] = (jfn, jax.tree.map(_spec_of,
+                                                               args))
             return out
 
         return wrapper
 
-    def compiled_text(self, kind: str, cap: int) -> str:
+    def compiled_text(self, kind: str, cap) -> str:
         """Optimized HLO of stage ``kind`` ("executor", "map_builder",
-        "plan_builder", "scene_builder", …) as last compiled for rung
-        ``cap`` — e.g. to check that a Pallas plan really lowered to
+        "plan_builder", "scene_builder", "map_composer", …) as last
+        compiled for rung ``cap`` (a (scene rung, bucket) pair for the
+        "map_placer") — e.g. to check that a Pallas plan really lowered to
         ``tpu_custom_call`` kernels.  Hits jax's caches: no retrace, and no
         compile counter moves."""
         jfn, specs = self._compiled[(kind, cap)]
@@ -624,42 +652,111 @@ class Engine:
                 self._scene_store.popitem(last=False)
 
     def _fetch_scene_entry(self, built, n: int, cap: int) -> SceneEntry:
-        """Wait for a scene builder's outputs (``scene_wait``), copy them
-        into a host ``SceneEntry`` (``scene_fetch``; raises when a dilated
-        level overflowed), and count the build's valid and scene-rung rows,
-        at the root and at the levels below it."""
+        """Wait for a scene builder's outputs (``scene_wait``), fetch its
+        level sizes and root table into a ``SceneEntry`` whose maps stay on
+        the device (``scene_fetch``; raises when a dilated level
+        overflowed), and count the build's valid and scene-rung rows, at
+        the root and at the levels below it, and the bytes fetched."""
         with self._phase("scene_wait", cap=cap):
-            maps, keys, order = jax.block_until_ready(built)
+            arrays, keys, order = jax.block_until_ready(built)
         with self._phase("scene_fetch", cap=cap):
-            ent = scene_entry_from_arrays(self.nplan.map_specs, maps, n,
-                                          keys, order)
+            host = jax.device_get((arrays["sizes"], keys, order))
+            self.stats.fetch_bytes += sum(np.asarray(x).nbytes
+                                          for x in jax.tree.leaves(host))
+            ent = scene_entry_from_arrays(self.nplan.map_specs,
+                                          {**arrays, "sizes": host[0]}, n,
+                                          host[1], host[2])
         self.stats.scene_rows += n
         self.stats.scene_rung_rows += cap
         self.stats.level_rows += sum(ent.sizes.values()) - n
         self.stats.level_cap_rows += cap * (len(ent.sizes) - 1)
         return ent
 
-    def _scene_entry(self, scene: Scene) -> SceneEntry:
-        with self._scene_lock:
-            ent = self._scene_store.get(scene.digest)
-            if ent is not None:
+    def _scene_entries(self, scenes: Sequence[Scene]) -> List[SceneEntry]:
+        """The scene-store entries of ``scenes``, building the cold ones: every
+        cold scene's build is dispatched before the host waits on any, so
+        the device runs them back to back; then each is fetched
+        (``scene_build``) and stored."""
+        found: Dict[str, SceneEntry] = {}
+        cold: Dict[str, tuple] = {}
+        for scene in scenes:
+            with self._scene_lock:
+                ent = self._scene_store.get(scene.digest)
+                if ent is not None:
+                    self._scene_store.move_to_end(scene.digest)
+                    found[scene.digest] = ent
+            if ent is not None or scene.digest in cold:
                 self.stats.scene_hits += 1
-                self._scene_store.move_to_end(scene.digest)
-                return ent
-        self.stats.scene_misses += 1
-        cap = self._scene_ladder.select(scene.num_points)
-        with self._phase("scene_build", cap=cap, points=scene.num_points):
-            ent = self._fetch_scene_entry(
-                self._scene_builder_for(cap)(self._scene_tensor(scene, cap)),
-                scene.num_points, cap)
-            if self.map_strategy == "incremental":
-                # seed the stream's cell ladder so later deltas propagate
-                # down the pyramid incrementally instead of re-deriving it
-                ent.ladder = cell_ladder(
-                    self._key_spec(scene.coords.shape[1]), ent.root_keys,
-                    self._down_strides)
-        self._store_scene(scene.digest, ent)
-        return ent
+                continue
+            self.stats.scene_misses += 1
+            cap = self._scene_ladder.select(scene.num_points)
+            cold[scene.digest] = (scene, cap, self._scene_builder_for(cap)(
+                self._scene_tensor(scene, cap)))
+        for digest, (scene, cap, built) in cold.items():
+            with self._phase("scene_build", cap=cap, points=scene.num_points):
+                ent = self._fetch_scene_entry(built, scene.num_points, cap)
+                if self.map_strategy == "incremental":
+                    # seed the stream's cell ladder so later deltas propagate
+                    # down the pyramid incrementally instead of re-deriving it
+                    ent.ladder = cell_ladder(
+                        self._key_spec(scene.coords.shape[1]), ent.root_keys,
+                        self._down_strides)
+            self._store_scene(digest, ent)
+            found[digest] = ent
+        return [found[s.digest] for s in scenes]
+
+    def _scene_entry(self, scene: Scene) -> SceneEntry:
+        return self._scene_entries([scene])[0]
+
+    def _placer_for(self, rung: int, bucket: int) -> Callable:
+        """The map composer's placing stage for scenes of ``rung`` in
+        ``bucket``, counted in ``stats.compose_compiles[bucket]``."""
+        fn = self._placers.get((rung, bucket))
+        if fn is None:
+            specs = self.nplan.map_specs
+            fn = self._jit_counting(
+                lambda acc, sc, b, offs: compose_place(specs, acc, sc, b, offs),
+                "map_placer", "compose_compiles", bucket, (rung, bucket))
+            self._placers[(rung, bucket)] = fn
+        return fn
+
+    def _composer_for(self, bucket: int) -> Callable:
+        """The map composer's finishing stage for ``bucket``, counted in
+        ``stats.compose_compiles[bucket]``."""
+        fn = self._composers.get(bucket)
+        if fn is None:
+            specs = self.nplan.map_specs
+            fn = self._jit_counting(
+                lambda acc, totals: compose_finish(specs, acc, totals),
+                "map_composer", "compose_compiles", bucket)
+            self._composers[bucket] = fn
+        return fn
+
+    def _compose_blank(self, entry: SceneEntry, bucket: int) -> dict:
+        """The empty batch map stack of ``bucket``, on this engine's device,
+        made once."""
+        blank = self._compose_blanks.get(bucket)
+        if blank is None:
+            blank = self._compose_blanks[bucket] = jax.device_put(
+                compose_blank(entry, bucket), self.device)
+        return blank
+
+    def _compose(self, entries: Sequence[SceneEntry], bucket: int):
+        """``compose_kmaps`` on this engine's device, through its jitted
+        stages (None when composition does not apply)."""
+        if self.device is not None:
+            # a DeviceRouter's shared store holds other devices' entries
+            entries = [dataclasses.replace(
+                e, arrays=jax.device_put(e.arrays, self.device))
+                for e in entries]
+
+        def place(acc, arrays, b, offs):
+            rung = next(iter(arrays["coords"].values())).shape[0]
+            return self._placer_for(rung, bucket)(acc, arrays, b, offs)
+
+        return compose_kmaps(self.nplan.map_specs, entries, bucket,
+                             place=place, finish=self._composer_for(bucket),
+                             blank=self._compose_blank(entries[0], bucket))
 
     def _maps_for(self, batch: PackedBatch,
                   scenes: Optional[Sequence[Scene]] = None) -> Tuple[dict, dict]:
@@ -682,15 +779,16 @@ class Engine:
             # includes nested scene_build spans for any cold scenes
             with self._phase("compose_kmaps", bucket=batch.bucket,
                              scenes=len(scenes)):
-                entries = [self._scene_entry(s) for s in scenes]
-                maps = compose_kmaps(entries, batch.bucket)
+                entries = self._scene_entries(scenes)
+                maps = self._compose(entries, batch.bucket)
             if maps is not None:
                 self.stats.composed_batches += 1
                 if pspecs:
                     with self._phase("compose_plans", bucket=batch.bucket):
                         for ref, ns, srt in pspecs:
                             plans[(ref, ns, srt)] = compose_split_plans(
-                                entries, ref, ns, srt, batch.bucket)
+                                self.nplan.map_specs, entries, ref, ns, srt,
+                                batch.bucket)
         if maps is None:
             with self._phase("map_build", bucket=batch.bucket):
                 maps = self._builder_for(batch.bucket)(batch.st)
@@ -1024,25 +1122,38 @@ class Engine:
         """Compile every bucket once on synthetic single-scene batches so the
         request stream never pays a trace.  Under the composed/incremental
         strategies this also traces the per-scene builders for every rung of
-        the scene-capacity ladder (and the delta builders, for streaming)."""
+        the scene-capacity ladder (and the delta builders, for streaming),
+        and the map composer's placing stage for every (rung, bucket) pair
+        a batch can hold, so batches mixing scene rungs compile nothing."""
         c = channels or self.binding.in_channels_of(self.cfg)
         if self.map_strategy in ("composed", "incremental"):
+            specs = self.nplan.map_specs
+            prev = 0
             for cap in self._scene_ladder.capacities:
                 coords = _box_coords(cap)
                 st = self._scene_tensor(
                     Scene(coords=coords,
                           feats=np.zeros((coords.shape[0], c), np.float32)),
                     cap)
-                maps, keys, order = jax.block_until_ready(
+                arrays, keys, order = jax.block_until_ready(
                     self._scene_builder_for(cap)(st))
+                # the composer's placing stage, in every bucket a scene of
+                # this rung (more than ``prev`` rows) can be packed into
+                ent = scene_entry_from_arrays(specs, arrays, cap, keys, order)
+                offs = {k: (np.int32(0), np.int32(0))
+                        for k in ent.arrays["coords"]}
+                for bucket in self.ladder.capacities:
+                    if prev < bucket:
+                        jax.block_until_ready(self._placer_for(cap, bucket)(
+                            self._compose_blank(ent, bucket), ent.arrays,
+                            np.int32(0), offs))
+                prev = cap
                 if self.map_strategy == "incremental":
                     # the fresh table doubles as a valid adopted-table input;
                     # derive its cell ladder so the traced pytree structure
                     # matches live delta-merge calls exactly
-                    m = coords.shape[0]
                     spec = self._key_spec(coords.shape[1])
-                    lad = cell_ladder(spec, np.asarray(keys)[:m],
-                                      self._down_strides)
+                    lad = cell_ladder(spec, ent.root_keys, self._down_strides)
                     tabs = ladder_tables(spec, lad, cap)
                     jax.block_until_ready(
                         self._scene_delta_builder_for(cap)(

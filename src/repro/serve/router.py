@@ -18,9 +18,10 @@ routes flushed batches between them:
 * workers run their assigned batches **concurrently** (one thread per
   worker — XLA execution releases the GIL, so one worker's host-side
   packing/unpacking overlaps another's device compute);
-* the host-side **scene store is shared** across workers (``SceneEntry``
-  composition is device-agnostic numpy): a scene warmed by any device
-  composes into batches on every device;
+* the **scene store is shared** across workers: an entry stays on the
+  device whose worker built it, and a worker on another device copies it
+  over to compose, so a scene warmed by any device composes into batches
+  on every device;
 * each worker resolves its own ``NetworkPlan`` through the
   ``PlanRegistry`` (``arch@devI`` entries when per-device plans were tuned,
   the shared ``arch`` entry otherwise — schema-v2 compatible either way).
@@ -52,7 +53,8 @@ from repro.serve.batcher import Scene, SceneBatcher, SceneDelta, SceneResult
 from repro.serve.bucketing import BucketLadder
 from repro.serve.engine import (DEFAULT_LADDER, DEFAULT_SPATIAL_BOUND, ARCHS,
                                 Engine, EngineStats, PHASE_WINDOW,
-                                percentiles_ms, summarize_phases)
+                                percentiles_ms, scene_stage_compiles,
+                                summarize_phases)
 from repro.serve.plans import PlanRegistry, device_key
 from repro.serve.service import (STATS_SCHEMA_VERSION, ServiceConfig,
                                  resolve_config)
@@ -126,7 +128,9 @@ class RouterStats:
             "delta_merges": sum(s.delta_merges for s in stats),
             "rows": sum(s.scene_rows for s in stats),
             "rung_rows": sum(s.scene_rung_rows for s in stats),
-            "compiles": self._merge_counter("scene_compiles"),
+            "fetch_bytes": sum(s.fetch_bytes for s in stats),
+            "compiles": {f"d{i}:{k}": n for i, w in enumerate(workers)
+                         for k, n in scene_stage_compiles(w.stats).items()},
         }
         devices = {}
         for i, w in enumerate(workers):
@@ -243,8 +247,8 @@ class DeviceRouter:
                    model_config=cfg, params=params, plans=self.plans,
                    precision=precision, device=dev)
             for i, dev in enumerate(self.devices)]
-        # one host-side scene store (and guard) for the whole tier: entries
-        # are device-agnostic numpy, so any worker's build serves every device
+        # one scene store (and guard) for the whole tier: any worker's build
+        # serves every device (composition copies it to the composing one)
         for w in self.workers[1:]:
             w._scene_store = self.workers[0]._scene_store
             w._scene_lock = self.workers[0]._scene_lock

@@ -137,9 +137,9 @@ def test_an_overflowing_level_raises():
     # the same scene's build for serving raises too
     specs = cylinder3d.map_specs()
     st = _tensor(rows[:, :], 64)
-    maps, keys, order = planlib.scene_entry_arrays(specs, st)
+    arrays, keys, order = planlib.scene_entry_arrays(specs, st)
     with pytest.raises(ValueError, match="capacity"):
-        planlib.scene_entry_from_arrays(specs, maps, len(rows), keys, order)
+        planlib.scene_entry_from_arrays(specs, arrays, len(rows), keys, order)
 
 
 def _slices_counted() -> int:
@@ -154,36 +154,49 @@ def _dense_scene(rng, n, side=7):
     return np.array(sorted(pts), np.int32)
 
 
-def test_composed_maps_equal_a_fresh_batch_build():
-    """Per-scene map stacks composed into a batch (dilated levels, their
-    transposes and the column subsets) equal the batch's own build."""
+@pytest.mark.parametrize("sizes,rungs,bucket", [
+    ((300, 420), (512, 512), 1024),
+    ((420, 300), (1024, 512), 1024),     # two scene rungs in one bucket
+    ((300,), (512,), 512),               # a single-scene batch
+])
+def test_composed_maps_equal_a_fresh_batch_build(sizes, rungs, bucket):
+    """Per-scene map stacks composed on the device into a batch (dilated
+    levels, their transposes and the column subsets), op by op and jitted
+    as the engine runs it, equal the batch's own build."""
     rng = np.random.default_rng(11)
     specs = cylinder3d.map_specs("composed")
-    scenes_ = [_dense_scene(rng, n) for n in (300, 420)]
+    scenes_ = [_dense_scene(rng, n) for n in sizes]
     entries = []
-    for sc in scenes_:
+    for sc, rung in zip(scenes_, rungs):
         rows = np.concatenate([np.zeros((len(sc), 1), np.int32), sc], 1)
-        maps, keys, order = planlib.scene_entry_arrays(specs,
-                                                       _tensor(rows, 512))
-        entries.append(planlib.scene_entry_from_arrays(specs, maps, len(sc),
-                                                       keys, order))
+        arrays, keys, order = planlib.scene_entry_arrays(specs,
+                                                         _tensor(rows, rung))
+        entries.append(planlib.scene_entry_from_arrays(specs, arrays,
+                                                       len(sc), keys, order))
     batch = np.concatenate([np.concatenate(
         [np.full((len(sc), 1), b, np.int32), sc], 1)
         for b, sc in enumerate(scenes_)])
     before = _slices_counted()
-    fresh = planlib.build_maps_from_specs(specs, _tensor(batch, 1024))
+    fresh = planlib.build_maps_from_specs(specs, _tensor(batch, bucket))
     # every column-subset map counted once, as the batch build cuts it
     assert _slices_counted() - before == 11 == sum(
         ms.kind == "slice" for ms in specs)
-    composed = km.compose_kmaps(entries, 1024)
-    assert set(composed) == set(fresh)
-    for ref in fresh:
-        for f in ("m_out", "out_coords", "n_out", "ws_in", "ws_out",
-                  "ws_count", "bitmask"):
-            np.testing.assert_array_equal(getattr(composed[ref], f),
-                                          getattr(fresh[ref], f), (ref, f))
-        assert composed[ref].out_stride == fresh[ref].out_stride
-        assert composed[ref].kernel_size == fresh[ref].kernel_size
+    jitted = dict(place=jax.jit(lambda *a: km.compose_place(specs, *a)),
+                  finish=jax.jit(lambda *a: km.compose_finish(specs, *a)))
+    for composed in (km.compose_kmaps(specs, entries, bucket),
+                     km.compose_kmaps(specs, entries, bucket, **jitted)):
+        assert set(composed) == set(fresh)
+        for ref in fresh:
+            for f in ("m_out", "out_coords", "n_out", "ws_in", "ws_out",
+                      "ws_count", "bitmask"):
+                np.testing.assert_array_equal(getattr(composed[ref], f),
+                                              getattr(fresh[ref], f),
+                                              (ref, f))
+            assert composed[ref].out_stride == fresh[ref].out_stride
+            assert composed[ref].kernel_size == fresh[ref].kernel_size
+    # a dilated level larger than the bucket declines composition
+    assert km.compose_kmaps(specs, entries,
+                            max(e.sizes[2] for e in entries) - 1) is None
 
 
 def test_plan_with_tuple_kernels_and_named_ops_round_trips():

@@ -490,6 +490,12 @@ def test_stage_modules_and_scopes_are_named(traced_streaming):
         assert scope in text, scope
     assert eng.compiled_text("scene_delta_builder", cap).startswith(
         "HloModule jit_scene_delta_builder")
+    # the device map composer: its finishing stage per bucket, its placing
+    # stage per (scene rung, bucket), both under the kmap.compose scope
+    for kind, key in (("map_composer", 512), ("map_placer", (cap, 512))):
+        text = eng.compiled_text(kind, key)
+        assert text.startswith(f"HloModule jit_{kind}")
+        assert "kmap.compose" in text, kind
     text = eng.compiled_text("executor", 512)
     assert text.startswith("HloModule jit_executor")
     assert "layer." in text

@@ -225,9 +225,11 @@ def test_batched_engine_bit_identical_to_per_scene(arch, channels):
 def test_engine_recompile_bound_and_map_reuse():
     """≤1 jit compile per bucket per stage after warmup, and replayed
     batches skip map construction via the content-keyed cross-request
-    cache.  Under the default "composed" strategy batch maps are
-    merge-composed on the host, so the map-builder stage is never traced
-    at all; the "sort" strategy keeps the PR-2 one-trace-per-bucket bound."""
+    cache.  Under the default "composed" strategy batch maps are composed
+    on the device from per-scene stacks, so the map-builder stage is never
+    traced at all, and warmup compiles the composer for every scene rung a
+    bucket can hold — batches mixing rungs trace nothing new; the "sort"
+    strategy keeps the PR-2 one-trace-per-bucket bound."""
     eng = Engine("centerpoint_waymo",
                  ladder=BucketLadder((256, 512), max_batch=3), spatial_bound=64)
     assert eng.map_strategy == "composed"    # the plan-declared default
@@ -236,14 +238,24 @@ def test_engine_recompile_bound_and_map_reuse():
     assert warm_exec == {256: 1, 512: 1}     # one executor trace per bucket
     assert eng.stats.map_compiles == {}      # composed: no builder traces
     assert eng.stats.composed_batches == 2   # one composed batch per bucket
+    warm_compose = dict(eng.stats.compose_compiles)
+    warm_scene = dict(eng.stats.scene_compiles)
+    # per bucket: the finishing stage, and a placing stage per scene rung
+    # (64..512) whose smallest scene fits the bucket
+    assert warm_compose == {256: 1 + 3, 512: 1 + 4}
 
+    # each flush is one batch pairing a rung-64 scene with a rung-256 one
     scenes = [_mk_scene(n, 5, seed=100 + n) for n in (60, 150, 40, 220)]
+    assert [eng._scene_ladder.select(s.num_points) for s in scenes] == \
+        [64, 256, 64, 256]
     eng.serve(scenes, flush_every=2)
     hits0 = eng.stats.map_hits
     eng.serve(scenes, flush_every=2)         # replay: identical batches
     # no new traces in steady state — the ≤1-per-bucket guarantee
     assert eng.stats.recompiles == warm_exec
     assert eng.stats.map_compiles == {}
+    assert eng.stats.compose_compiles == warm_compose
+    assert eng.stats.scene_compiles == warm_scene
     # replayed epoch's batches all hit the whole-batch map cache
     assert eng.stats.map_hits >= hits0 + 2
     s = eng.stats.summary()
@@ -256,6 +268,48 @@ def test_engine_recompile_bound_and_map_reuse():
     eng2.warmup()
     assert eng2.stats.map_compiles == {256: 1, 512: 1}
     assert eng2.stats.composed_batches == 0 and eng2.stats.scene_misses == 0
+
+
+def test_cold_composed_batches_fetch_only_sizes_and_root_table():
+    """Every batch of fresh scenes composes on the device; a cold scene's
+    build sends the host only its level sizes and its root table (keys and
+    order, one int32 word each a rung row), never its maps."""
+    eng = Engine("centerpoint_waymo",
+                 ladder=BucketLadder((256, 512), max_batch=2),
+                 spatial_bound=64)
+    scenes = [_mk_scene(n, 5, seed=300 + n) for n in (60, 150, 40, 220, 90)]
+    eng.serve(scenes, flush_every=2)
+    st = eng.stats
+    assert st.composed_batches == st.batches == st.map_misses == 3
+    assert st.scene_misses == len(scenes)
+    levels = sum(ms.kind == "down" for ms in eng.nplan.map_specs)
+    rungs = [eng._scene_ladder.select(s.num_points) for s in scenes]
+    assert st.fetch_bytes == sum(4 * (2 * r + levels) for r in rungs)
+    assert st.summary()["scene_tables"]["fetch_bytes"] == st.fetch_bytes
+    # the maps themselves stayed on the device
+    for ent in eng._scene_store.values():
+        assert all(isinstance(a, jax.Array)
+                   for a in jax.tree.leaves(ent.arrays))
+        assert st.fetch_bytes < ent.nbytes
+
+
+def test_scene_store_byte_bound_evicts_by_device_bytes():
+    """``scene_cache_bytes`` bounds the device bytes the scene store pins:
+    entries are evicted oldest first until the rest fit."""
+    scenes = [_mk_scene(100, 5, seed=400 + i) for i in range(5)]
+    probe = Engine("centerpoint_waymo",
+                   ladder=BucketLadder((256,), max_batch=1), spatial_bound=64)
+    size = probe._scene_entry(scenes[0]).nbytes
+    assert size == sum(a.nbytes for a in jax.tree.leaves(
+        probe._scene_store[scenes[0].digest].arrays))
+    eng = Engine("centerpoint_waymo",
+                 ladder=BucketLadder((256,), max_batch=1), spatial_bound=64,
+                 scene_cache_bytes=int(2.5 * size))
+    eng.serve(scenes, flush_every=1)
+    store = eng._scene_store
+    assert list(store) == [s.digest for s in scenes[-2:]]
+    assert sum(e.nbytes for e in store.values()) <= eng.scene_cache_bytes
+    assert all(e.nbytes == size for e in store.values())
 
 
 def test_engine_rejects_oversize_scene():

@@ -4,9 +4,10 @@ The contract under test (ISSUE 4): composed-batch tables
 (``hashing.compose_tables``), delta-merged tables
 (``CoordTable.delta_merge``) and every kernel map built from them — through
 ``build_maps_from_specs(tables=...)`` pre-adoption and through
-``kmap.compose_kmaps`` scene-stack concatenation — are **bit-identical** to
-fresh full builds, across negative coords, multi-batch packing, strided
-table adoption and transposed (up) edges, for all three key-spec modes.
+``kmap.compose_kmaps`` device composition of scene stacks — are
+**bit-identical** to fresh full builds, across negative coords,
+multi-batch packing, strided table adoption and transposed (up) edges, for
+all three key-spec modes.
 Plus the serving-engine integration: scene-granular hits where the PR-2
 whole-batch digest scores misses, streaming delta submits, and the
 deadline-/count-triggered flush satellites.
@@ -228,36 +229,101 @@ def test_composed_tables_build_identical_maps(spec_kind, with_up):
         assert_kmaps_equal(fresh[ref], composed[ref], ctx=str(ref))
 
 
+def _scene_entries(specs, scenes, caps):
+    """One scene entry per batch-0 coordinate block, each built at its own
+    scene capacity (rung)."""
+    entries = []
+    for c, cap in zip(scenes, caps):
+        rows = np.full((cap, 4), int(INVALID_COORD), np.int32)
+        rows[:len(c)] = c
+        st = SparseTensor(coords=jnp.asarray(rows),
+                          feats=jnp.zeros((cap, 1)),
+                          num_valid=jnp.asarray(len(c), jnp.int32), stride=1,
+                          batch_bound=4, spatial_bound=64)
+        entries.append(planlib.build_scene_entry(specs, st))
+    return entries
+
+
+def _assert_composes_to_fresh(specs, scenes, caps, cap):
+    """The device composer — op by op, and jitted as the engine runs it —
+    gives exactly the fresh batch build's maps."""
+    batch, bst, total = _pack_batch(scenes, cap)
+    fresh = planlib.build_maps_from_specs(specs, bst)
+    entries = _scene_entries(specs, scenes, caps)
+    jitted = dict(place=jax.jit(lambda *a: km.compose_place(specs, *a)),
+                  finish=jax.jit(lambda *a: km.compose_finish(specs, *a)))
+    for composed in (km.compose_kmaps(specs, entries, cap),
+                     km.compose_kmaps(specs, entries, cap, **jitted)):
+        assert composed is not None and set(composed) == set(fresh)
+        for ref in fresh:
+            assert_kmaps_equal(fresh[ref], composed[ref], ctx=str(ref))
+            assert composed[ref].out_stride == fresh[ref].out_stride
+            assert composed[ref].kernel_size == fresh[ref].kernel_size
+    return entries
+
+
 @pytest.mark.parametrize("with_up", [False, True])
 def test_composed_scene_kmap_stacks_bit_identical(with_up):
-    """compose_kmaps: per-scene cached kernel-map stacks concatenate into
-    the exact batch map stack (Minuet §4 proper) — m_out/ws/bitmask and the
-    up-map transpose edges included.  Scene rows are shuffled: client scenes
-    arrive in arbitrary row order, and the up-map pair lists follow the
-    forward map's coarse-row order (transpose_kmap), not fine-row order —
-    a regression the sorted rows np.unique produces would mask."""
+    """compose_kmaps: per-scene cached kernel-map stacks compose on the
+    device into the exact batch map stack (Minuet §4 proper) — m_out/ws/
+    bitmask and the up-map transpose edges included.  Scene rows are
+    shuffled: client scenes arrive in arbitrary row order, and the up-map
+    pair lists follow the forward map's coarse-row order (transpose_kmap),
+    not fine-row order — a regression the sorted rows np.unique produces
+    would mask."""
     rng = np.random.default_rng(13)
     scenes = [_mk_scene_coords(rng, n) for n in (40, 25, 33)]
     for c in scenes:
         rng.shuffle(c)
     cap = 128
-    batch, bst, total = _pack_batch(scenes, cap)
     specs = pyramid_map_specs(4, with_up=with_up, table="composed")
-    fresh = planlib.build_maps_from_specs(specs, bst)
-    entries = []
-    for c in scenes:
-        st = SparseTensor(coords=jnp.asarray(c),
-                          feats=jnp.zeros((len(c), 1)),
-                          num_valid=jnp.asarray(len(c), jnp.int32), stride=1,
-                          batch_bound=4, spatial_bound=64)
-        entries.append(planlib.build_scene_entry(specs, st))
-    composed = km.compose_kmaps(entries, cap)
-    assert composed is not None and set(composed) == set(fresh)
-    for ref in fresh:
-        assert_kmaps_equal(fresh[ref], composed[ref], ctx=str(ref))
+    entries = _assert_composes_to_fresh(specs, scenes, [c.shape[0]
+                                                        for c in scenes], cap)
     # degenerate inputs fall back instead of mis-composing
-    assert km.compose_kmaps([], cap) is None
-    assert km.compose_kmaps(entries, entries[0].n - 1) is None
+    assert km.compose_kmaps(specs, [], cap) is None
+    assert km.compose_kmaps(specs, entries, entries[0].n - 1) is None
+
+
+@pytest.mark.parametrize("arch", ["minkunet", "centerpoint"])
+@pytest.mark.parametrize("sizes,caps,cap", [
+    ((90, 40), (128, 64), 256),      # two scene rungs in one bucket
+    ((40, 90), (64, 128), 256),      # the other order
+    ((70,), (128,), 128),            # a single-scene batch
+    ((90,), (128,), 96),             # a scene rung taller than the bucket
+])
+def test_device_composer_matches_batch_build_for_model_specs(arch, sizes,
+                                                             caps, cap):
+    """MinkUNet's and CenterPoint's own map programs compose bit-identically
+    to a fresh batch build, whatever the scenes' rungs and the batch size."""
+    from repro.configs import centerpoint_waymo, minkunet_kitti
+    from repro.models import centerpoint, minkunet
+    specs = (minkunet.declare(minkunet_kitti.CONFIG_BENCH) if arch ==
+             "minkunet" else centerpoint.declare(
+                 centerpoint_waymo.CONFIG_BENCH)).map_specs
+    rng = np.random.default_rng(sum(sizes))
+    scenes = [_mk_scene_coords(rng, n, -20, 20) for n in sizes]
+    _assert_composes_to_fresh(specs, scenes, caps, cap)
+
+
+def test_device_composer_declines_what_it_cannot_compose():
+    """An empty scene, or a level with more rows than the bucket, gives
+    None (the engine then builds the batch's maps afresh)."""
+    rng = np.random.default_rng(4)
+    specs = pyramid_map_specs(2, with_up=True, table="composed")
+    scenes = [_mk_scene_coords(rng, n) for n in (30, 20)]
+    entries = _scene_entries(specs, scenes, (32, 32))
+    assert km.compose_kmaps(specs, entries, 50) is not None
+    assert km.compose_kmaps(specs, entries, 49) is None
+    empty = _scene_entries(specs, [scenes[0][:0]], (32,))[0]
+    assert empty.n == 0
+    assert km.compose_kmaps(specs, [entries[0], empty], 64) is None
+    # only the level sizes and the root table reached the host
+    assert set(entries[0].arrays) == {"m_out", "coords"}
+    for a in jax.tree.leaves(entries[0].arrays):
+        assert isinstance(a, jax.Array)
+    assert isinstance(entries[0].root_keys, np.ndarray)
+    assert entries[0].nbytes == sum(a.nbytes for a in
+                                    jax.tree.leaves(entries[0].arrays))
 
 
 # ---------------------------------------------------------------------------
